@@ -8,13 +8,19 @@ into single fused HLO modules; distribution is mesh + shardings over ICI/DCN.
 
 from __future__ import annotations
 
+import os as _os
+
 import jax as _jax
+
+# Importing this package initialises NO backend (tests/test_startup.py): a
+# chip belongs to one process, so a parent that only imports paddle_tpu must
+# leave it free for the child it starts.  The first array op, paddle.seed'ed
+# key draw or set_device() is what claims the device.
 
 # Multi-process contract (SURVEY.md §3.5): the launch CLI exports
 # PADDLE_TRAINER_* env vars; jax.distributed.initialize must run BEFORE the
-# first backend touch, and importing this package touches the backend — so
-# join the coordination service here, first thing (dependency-free module:
-# the distributed package itself needs tensors, which need the backend).
+# first backend touch — so join the coordination service here, first thing
+# (dependency-free module).
 from ._bootstrap import maybe_join_coordination_service as _mpi  # noqa: E402
 
 _mpi()
@@ -23,6 +29,23 @@ _mpi()
 # and index tensors to int64).  Model code stays float32/bf16; f64 on TPU is
 # a user error surfaced by XLA, same as the reference on most GPU kernels.
 _jax.config.update("jax_enable_x64", True)
+
+
+def _configure_compile_cache():
+    """Persistent XLA compile cache, placeable from outside.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX's own handling of it stands and
+    this code sets no directory.  Unset: one FIXED path inside the checkout
+    (``<repo>/.jax_cache``, git-ignored) — the directory is part of what a
+    warm restart must find again, so never a temp dir, pid or timestamp."""
+    if _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    root = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
+    _jax.config.update("jax_compilation_cache_dir",
+                       _os.path.join(root, ".jax_cache"))
+
+
+_configure_compile_cache()
 
 __version__ = "0.1.0"
 

@@ -234,7 +234,7 @@ def dtensor_from_local(local, process_mesh, placements):
 def _materialize_partial(t, target_placements):
     """Partial -> Replicate/Shard: the real reduction, via a shard_map
     collective over the partial mesh axis (psum / psum_scatter)."""
-    from .communication import shard_map as _sm  # version shim
+    from .communication import shard_map as _sm
     from jax import lax
 
     pm, placements = t._dist_attr

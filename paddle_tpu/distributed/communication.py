@@ -33,19 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map_new
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_new(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                              check_vma=False)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                              check_rep=False)
-
 from ..observability import faults as _faults
 from ..observability import tracing as _tracing
 from ..observability import watchdog as _watchdog
@@ -58,6 +45,11 @@ __all__ = [
     "reduce_scatter", "broadcast", "scatter", "alltoall", "alltoall_single",
     "send", "recv", "isend", "irecv", "barrier", "stream",
 ]
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def _group(group) -> Group:

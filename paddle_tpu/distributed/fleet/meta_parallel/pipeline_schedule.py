@@ -46,14 +46,8 @@ def _shard_map(f, mesh, in_specs, out_specs):
     """Fully manual over the mesh: hybrid parallelism inside the body is
     explicit — pp via ppermute here, mp via the TP layers' own psum
     (mp_layers manual mode), dp via the batch specs."""
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def pipeline_tick_stats(n_micro, n_stages, layers_per_stage=1, schedule="gpipe"):

@@ -86,8 +86,9 @@ def main(argv=None):
     if args.nnodes > 1:
         # multi-process: the worker must import the framework FRESH so the
         # bootstrap joins the coordination service before any backend touch
-        # (this launcher process may already hold an initialized backend) —
-        # same spawn model as the reference launcher's worker processes.
+        # — same spawn model as the reference launcher's worker processes.
+        # This launcher has imported the package but initialised no backend
+        # (tests/test_startup.py), so the chip is free for the worker.
         import subprocess
 
         proc = subprocess.run([sys.executable, args.script] +

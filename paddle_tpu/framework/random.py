@@ -41,7 +41,10 @@ import numpy as _np
 _IMPL = os.environ.get("PADDLE_TPU_PRNG_IMPL", "rbg")
 
 _lock = threading.Lock()
-_global_key = jax.random.key(0, impl=_IMPL)
+# created from _seed_value on first use, not at import: jax.random.key
+# initialises the backend, and a process that only imports the package must
+# leave the chip to the child it starts
+_global_key = None
 _seed_value = 0
 # host-side stream for draws that must be CONCRETE Python floats even
 # inside a jit trace (static shape/layout decisions): under omnistaging
@@ -57,12 +60,20 @@ def seed(s: int):
     global _global_key, _seed_value, _host_rng
     with _lock:
         _seed_value = int(s)
-        _global_key = jax.random.key(int(s), impl=_IMPL)
+        _global_key = None
         _host_rng = _np.random.default_rng(int(s))
 
 
 def get_seed() -> int:
     return _seed_value
+
+
+def _key():
+    """The global key (caller holds ``_lock``)."""
+    global _global_key
+    if _global_key is None:
+        _global_key = jax.random.key(_seed_value, impl=_IMPL)
+    return _global_key
 
 
 def next_key():
@@ -79,7 +90,7 @@ def next_key():
         return sub
     global _global_key
     with _lock:
-        _global_key, sub = jax.random.split(_global_key)
+        _global_key, sub = jax.random.split(_key())
     return sub
 
 
@@ -118,7 +129,8 @@ def fold_in_axis(key, axis_name: str):
 
 def get_rng_state():
     """Return opaque RNG state (the current key)."""
-    return _global_key
+    with _lock:
+        return _key()
 
 
 def set_rng_state(state):

@@ -93,10 +93,7 @@ def flops(net, input_size, custom_ops=None, print_detail=False):
 
     try:
         compiled = jax.jit(fwd).lower(params, bufs, x).compile()
-        analysis = compiled.cost_analysis()
-        if isinstance(analysis, list):
-            analysis = analysis[0] if analysis else {}
-        val = int(analysis.get("flops", 0))
+        val = int(compiled.cost_analysis().get("flops", 0))
     except Exception:
         val = 0
     if print_detail:

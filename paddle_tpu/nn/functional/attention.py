@@ -76,12 +76,9 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
 
             return apply(ring_fn, query, key, value, op_name="ring_attention")
     if (attn_mask is None and dropout_p == 0.0 and qv.ndim == 4):
-        try:
-            from ...ops.flash_attention import supported
+        from ...ops.flash_attention import supported
 
-            use_flash = supported(qv.shape, unwrap(key).shape, is_causal)
-        except Exception:
-            use_flash = False
+        use_flash = supported(qv.shape, kv_.shape, is_causal)
     if use_flash:
         from ...ops.flash_attention import flash_attention_bshd
 

@@ -88,8 +88,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        # output stays f32 (the f32->bf16 truncf fails to legalize in this
-        # Mosaic backend); XLA fuses the downcast outside the kernel
+        # output stays f32; XLA fuses the downcast outside the kernel
         denom = jnp.maximum(l_scr[:], jnp.float32(1e-30))
         o_ref[0] = acc_scr[:] / denom
         lse_ref[0] = m_scr[:] + jnp.log(denom)

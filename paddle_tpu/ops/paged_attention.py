@@ -50,10 +50,9 @@ def _paged_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(i * page_size < seq_len)
     def _compute():
-        # Mosaic discipline (mirrors ops/flash_attention.py, which compiles
-        # on this backend): strictly 2-D tiles, keepdims reductions, f32
-        # constants, plain-contracting dot_generals only (the H-batched
-        # spelling fails to parse here — r5).  KV heads run as a STATIC
+        # Mosaic discipline (mirrors ops/flash_attention.py): strictly 2-D
+        # tiles, keepdims reductions, f32 constants, plain-contracting
+        # dot_generals only.  KV heads run as a STATIC
         # unrolled loop; each page streams HBM->VMEM ONCE and serves all g
         # grouped query heads via two small MXU dots — GQA's bandwidth
         # saving holds inside the kernel (no repeated-KV reads).
@@ -82,8 +81,7 @@ def _paged_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(i == pl.num_programs(1) - 1)
     def _fin():
-        # output stays f32 — the f32->bf16 truncf fails to legalize in this
-        # Mosaic backend; the public entry downcasts outside the kernel
+        # output stays f32; the public entry downcasts outside the kernel
         o_ref[0] = acc_scr[...] / jnp.maximum(l_scr[...], jnp.float32(1e-30))
 
 
@@ -115,16 +113,12 @@ def _paged_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
         ],
     )
     # x64 OFF around the call: the framework enables jax_enable_x64 globally
-    # (paddle int64 tensor parity), and under it the scalar-prefetch grid
-    # machinery emits i64 index arithmetic that this Mosaic backend cannot
-    # legalize (r5: compile failed from inside paddle_tpu but succeeded in a
-    # bare-jax process; bisected to exactly this flag).  Every dtype in the
-    # kernel is pinned, so x32 promotion rules change nothing numerically.
-    # (jax.enable_x64 is a lazy attr some versions never bind — the
-    # experimental spelling is the stable one.)
-    from jax.experimental import enable_x64 as _enable_x64
-
-    with _enable_x64(False):
+    # (paddle int64 tensor parity), and under it the literal 0s of the
+    # BlockSpec index maps trace as i64 constants, which Mosaic fails to
+    # legalize (checked against libtpu 0.0.34: "failed to legalize operation
+    # 'func.func'" on the index-map transform).  Every dtype in the kernel is
+    # pinned, so x32 promotion rules change nothing numerically.
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(_paged_kernel, page_size=page_size, scale=scale,
                               num_kv_heads=HKV),
@@ -203,7 +197,6 @@ def _flash_sharded(pallas_fn, q, pools, scales, page_table, seq_lens,
     the head dim, table/lens replicate, out follows q.  ``pools`` are the
     [P, ps, h, d] payload arrays, ``scales`` the optional [P, ps, h] scale
     pools (quantized path)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, ax = _MP_SCOPE[0]
@@ -218,8 +211,8 @@ def _flash_sharded(pallas_fn, q, pools, scales, page_table, seq_lens,
         table_, lens_ = rest[-2:]
         return pallas_fn(q_, *kv, table_, lens_, scale, interpret)
 
-    f = shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=q_spec,
-                  check_rep=False)
+    f = jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=q_spec,
+                      check_vma=False)
     return f(q, *pools, *scales, page_table, seq_lens)
 
 
@@ -251,9 +244,9 @@ def _accum_page(q_ref, valid, load_k, load_v, scale, num_kv_heads,
                 m_scr, l_scr, acc_scr):
     """One page's online-softmax update, shared by the flash kernels.
 
-    Mosaic discipline (mirrors _paged_kernel, which compiles on this
-    backend): strictly 2-D tiles, keepdims reductions, f32 constants,
-    plain-contracting dot_generals only.  KV heads run as a STATIC
+    Mosaic discipline (mirrors _paged_kernel): strictly 2-D tiles,
+    keepdims reductions, f32 constants, plain-contracting dot_generals
+    only.  KV heads run as a STATIC
     unrolled loop; ``load_k(j)``/``load_v(j)`` return the page's f32
     [page, D] tile for kv head j (the int8 kernel fuses dequant there),
     streamed ONCE and serving all g grouped query heads."""
@@ -316,22 +309,8 @@ def _paged_flash_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     def _fin():
         # empty rows (seq_len == 0) run _init then _fin at step 0 (when
         # blocks execute in definition order) and write zeros.  Output
-        # stays f32 — the f32->bf16 truncf fails to legalize in this
-        # Mosaic backend; the public entry downcasts outside the kernel.
+        # stays f32; the public entry downcasts outside the kernel.
         o_ref[0] = acc_scr[...] / jnp.maximum(l_scr[...], jnp.float32(1e-30))
-
-
-def _flash_compiler_params():
-    """Megacore partitioning over the batch grid dimension, defensively:
-    older Pallas revisions spell the params differently (or not at all),
-    and the kernel is correct without them."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    except Exception:
-        return None
 
 
 def _paged_flash_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
@@ -360,21 +339,18 @@ def _paged_flash_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
             pltpu.VMEM((H, D), jnp.float32),
         ],
     )
-    kwargs = {}
-    cparams = None if interpret else _flash_compiler_params()
-    if cparams is not None:
-        kwargs["compiler_params"] = cparams
     # x64 OFF for the same Mosaic i64-index reason as _paged_pallas
-    from jax.experimental import enable_x64 as _enable_x64
-
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(_paged_flash_kernel, page_size=page_size,
                               scale=scale, num_kv_heads=HKV),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
             interpret=interpret,
-            **kwargs,
+            # batch rows are independent (megacore-partitionable); the page
+            # sweep carries the online-softmax state and stays sequential
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
         )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
           q, k_pages, v_pages)
     return out.astype(q.dtype)
@@ -776,9 +752,7 @@ def _paged_q_pallas(q, k_pages, v_pages, k_scales, v_scales, page_table,
         ],
     )
     # x64 OFF for the same Mosaic i64-index reason as _paged_pallas
-    from jax.experimental import enable_x64 as _enable_x64
-
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(_paged_q_kernel, page_size=page_size,
                               scale=scale, num_kv_heads=HKV),
@@ -859,21 +833,16 @@ def _paged_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
             pltpu.VMEM((H, D), jnp.float32),
         ],
     )
-    kwargs = {}
-    cparams = None if interpret else _flash_compiler_params()
-    if cparams is not None:
-        kwargs["compiler_params"] = cparams
     # x64 OFF for the same Mosaic i64-index reason as _paged_pallas
-    from jax.experimental import enable_x64 as _enable_x64
-
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(_paged_q_flash_kernel, page_size=page_size,
                               scale=scale, num_kv_heads=HKV),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
             interpret=interpret,
-            **kwargs,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
         )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
           q, k_pages, v_pages, k_scales.astype(jnp.float32),
           v_scales.astype(jnp.float32))

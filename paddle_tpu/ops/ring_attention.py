@@ -53,18 +53,9 @@ def _block_attn(qf, kf, vf, scale, causal):
     return o, m + jnp.log(jnp.maximum(l, 1e-30))
 
 
-def _axis_size(axis):
-    """Static mapped-axis size.  jax >= 0.6 spells it lax.axis_size; on
-    0.4.x jax.core.axis_frame(name) returns the size itself."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    fr = jax.core.axis_frame(axis)
-    return int(getattr(fr, "size", fr))
-
-
 def _ring_body(q, k, v, axis, scale, causal):
     """Per-device body: q,k,v local [B, S_loc, H, D]."""
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     b, s_loc, h, d = q.shape
 
@@ -123,12 +114,6 @@ def ring_attention_fn(q, k, v, mesh, axis="sep", scale=None, causal=False):
     def body(q_l, k_l, v_l):
         return _ring_body(q_l, k_l, v_l, axis, scale, causal)
 
-    try:
-        mapped = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                               out_specs=spec, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as sm
-
-        mapped = sm(body, mesh=mesh, in_specs=(spec, spec, spec),
-                    out_specs=spec, check_rep=False)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=spec, check_vma=False)
     return mapped(q, k, v)

@@ -492,9 +492,49 @@ def _run_gate(*args):
     return r.returncode, json.loads(line)
 
 
-def test_check_regressions_real_trajectory_passes():
-    rc, verdict = _run_gate("--check-regressions", "BENCH_r04.json",
-                            "--current", "BENCH_r05.json")
+def _bench_result(scale):
+    """A ``python bench.py`` result line in the shape main() prints, with
+    SYNTHETIC values (``scale`` moves the throughput-like leaves)."""
+    return {
+        "metric": "resnet50_train_imgs_per_sec", "value": 2000.0 * scale,
+        "vs_baseline": 1.0, "device_kind": "synthetic",
+        "roofline": {"matmul_bf16_tflops_measured": 100.0,
+                     "hbm_gbs_measured": 400.0},
+        "resnet50_mfu": {"mfu_vs_peak": 0.25},
+        "step_cost_fw_vs_raw": {
+            "resnet_fw": {"gflops": 3000.0, "gbytes_accessed": 50.0},
+            "resnet_raw": {"gflops": 3000.0, "gbytes_accessed": 50.0}},
+        "batch_sweep": {"b256_imgs_per_sec": 2000.0 * scale,
+                        "b256_vs_baseline": 1.0},
+        "bert_base_finetune": {
+            "metric": "ernie3_base_ft_samples_per_sec",
+            "value": 800.0 * scale, "vs_baseline": 1.0,
+            "mfu": {"achieved_tflops": 60.0, "mfu_vs_peak": 0.3}},
+        "allreduce": {"value": None, "n_devices": 1},
+        "attention_pallas_vs_xla": [
+            {"seq": sq, "speedup_fwd": 1.5, "speedup_fwdbwd": 1.5}
+            for sq in (1024, 2048, 4096)],
+        "decode_gpt_base": {"dense_cache": 200.0 * scale,
+                            "paged_cache": 200.0 * scale,
+                            "paged_vs_dense": 1.0},
+    }
+
+
+def _driver_artifact(path, result):
+    """The driver's wrapper around one bench run: it keeps only the LAST
+    bytes of the output, so the JSON line arrives head-truncated (cut here
+    inside step_cost_fw_vs_raw, mid-key) and ``parsed`` is null."""
+    text = json.dumps(result)
+    cut = text.index('"resnet_raw"') + 5
+    path.write_text(json.dumps({"n": 0, "cmd": "python bench.py", "rc": 0,
+                                "tail": text[cut:], "parsed": None}))
+    return str(path)
+
+
+def test_check_regressions_truncated_trajectory_passes(tmp_path):
+    base = _driver_artifact(tmp_path / "base.json", _bench_result(1.0))
+    cur = _driver_artifact(tmp_path / "cur.json", _bench_result(1.25))
+    rc, verdict = _run_gate("--check-regressions", base, "--current", cur)
     assert rc == 0
     assert verdict["pass"] is True and verdict["checked"] >= 8
     assert verdict["regressions"] == []
@@ -502,28 +542,32 @@ def test_check_regressions_real_trajectory_passes():
     assert verdict["baseline_recovered_partial"] is True
     by_name = {r["metric"]: r for r in verdict["results"]}
     assert by_name["bert_base_finetune.value"]["status"] == "ok"
-    assert by_name["bert_base_finetune.value"]["baseline"] == 867.8
-    assert by_name["bert_base_finetune.value"]["current"] == 1105.3
+    assert by_name["bert_base_finetune.value"]["baseline"] == 800.0
+    assert by_name["bert_base_finetune.value"]["current"] == 1000.0
+    # leaves whose path prefix went with the head are not gated under a
+    # shorter, aliased path
+    assert "value" not in by_name and "vs_baseline" not in by_name
 
 
 def test_check_regressions_catches_injected_regression(tmp_path):
     import bench
 
-    m5, meta = bench.load_bench_metrics(os.path.join(REPO, "BENCH_r05.json"))
+    base = _driver_artifact(tmp_path / "base.json", _bench_result(1.0))
+    m, meta = bench.load_bench_metrics(base)
     assert meta["complete"] is False
     bad = {"bert_base_finetune": {
-        "value": m5["bert_base_finetune.value"] * 0.8,   # injected -20%
-        "vs_baseline": m5["bert_base_finetune.vs_baseline"],
-        "mfu": {"mfu_vs_peak": m5["bert_base_finetune.mfu.mfu_vs_peak"]}}}
+        "value": m["bert_base_finetune.value"] * 0.8,   # injected -20%
+        "vs_baseline": m["bert_base_finetune.vs_baseline"],
+        "mfu": {"mfu_vs_peak": m["bert_base_finetune.mfu.mfu_vs_peak"]}}}
     p = tmp_path / "current.json"
     p.write_text(json.dumps(bad))
-    rc, verdict = _run_gate("--check-regressions", "BENCH_r05.json",
+    rc, verdict = _run_gate("--check-regressions", base,
                             "--current", str(p))
     assert rc == 1
     assert verdict["pass"] is False
     assert "bert_base_finetune.value" in verdict["regressions"]
     # a wide-open tolerance waves the same delta through
-    rc, verdict = _run_gate("--check-regressions", "BENCH_r05.json",
+    rc, verdict = _run_gate("--check-regressions", base,
                             "--current", str(p), "--tolerance", "0.5")
     assert rc == 0 and verdict["pass"] is True
 
