@@ -199,8 +199,7 @@ def _eager_collective(op_name, g, v, op=ReduceOp.SUM, *, _kind=None,
            tuple(sorted(kw.items())), tuple(v.shape), str(v.dtype))
     first_dispatch = sig not in _COMPILED_SIGS
     cm = _tracing.span(f"collective.{op_name}", group=g.id,
-                       nranks=g.nranks, bytes=_nbytes(v)) \
-        if _tracing._ACTIVE else _tracing.NOOP
+                       nranks=g.nranks, bytes=_nbytes(v))
     token = None if first_dispatch \
         else _watchdog.collective_begin(op_name, g)
     try:

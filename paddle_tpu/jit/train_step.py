@@ -259,18 +259,14 @@ class TrainStep:
                     self._m_mfu.set(achieved / peak)
         self._last_call_t = t_call
         self._perf_prev_family = fn._perf_family
-        # span per fused step: traced-phase collective events recorded
-        # while a new variant traces inherit this trace id, so a step and
-        # its collectives correlate in the merged cross-rank timeline
-        cm = _tracing.span("jit.train_step", step=self._step_count,
-                           new_variant=new_variant) \
-            if _tracing._ACTIVE else _tracing.NOOP
-        with cm:
-            if _prof_events._ACTIVE:
-                with _prof_events.record("TrainStep"):
-                    out = fn(*call_args, *vals, *tail)
-            else:
-                out = fn(*call_args, *vals, *tail)
+        # span per fused step (the dispatch of the compiled call): traced-
+        # phase collective events recorded while a new variant traces
+        # inherit this trace id, so a step and its collectives correlate in
+        # the merged cross-rank timeline; in a jax.profiler trace it is the
+        # host's part of a step, on the device events' clock
+        with _tracing.span("jit.train_step", step=self._step_count,
+                           new_variant=new_variant):
+            out = fn(*call_args, *vals, *tail)
         if new_variant:
             # first dispatch of a variant = trace + XLA compile (+ async
             # enqueue); record it and refresh the donation footprint
@@ -464,6 +460,10 @@ class TrainStep:
                         _stack.enter_context(no_grad_ctx())
                         _stack.enter_context(_rng.rng_scope(key))
                         _stack.enter_context(model.bind(bind_p, dict(buffers)))
+                        # names the forward and the loss (and, as
+                        # transpose(jvp(forward_loss)), their backward) in
+                        # every device operation's name stack
+                        _stack.enter_context(jax.named_scope("forward_loss"))
                         with auto_cast(enable=amp_level is not None,
                                        level=amp_level or "O1", dtype=amp_dtype):
                             args = jax.tree_util.tree_unflatten(
@@ -551,8 +551,9 @@ class TrainStep:
                 found = jnp.zeros((), jnp.bool_)
                 for g in jax.tree_util.tree_leaves(grads):
                     found = found | ~jnp.all(jnp.isfinite(g))
-            new_p, new_s = opt.functional_update(
-                diff_params, grads, opt_state, lr, leaf_meta=leaf_meta)
+            with jax.named_scope("optimizer_step"):
+                new_p, new_s = opt.functional_update(
+                    diff_params, grads, opt_state, lr, leaf_meta=leaf_meta)
             if use_scaler:
                 # skip-step: keep old params/opt-state when any grad is
                 # non-finite (one jnp.where per leaf; XLA fuses into the copy)

@@ -5,12 +5,16 @@ reference merges per-rank chrome traces by aligned wall clocks) plus the
 trace-id plumbing production serving stacks thread from request admission
 through every decode iteration.
 
-Design (PR-1/PR-2 discipline: one attribute load when disabled):
+Design:
 
-- :func:`span` is the instrumentation primitive.  With no sink armed it
-  returns a module-level no-op singleton — instrumented hot paths
-  (``ServingEngine`` decode step, eager collectives, ``TrainStep``) pay a
-  single ``if _ACTIVE`` check and nothing else.
+- :func:`span` is the ONE instrumentation primitive of the engine's
+  scheduler and ``TrainStep``.  Every span is a
+  ``jax.profiler.TraceAnnotation`` of its name, so it is an event of the
+  ``/host:CPU`` plane, on the thread that opened it, in any running
+  ``jax.profiler`` trace — the same clock the device's ``XLA Ops`` are
+  on.  With no sink armed that annotation is all there is: no
+  :class:`Span`, no id, no lock; the profiler's own inactive check is
+  what an instrumented hot path pays when nothing records.
 - A :class:`Tracer` collects finished :class:`Span` objects (bounded) for
   export; the armed :class:`~.flight_recorder.FlightRecorder` additionally
   receives every finished span into its crash ring.  Either sink flips the
@@ -38,6 +42,7 @@ import threading
 from time import perf_counter, time as _wall
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from ..profiler import events as _events
 from ..profiler import metrics as _metrics
@@ -84,7 +89,7 @@ class Span:
     """One timed region with distributed-tracing identity."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0", "t1",
-                 "wall_t0", "attrs", "rank", "tid", "_ev", "_col")
+                 "wall_t0", "attrs", "rank", "tid", "_ev", "_col", "_ann")
 
     def __init__(self, name, attrs):
         self.name = name
@@ -98,6 +103,7 @@ class Span:
         self.tid = threading.get_ident()
         self._ev = None
         self._col = None
+        self._ann = None
 
     @property
     def duration(self):
@@ -130,9 +136,15 @@ class Span:
         if col is not None:
             self._col = col
             self._ev = col.push(self.name)
+        # the same region in a running jax.profiler trace, with the ids
+        # that join it to this span's export
+        self._ann = TraceAnnotation(self.name, trace_id=self.trace_id,
+                                    span_id=self.span_id)
+        self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        self._ann.__exit__(exc_type, exc, tb)
         self.t1 = perf_counter()
         if exc_type is not None:
             self.attrs = dict(self.attrs, error=repr(exc))
@@ -164,31 +176,28 @@ class Span:
                 f"{'open' if dur is None else f'{dur * 1e3:.3f} ms'})")
 
 
-class _NoopSpan:
-    """Returned by span() when no sink is armed — zero-allocation path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
-
-
-NOOP = _NoopSpan()
-
-
-def span(name, **attrs):
+def span(name, lazy=None, **attrs):
     """Open a traced region: ``with span("serving.prefill", trace_id=t): …``
 
     Pass ``trace_id=`` to root the span on an existing trace (cross-thread
     propagation — the serving engine hands the scheduler thread each
     request's id this way); otherwise the innermost open span's trace id is
     inherited, or a fresh one is minted.
+
+    With no Tracer or flight recorder armed the region is only a
+    ``TraceAnnotation(name, **attrs)``: an event in a running
+    ``jax.profiler`` trace, an inactive check otherwise (a ``RecordEvent``
+    while a ``Profiler`` collects its host event tree, which is that
+    annotation and a row of the summary).  ``lazy`` is a callable giving
+    further attributes (a decode step's ``links``); it runs only when a
+    sink is armed to keep them.
     """
     if not _ACTIVE:
-        return NOOP
+        if _events._ACTIVE:
+            return _events.RecordEvent(name)
+        return TraceAnnotation(name, **attrs)
+    if lazy is not None:
+        attrs.update(lazy())
     return Span(name, attrs)
 
 

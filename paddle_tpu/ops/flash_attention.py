@@ -110,6 +110,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, causal_offset=0,
     # literal 0 would trace as i64, which Mosaic rejects
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, b * 0),
@@ -272,6 +273,11 @@ def _flash_bwd_pallas(q, k, v, g, lse, r, scale, causal, causal_offset):
                           memory_space=pltpu.VMEM)
     row_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, j, b * 0),
                             memory_space=pltpu.VMEM)
+    # Neither backward call carries a ``name`` (``flash_dkdv``, ``flash_dq``)
+    # yet: a name is the innermost scope of the kernel's name stack and so
+    # becomes its HLO instruction name, and the benchmark's two flash
+    # rooflines tell backward from forward by the ``%transpose_jvp...`` that
+    # the instruction is called without one (PERF.md section 7).
     dkdv = pl.pallas_call(
         functools.partial(_fa_bwd_dkdv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, nq=nq,

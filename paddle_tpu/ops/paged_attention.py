@@ -122,6 +122,7 @@ def _paged_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
         out = pl.pallas_call(
             functools.partial(_paged_kernel, page_size=page_size, scale=scale,
                               num_kv_heads=HKV),
+            name="paged_decode",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
             interpret=interpret,
@@ -339,7 +340,13 @@ def _paged_flash_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
             pltpu.VMEM((H, D), jnp.float32),
         ],
     )
-    # x64 OFF for the same Mosaic i64-index reason as _paged_pallas
+    # x64 OFF for the same Mosaic i64-index reason as _paged_pallas.
+    # This call alone carries no ``name="paged_decode"``: a name is the
+    # innermost scope of the kernel's name stack and so becomes its HLO
+    # instruction name, and the benchmark's ``paged_decode_roofline`` finds
+    # this kernel in the decode program as ``%step.N`` (PERF.md section 7
+    # says what has to be repointed first).  Behind ``paged_chunk_attend``
+    # the scope there names it ``chunk_attention``.
     with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(_paged_flash_kernel, page_size=page_size,
@@ -604,8 +611,9 @@ def paged_chunk_attend(q, k_pages, v_pages, table, lens):
         return _gathered_chunk_attend(q, k, v, lens2,
                                       1.0 / math.sqrt(D))
     table2 = jnp.broadcast_to(table[:, None, :], (B, C, NP)).reshape(B * C, NP)
-    out = paged_attention(q.reshape(B * C, H, D), k_pages, v_pages,
-                          table2, lens2.reshape(-1))
+    with jax.named_scope("chunk_attention"):
+        out = paged_attention(q.reshape(B * C, H, D), k_pages, v_pages,
+                              table2, lens2.reshape(-1))
     return out.reshape(B, C, H, D)
 
 
@@ -756,6 +764,7 @@ def _paged_q_pallas(q, k_pages, v_pages, k_scales, v_scales, page_table,
         out = pl.pallas_call(
             functools.partial(_paged_q_kernel, page_size=page_size,
                               scale=scale, num_kv_heads=HKV),
+            name="paged_decode_q",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
             interpret=interpret,
@@ -838,6 +847,7 @@ def _paged_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
         out = pl.pallas_call(
             functools.partial(_paged_q_flash_kernel, page_size=page_size,
                               scale=scale, num_kv_heads=HKV),
+            name="paged_decode_q",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
             interpret=interpret,
@@ -921,9 +931,10 @@ def paged_chunk_attend_quant(q, k_pages, v_pages, k_scales, v_scales,
             v.reshape(B, NP * ps, HKV, D), lens2,
             1.0 / math.sqrt(D)).astype(q.dtype)
     table2 = jnp.broadcast_to(table[:, None, :], (B, C, NP)).reshape(B * C, NP)
-    out = paged_attention_quantized(
-        q.reshape(B * C, H, D), k_pages, v_pages, k_scales, v_scales,
-        table2, lens2.reshape(-1))
+    with jax.named_scope("chunk_attention"):
+        out = paged_attention_quantized(
+            q.reshape(B * C, H, D), k_pages, v_pages, k_scales, v_scales,
+            table2, lens2.reshape(-1))
     return out.reshape(B, C, H, D)
 
 

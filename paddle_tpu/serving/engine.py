@@ -29,7 +29,12 @@ Observability (PR-1 metrics registry): ``serving.ttft_seconds``,
 ``serving.page_utilization``, ``serving.pages_in_use`` gauges;
 ``serving.requests{status=...}``, ``serving.tokens_generated``,
 ``serving.admissions_blocked``, ``serving.preemptions``,
-``serving.step_traces``, ``serving.prefill_traces`` counters.
+``serving.step_traces``, ``serving.prefill_traces`` counters;
+``serving.decode_batch_size`` and ``serving.step_page_utilization``
+histograms, observed once per decode dispatch (a window's mean).  The
+scheduler thread's turns are spans of ``observability.tracing`` (README
+"Distributed tracing & forensics" names them), in any ``jax.profiler``
+trace too.
 
 Speculative decoding (``speculative_k > 0``, see ``serving/speculative.py``
 and README "Speculative decoding"): each iteration drafts up to k tokens
@@ -735,6 +740,16 @@ class ServingEngine:
             buckets=itl_buckets)
         self._m_step_seconds = _h(
             "serving.step_seconds", "one batched decode iteration")
+        # observed once per decode / verify dispatch, so a window's delta
+        # of _sum over _count is a mean (the gauges of the same quantities
+        # are refreshed every 50 ms for /metrics and average nothing)
+        self._m_decode_batch = _h(
+            "serving.decode_batch_size", "lanes in one decode dispatch",
+            buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256))
+        self._m_step_page_util = _h(
+            "serving.step_page_utilization",
+            "KV pages in use / pool size at a decode dispatch",
+            buckets=(0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 1.0))
         self._m_prefill_seconds = _h(
             "serving.prefill_seconds", "admit-time prefill")
         self._m_queue_depth = _g(
@@ -1909,21 +1924,17 @@ class ServingEngine:
                             f"replica {self.replica} lost: host reclaimed "
                             "by the cluster scheduler (injected replica "
                             "loss)")
-                self._admit()
-                # chunked prefill rides the SAME scheduler iteration as the
-                # decode dispatch: one budget's worth of chunk work, then
-                # the batch decode over the lanes that finished ingesting
-                self._advance_prefills()
-                self._update_gauges()
-                if not any(s is not None and s.prefilled is None
-                           for s in self._slots):
-                    if any(s is not None for s in self._slots):
-                        continue        # chunked prefills still advancing
-                    with self._cv:
-                        if not self._queue and not self._stop_evt.is_set():
-                            self._cv.wait(timeout=0.02)
+                if self._queue or any(s is not None for s in self._slots):
+                    # one tree of spans per turn that does work; what its
+                    # duration holds beyond its children is the turn's
+                    # self time: gauges, ledgers, host-buffer writes
+                    with _tracing.span("serving.iteration"):
+                        self._turn()
                     continue
-                self._step_once()
+                self._update_gauges()
+                with _tracing.span("serving.idle_wait"), self._cv:
+                    if not self._queue and not self._stop_evt.is_set():
+                        self._cv.wait(timeout=0.02)
             except BaseException as e:
                 # OOM forensics FIRST, while the allocation state that
                 # produced the failure is still live: one flight dump
@@ -1954,6 +1965,18 @@ class ServingEngine:
                 self._error = e
                 self._abort_all(e)
                 return
+
+    def _turn(self):
+        """One scheduler iteration with something queued or in a slot."""
+        with _tracing.span("serving.admit"):
+            self._admit()
+        # chunked prefill rides the SAME scheduler iteration as the
+        # decode dispatch: one budget's worth of chunk work, then the
+        # batch decode over the lanes that finished ingesting
+        self._advance_prefills()
+        self._update_gauges()
+        if any(s is not None and s.prefilled is None for s in self._slots):
+            self._step_once()
 
     def _recover(self, exc):
         """Transient scheduler failure (classified by
@@ -2343,16 +2366,18 @@ class ServingEngine:
                                trace_id=req.handle.trace_id,
                                request_id=req.handle.request_id,
                                slot=slot_idx, prompt_len=S0):
-                if guard:
-                    tok, bad, nstats, *pools = prog(
-                        self._params, self._bufs, ids, *self._pools,
-                        table, lens, temps, rkey, *extra, *tail)
-                else:
-                    tok, *pools = prog(self._params, self._bufs, ids,
-                                       *self._pools, table, lens, temps,
-                                       rkey, *extra)
-                self._pools = tuple(pools)
-                tok = int(np.asarray(tok)[0])
+                with _tracing.span("serving.dispatch"):
+                    if guard:
+                        tok, bad, nstats, *pools = prog(
+                            self._params, self._bufs, ids, *self._pools,
+                            table, lens, temps, rkey, *extra, *tail)
+                    else:
+                        tok, *pools = prog(self._params, self._bufs, ids,
+                                           *self._pools, table, lens, temps,
+                                           rkey, *extra)
+                    self._pools = tuple(pools)
+                with _tracing.span("serving.device_wait"):
+                    tok = int(np.asarray(tok)[0])
         finally:
             win.close(traced=traces[0] > n0)
             self._progress_t = time.monotonic()
@@ -2457,17 +2482,19 @@ class ServingEngine:
                                request_id=req.handle.request_id,
                                slot=slot_idx, prompt_len=S0,
                                cached_tokens=cached):
-                if guard:
-                    tok, bad, nstats, *pools = prog(
-                        self._params, self._bufs, ids, nvalid,
-                        *self._pools, table, lens, temps, rkey,
-                        *extra, *gtail)
-                else:
-                    tok, *pools = prog(self._params, self._bufs, ids,
-                                       nvalid, *self._pools, table, lens,
-                                       temps, rkey, *extra)
-                self._pools = tuple(pools)
-                tok = int(np.asarray(tok)[0])
+                with _tracing.span("serving.dispatch"):
+                    if guard:
+                        tok, bad, nstats, *pools = prog(
+                            self._params, self._bufs, ids, nvalid,
+                            *self._pools, table, lens, temps, rkey,
+                            *extra, *gtail)
+                    else:
+                        tok, *pools = prog(self._params, self._bufs, ids,
+                                           nvalid, *self._pools, table, lens,
+                                           temps, rkey, *extra)
+                    self._pools = tuple(pools)
+                with _tracing.span("serving.device_wait"):
+                    tok = int(np.asarray(tok)[0])
         finally:
             win.close(traced=traces[0] > n0)
             self._progress_t = time.monotonic()
@@ -2627,17 +2654,19 @@ class ServingEngine:
                                trace_id=req.handle.trace_id,
                                request_id=req.handle.request_id,
                                slot=i, chunk_start=c0, chunk_tokens=nval):
-                if guard:
-                    tok, bad, nstats, *pools = prog(
-                        self._params, self._bufs, ids, nvalid,
-                        *self._pools, table, lens, temps, rkey,
-                        *extra, *tail)
-                else:
-                    tok, *pools = prog(self._params, self._bufs, ids,
-                                       nvalid, *self._pools, table, lens,
-                                       temps, rkey, *extra)
-                self._pools = tuple(pools)
-                tok = int(np.asarray(tok)[0])
+                with _tracing.span("serving.dispatch"):
+                    if guard:
+                        tok, bad, nstats, *pools = prog(
+                            self._params, self._bufs, ids, nvalid,
+                            *self._pools, table, lens, temps, rkey,
+                            *extra, *tail)
+                    else:
+                        tok, *pools = prog(self._params, self._bufs, ids,
+                                           nvalid, *self._pools, table, lens,
+                                           temps, rkey, *extra)
+                    self._pools = tuple(pools)
+                with _tracing.span("serving.device_wait"):
+                    tok = int(np.asarray(tok)[0])
         finally:
             win.close(traced=traces[0] > n0)
             self._progress_t = time.monotonic()
@@ -2824,16 +2853,13 @@ class ServingEngine:
                 prog, (self._params, self._bufs, self._h_last, *self._pools,
                        self._h_table, self._h_lens, self._h_temps, rkey,
                        *extra, *tail)))
-        if _tracing._ACTIVE:
-            # one span per batched iteration, LINKING every active
-            # request's trace id (a decode step serves many traces at once
-            # — the OTLP links model, not one parent)
-            cm = _tracing.span(
-                "serving.decode_step", iteration=self._iteration,
-                batch=len(active),
-                links=[self._slots[i].handle.trace_id for i in active])
-        else:  # hot path: one flag read, no span/link-list construction
-            cm = _tracing.NOOP
+        # one span per batched iteration, LINKING every active request's
+        # trace id (a decode step serves many traces at once — the OTLP
+        # links model, not one parent); the list is built only for a sink
+        # that keeps it
+        cm = _tracing.span(
+            "serving.decode_step", self._links(active),
+            iteration=self._iteration, batch=len(active))
         # first decode dispatch = XLA compile; every active request waits
         # out the whole stall, so the window bills each of their TTFTs
         win = _programs.ledger().compile_window(
@@ -2850,18 +2876,20 @@ class ServingEngine:
         bad = nstats = None
         try:
             with cm:
-                if guard:
-                    tok, bad, nstats, *pools = prog(
-                        self._params, self._bufs, self._h_last,
-                        *self._pools, self._h_table, self._h_lens,
-                        self._h_temps, rkey, *extra, *tail)
-                else:
-                    tok, *pools = prog(self._params, self._bufs,
-                                       self._h_last, *self._pools,
-                                       self._h_table, self._h_lens,
-                                       self._h_temps, rkey, *extra)
-                self._pools = tuple(pools)
-                tok = np.asarray(tok)
+                with _tracing.span("serving.dispatch"):
+                    if guard:
+                        tok, bad, nstats, *pools = prog(
+                            self._params, self._bufs, self._h_last,
+                            *self._pools, self._h_table, self._h_lens,
+                            self._h_temps, rkey, *extra, *tail)
+                    else:
+                        tok, *pools = prog(self._params, self._bufs,
+                                           self._h_last, *self._pools,
+                                           self._h_table, self._h_lens,
+                                           self._h_temps, rkey, *extra)
+                    self._pools = tuple(pools)
+                with _tracing.span("serving.device_wait"):
+                    tok = np.asarray(tok)
         finally:
             win.close(traced=traces[0] > n0)
             self._progress_t = time.monotonic()
@@ -2869,30 +2897,44 @@ class ServingEngine:
             self._m_step_traces.inc(traces[0] - n0)
         else:
             _perf.record(fam, time.perf_counter() - t0)
-        self._m_step_seconds.observe(time.perf_counter() - t0)
-        self._iteration += 1
+        self._observe_step(t0, len(active))
         if guard:
             _numerics.submit(f"serving/{self.replica}", ("logits",), nstats,
                              step=self._iteration)
             bad = np.asarray(bad)
-        for i in active:
-            if guard and bad[i]:
-                # this lane's logits went non-finite: fail exactly this
-                # request; finite lanes below emit byte-identical tokens
-                self._fail_numeric(i)
-                continue
-            s = self._slots[i]
-            s.length += 1
-            s.produced += 1
-            s.last = int(tok[i])
-            self._h_lens[i] = s.length
-            self._h_last[i, 0] = s.last
-            self._emit_token(s, s.last)
-            if not self._retire_if_done(i) and self._drafter is not None:
-                # a speculative engine can route no-draft iterations through
-                # this path: the drafter's context must keep growing or it
-                # would never find a matching suffix again
-                self._drafter.extend(i, [s.last])
+        with _tracing.span("serving.emit"):
+            for i in active:
+                if guard and bad[i]:
+                    # this lane's logits went non-finite: fail exactly this
+                    # request; finite lanes below emit byte-identical tokens
+                    self._fail_numeric(i)
+                    continue
+                s = self._slots[i]
+                s.length += 1
+                s.produced += 1
+                s.last = int(tok[i])
+                self._h_lens[i] = s.length
+                self._h_last[i, 0] = s.last
+                self._emit_token(s, s.last)
+                if not self._retire_if_done(i) and self._drafter is not None:
+                    # a speculative engine can route no-draft iterations
+                    # through this path: the drafter's context must keep
+                    # growing or it would never find a matching suffix again
+                    self._drafter.extend(i, [s.last])
+
+    def _links(self, active):
+        """The trace ids of the requests a batched step serves, as a
+        span's lazy attributes."""
+        return lambda: {"links": [self._slots[i].handle.trace_id
+                                  for i in active]}
+
+    def _observe_step(self, t0, lanes):
+        """One decode / verify dispatch is over: its wall time, and the
+        counts at the same boundary."""
+        self._m_step_seconds.observe(time.perf_counter() - t0)
+        self._m_decode_batch.observe(lanes)
+        self._m_step_page_util.observe(self._bm.utilization())
+        self._iteration += 1
 
     def _verify_once(self, active):
         """One speculative iteration: draft up to k tokens per slot from
@@ -2934,14 +2976,10 @@ class ServingEngine:
                 prog, (self._params, self._bufs, self._h_ids, *self._pools,
                        self._h_table, self._h_lens, self._h_dlen,
                        self._h_temps, rkey, *extra, *tail)))
-        if _tracing._ACTIVE:
-            cm = _tracing.span(
-                "serving.verify_step", iteration=self._iteration,
-                batch=len(active), k=K,
-                drafted=int(sum(len(drafts[i]) for i in active)),
-                links=[self._slots[i].handle.trace_id for i in active])
-        else:
-            cm = _tracing.NOOP
+        cm = _tracing.span(
+            "serving.verify_step", self._links(active),
+            iteration=self._iteration, batch=len(active), k=K,
+            drafted=sum(len(drafts[i]) for i in active))
         win = _programs.ledger().compile_window(
             self._verify_store_key(K), family=fam, replica=self.replica,
             device=self._device_label(), store=self._store(),
@@ -2957,19 +2995,21 @@ class ServingEngine:
         bad = nstats = None
         try:
             with cm:
-                if guard:
-                    targets, accept, bad, nstats, *pools = prog(
-                        self._params, self._bufs, self._h_ids, *self._pools,
-                        self._h_table, self._h_lens, self._h_dlen,
-                        self._h_temps, rkey, *extra, *tail)
-                else:
-                    targets, accept, *pools = prog(
-                        self._params, self._bufs, self._h_ids, *self._pools,
-                        self._h_table, self._h_lens, self._h_dlen,
-                        self._h_temps, rkey, *extra)
-                self._pools = tuple(pools)
-                targets = np.asarray(targets)
-                accept = np.asarray(accept)
+                with _tracing.span("serving.dispatch"):
+                    if guard:
+                        targets, accept, bad, nstats, *pools = prog(
+                            self._params, self._bufs, self._h_ids,
+                            *self._pools, self._h_table, self._h_lens,
+                            self._h_dlen, self._h_temps, rkey, *extra, *tail)
+                    else:
+                        targets, accept, *pools = prog(
+                            self._params, self._bufs, self._h_ids,
+                            *self._pools, self._h_table, self._h_lens,
+                            self._h_dlen, self._h_temps, rkey, *extra)
+                    self._pools = tuple(pools)
+                with _tracing.span("serving.device_wait"):
+                    targets = np.asarray(targets)
+                    accept = np.asarray(accept)
         finally:
             win.close(traced=traces[0] > n0)
             self._progress_t = time.monotonic()
@@ -2977,47 +3017,47 @@ class ServingEngine:
             self._m_verify_traces.inc(traces[0] - n0)
         else:
             _perf.record(fam, time.perf_counter() - t0)
-        self._m_step_seconds.observe(time.perf_counter() - t0)
-        self._iteration += 1
+        self._observe_step(t0, len(active))
         if guard:
             _numerics.submit(f"serving/{self.replica}", ("logits",), nstats,
                              step=self._iteration)
             bad = np.asarray(bad)
         proposed = accepted = 0
-        for i in active:
-            if guard and bad[i]:
-                self._fail_numeric(i)
-                continue
-            s = self._slots[i]
-            d = drafts[i]
-            a = 0
-            while a < len(d) and accept[i, a]:
-                a += 1
-            proposed += len(d)
-            emitted = [int(t) for t in d[:a]] + [int(targets[i, a])]
-            # pool state: positions length..length+a now hold the old
-            # `last` + the a accepted drafts; rejected tail K/V sits past
-            # the new length, where seq_lens masking hides it until the
-            # next chunk write overwrites it (rollback = lens stays put)
-            done = False
-            emitted_n = 0
-            for tok in emitted:
-                s.length += 1
-                s.produced += 1
-                s.last = tok
-                self._h_lens[i] = s.length
-                self._h_last[i, 0] = tok
-                self._emit_token(s, tok)
-                emitted_n += 1
-                if self._retire_if_done(i):
-                    done = True
-                    break
-            # accepted = drafts that became OUTPUT tokens: early retirement
-            # (EOS mid-draft, deadline, budget) discards the rest, and the
-            # acceptance-rate gauge must not credit discarded tokens
-            accepted += min(emitted_n, a)
-            if not done:
-                self._drafter.extend(i, emitted)
+        with _tracing.span("serving.emit"):
+            for i in active:
+                if guard and bad[i]:
+                    self._fail_numeric(i)
+                    continue
+                s = self._slots[i]
+                d = drafts[i]
+                a = 0
+                while a < len(d) and accept[i, a]:
+                    a += 1
+                proposed += len(d)
+                emitted = [int(t) for t in d[:a]] + [int(targets[i, a])]
+                # pool state: positions length..length+a now hold the old
+                # `last` + the a accepted drafts; rejected tail K/V sits past
+                # the new length, where seq_lens masking hides it until the
+                # next chunk write overwrites it (rollback = lens stays put)
+                done = False
+                emitted_n = 0
+                for tok in emitted:
+                    s.length += 1
+                    s.produced += 1
+                    s.last = tok
+                    self._h_lens[i] = s.length
+                    self._h_last[i, 0] = tok
+                    self._emit_token(s, tok)
+                    emitted_n += 1
+                    if self._retire_if_done(i):
+                        done = True
+                        break
+                # accepted = drafts that became OUTPUT tokens: early retirement
+                # (EOS mid-draft, deadline, budget) discards the rest, and the
+                # acceptance-rate gauge must not credit discarded tokens
+                accepted += min(emitted_n, a)
+                if not done:
+                    self._drafter.extend(i, emitted)
         if proposed:
             self._m_spec_proposed.inc(proposed)
             self._spec_proposed_total += proposed

@@ -19,7 +19,7 @@ TPU-first structure:
 
 from __future__ import annotations
 
-
+import jax
 import jax.numpy as jnp
 
 from ...nn import functional as F
@@ -377,6 +377,17 @@ class GPTModel(Layer):
         return (x, new_cache) if cache is not None else x
 
 
+@jax.named_scope("lm_head_loss")
+def _lm_head_loss(hidden, w, labels):
+    """Next-token loss through the head tied to the embedding ``w``
+    [vocab, hidden].  The scope names the vocabulary-wide product and the
+    loss, forward and backward, in a device trace."""
+    logits = _apply(lambda h, wv: h @ wv.T, hidden, w, op_name="matmul")
+    return F.cross_entropy(
+        logits[:, :-1].reshape([-1, logits.shape[-1]]),
+        labels[:, 1:].reshape([-1]), reduction="mean")
+
+
 class GPTForCausalLM(Layer):
     """LM head tied to the vocab embedding (reference GPTForCausalLM /
     GPTLMHeadModel)."""
@@ -389,13 +400,9 @@ class GPTForCausalLM(Layer):
                 labels=None):
         hidden = self.gpt(input_ids, position_ids, attention_mask)
         w = self.gpt.word_embeddings.weight  # [vocab, hidden]
-        logits = _apply(lambda h, wv: h @ wv.T, hidden, w, op_name="matmul")
-        if labels is not None:
-            loss = F.cross_entropy(
-                logits[:, :-1].reshape([-1, logits.shape[-1]]),
-                labels[:, 1:].reshape([-1]), reduction="mean")
-            return loss
-        return logits
+        if labels is None:
+            return _apply(lambda h, wv: h @ wv.T, hidden, w, op_name="matmul")
+        return _lm_head_loss(hidden, w, labels)
 
     # ------------------------------------------------------------ generation
     def generate(self, input_ids, max_new_tokens=32, temperature=1.0, top_k=0,
@@ -724,12 +731,9 @@ class GPTForCausalLMPipe(Layer):
 
             return sharded_vocab_head_loss(hidden, w, labels, self._mesh,
                                            batch_axis=self._batch_axis)
-        logits = _apply(lambda h, wv: h @ wv.T, hidden, w, op_name="matmul")
-        if labels is not None:
-            return F.cross_entropy(
-                logits[:, :-1].reshape([-1, logits.shape[-1]]),
-                labels[:, 1:].reshape([-1]), reduction="mean")
-        return logits
+        if labels is None:
+            return _apply(lambda h, wv: h @ wv.T, hidden, w, op_name="matmul")
+        return _lm_head_loss(hidden, w, labels)
 
 
 def pipeline_forward(model: GPTModel, input_ids, mesh, n_micro, axis="pp",

@@ -14,6 +14,7 @@ import time
 import urllib.error
 import urllib.request
 
+import jax
 import numpy as np
 import pytest
 
@@ -68,9 +69,16 @@ def test_explicit_trace_id_roots_new_trace():
     assert {s.trace_id for s in tr.spans} == {tid}
 
 
-def test_span_disabled_is_noop_singleton():
+def test_span_disabled_is_only_a_trace_annotation():
+    """With no sink armed span() builds no Span: the region is the
+    profiler's own annotation (an inactive check outside a profiler
+    session), and a lazy attribute is never computed."""
     assert tracing.get_tracer() is None and not tracing.enabled()
-    assert tracing.span("anything", big=list(range(5))) is tracing.NOOP
+    cm = tracing.span("anything", lambda: 1 / 0, big=list(range(5)))
+    assert type(cm) is jax.profiler.TraceAnnotation
+    assert not isinstance(cm, tracing.Span)
+    with cm:
+        assert tracing.current_span() is None
     assert tracing.event("anything") is None
 
 

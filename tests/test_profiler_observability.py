@@ -76,7 +76,7 @@ def test_summary_table_from_trainstep_run(capsys):
         p.step(num_samples=16)
     p.stop()
     text = p.summary()
-    assert "TrainStep" in text and "Calls" in text and "Ratio (%)" in text
+    assert "jit.train_step" in text and "Calls" in text and "Ratio (%)" in text
     # per-op rows from the traced forward appear in the table
     assert "Linear" in text or "linear" in text
 
@@ -169,7 +169,7 @@ def test_chrome_trace_export_and_load_roundtrip(tmp_path):
     res = profiler.load_profiler_result(path)
     assert res.events, "exported trace must carry host events"
     agg = res.op_summary()
-    assert "TrainStep" in agg
+    assert "jit.train_step" in agg
     rows = res.summary(sorted_by="total")
     assert rows[0]["total"] >= rows[-1]["total"]
     # directory form also resolves
