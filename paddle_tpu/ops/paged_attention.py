@@ -195,13 +195,14 @@ def mp_shard_scope(mesh, axis="model"):
 def _flash_sharded(pallas_fn, q, pools, scales, page_table, seq_lens,
                    scale, interpret):
     """shard_map wrapper for a flash Pallas entry: q and the pools shard
-    the head dim, table/lens replicate, out follows q.  ``pools`` are the
-    [P, ps, h, d] payload arrays, ``scales`` the optional [P, ps, h] scale
-    pools (quantized path)."""
+    the head dim, table/lens replicate, out follows q.  ``q`` is the decode
+    [B, h, d] or the chunk [B, C, h, d]; ``pools`` are the [P, ps, h, d]
+    payload arrays, ``scales`` the optional [P, ps, h] scale pools
+    (quantized path)."""
     from jax.sharding import PartitionSpec as P
 
     mesh, ax = _MP_SCOPE[0]
-    q_spec = P(None, ax, None)
+    q_spec = P(*(None,) * (q.ndim - 2), ax, None)
     pool_spec = P(None, None, ax, None)
     scale_spec = P(None, None, ax)
     in_specs = (q_spec,) + (pool_spec,) * len(pools) \
@@ -345,8 +346,9 @@ def _paged_flash_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
     # innermost scope of the kernel's name stack and so becomes its HLO
     # instruction name, and the benchmark's ``paged_decode_roofline`` finds
     # this kernel in the decode program as ``%step.N`` (PERF.md section 7
-    # says what has to be repointed first).  Behind ``paged_chunk_attend``
-    # the scope there names it ``chunk_attention``.
+    # says what has to be repointed first).  ``paged_chunk_attend`` does
+    # not come through here: it has a kernel of its own
+    # (``_paged_chunk_pallas``) under the scope ``chunk_attention``.
     with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(_paged_flash_kernel, page_size=page_size,
@@ -583,38 +585,274 @@ def paged_table_chunk_write(pool, kv, table, lens):
         kv.reshape((B * C,) + rest).astype(pool.dtype), mode="drop")
 
 
+# ---------------------------------------------------------- chunk attention
+# C query positions per slot (a prefill chunk, a verify chunk) against the
+# slot's pages.  The decode kernels above take ONE query row per batch row,
+# so serving a chunk through them means a [B*C]-row batch that walks the
+# same page table C times: at C=256 over a 64-page table that was 16,384
+# grid steps, each fetching a 16-token page for a one-row product.  The
+# chunk kernel keeps the slot's whole query block resident instead — its
+# block index is constant over the page sweep, so it is fetched once — and
+# every K/V page comes into VMEM once per (slot, query tile) and meets all
+# of the tile's positions there.
+#
+# Query positions ride the LANE axis: q goes in as [B, H, D, C], scores are
+# [keys, C], the softmax statistics [1, C], the accumulator [D, C].  A page
+# of 16 keys on the lane axis would fill 16 of 128 lanes; C fills them, and
+# the reductions over keys run down the sublanes.  A step takes as many
+# pages as make 128 keys (each page its own in-spec through the page
+# table), so the two products of a step have a full contraction tile.
+# Position t of slot b sees keys 0 .. lens[b]+t: one mask per step replaces
+# the per-row seq_len, and the sweep stops at the page of the tile's LAST
+# position (same re-present-the-last-page clamp as _bounded_page_map).
+_LANES = 128
+
+
+def _chunk_blocking(q, k_pages, NP):
+    """Block sizes from the shapes the kernel is handed: ``(Cp, tile,
+    pages, vmem_limit)`` — C padded to whole lane tiles; the query tile of
+    one grid step (two lane tiles where the kernel's VMEM then stays under
+    12 MiB, else one); the pages of one step (128 keys' worth, at most 8
+    in-specs a pool, no more than the table holds); and the VMEM limit to
+    ask Mosaic for where a wide model needs more than its 16 MiB default
+    (``None``: the default does)."""
+    _, C, H, D = q.shape
+    _, page_size, HKV, _ = k_pages.shape
+    kv_bytes = k_pages.dtype.itemsize
+    Cp = -(-C // _LANES) * _LANES
+    pages = max(1, min(_LANES // page_size, 8, NP))
+    T = pages * page_size
+    lanes = -(-D // _LANES) * _LANES
+    rows = 32 // kv_bytes                 # sublanes of one tile of the pool
+
+    def vmem(tile):
+        blocks = H * D * tile * (2 * q.dtype.itemsize + 3 * 4)  # q, out x2; acc
+        stats = 2 * H * 8 * tile * 4
+        staged = 2 * HKV * T * lanes * 4
+        paged = 2 * 2 * T * -(-HKV // rows) * rows * lanes * kv_bytes
+        return blocks + stats + staged + paged + (1 << 20)      # + scales
+
+    tile = 2 * _LANES if Cp % (2 * _LANES) == 0 \
+        and vmem(2 * _LANES) <= 12 << 20 else _LANES
+    need = vmem(tile) + (4 << 20)                               # temporaries
+    return Cp, tile, pages, need if need > 16 << 20 else None
+
+
+def _chunk_last_key(seq_len, j, tile, chunk, capacity):
+    """Last key position query tile ``j`` of a slot can see: that of its
+    last REAL position (pad positions past ``chunk`` extend no sweep),
+    clipped to the table's reach as the dense path clips ``lens2``."""
+    return jnp.clip(seq_len + jnp.minimum((j + 1) * tile, chunk) - 1,
+                    0, capacity - 1)
+
+
+def _paged_chunk_kernel(pt_ref, lens_ref, q_ref, *refs, page_size, scale,
+                        num_kv_heads, pages, chunk, table_pages, quantized):
+    """Grid (slot b, query tile j, page step i).  ``refs``: ``pages`` K
+    page refs, as many V, (int8 pools: as many K-scale and V-scale refs),
+    the output block, then the scratch: the step's K and V staged
+    head-major in f32, and m / l / acc.  Same arithmetic as
+    :func:`_accum_page`: K/V read as stored and widened to f32 in VMEM
+    (dequant fused there), both products and the probabilities in f32,
+    2-D tiles inside the head loop, keepdims reductions, f32 constants."""
+    from jax.experimental import pallas as pl
+
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    ks_refs, vs_refs = ((refs[2 * pages:3 * pages],
+                         refs[3 * pages:4 * pages]) if quantized
+                        else (None, None))
+    o_ref, k_scr, v_scr, m_scr, l_scr, acc_scr = refs[-6:]
+
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    i = pl.program_id(2)
+    H, tile = q_ref.shape[1], q_ref.shape[3]
+    g = H // num_kv_heads
+    T = k_scr.shape[1]                         # keys of one step
+    capacity = table_pages * page_size
+
+    @pl.when(i == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, jnp.float32(NEG_INF))
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    seq_len = lens_ref[b]
+    last = _chunk_last_key(seq_len, j, tile, chunk, capacity)
+
+    def stage(pool_refs, scale_refs, scr):
+        """The step's pages into ``scr`` [HKV, T, D] f32, head-major: the
+        head loop below indexes a LEADING dim (a page holds its heads on
+        the sublane axis, where Mosaic takes no dynamic index)."""
+        for r in range(pages):
+            x = pool_refs[r][0].astype(jnp.float32)        # [ps, HKV, D]
+            if quantized:
+                x = x * scale_refs[r][0][:, :, None]
+            scr[:, r * page_size:(r + 1) * page_size, :] = \
+                jnp.swapaxes(x, 0, 1)
+
+    @pl.when(i * T <= last)
+    def _compute():
+        stage(k_refs, ks_refs, k_scr)
+        stage(v_refs, vs_refs, v_scr)
+        key = i * T + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
+        lim = jnp.minimum(
+            seq_len + j * tile
+            + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1),
+            capacity - 1)
+        valid = key <= lim                                 # [T, tile]
+
+        # a loop over kv heads, not an unrolled one: the kernel is traced
+        # and lowered once per layer of every program that holds it, and
+        # 16 unrolled heads of 8 pages were a thousand equations each time
+        @pl.loop(0, num_kv_heads)
+        def _kv_head(kv):
+            k = k_scr[kv]                                  # [T, D]
+            v = v_scr[kv]
+            for h in (kv * g + r for r in range(g)):
+                q = q_ref[0, h].astype(jnp.float32)        # [D, tile]
+                s = jax.lax.dot_general(
+                    k, q, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32) * jnp.float32(scale)
+                s = jnp.where(valid, s, jnp.float32(NEG_INF))
+                m_prev = m_scr[h]                          # [1, tile]
+                m_new = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
+                # a position with no valid key so far has m_new == NEG_INF
+                # and s - m_new == 0: its p must still be 0, so that it
+                # ends with l == 0 and writes zeros
+                p = jnp.where(valid, jnp.exp(s - m_new), jnp.float32(0.0))
+                alpha = jnp.exp(m_prev - m_new)
+                l_scr[h] = l_scr[h] * alpha + p.sum(axis=0, keepdims=True)
+                pv = jax.lax.dot_general(
+                    v, p, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)    # [D, tile]
+                acc_scr[h] = acc_scr[h] * alpha + pv
+                m_scr[h] = m_new
+
+    @pl.when(i == last // T)
+    def _fin():
+        # output stays f32; the wrapper downcasts outside the kernel
+        @pl.loop(0, H)
+        def _head(h):
+            o_ref[0, h] = acc_scr[h] / jnp.maximum(l_scr[h],
+                                                   jnp.float32(1e-30))
+
+
+def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
+                        name=None):
+    """q [B, C, H, D] against ``pools`` (K, V: [P, ps, HKV, D]) and, for
+    int8 pools, ``scales`` (K, V: [P, ps, HKV]) -> [B, C, H, D]."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, C, H, D = q.shape
+    page_size, HKV = pools[0].shape[1:3]
+    NP = table.shape[1]
+    Cp, tile, pages, vmem_limit = _chunk_blocking(q, pools[0], NP)
+    qt = jnp.transpose(jnp.pad(q, ((0, 0), (0, Cp - C), (0, 0), (0, 0))),
+                       (0, 2, 3, 1))                       # [B, H, D, Cp]
+
+    def page_map(r, rank):
+        def idx(b, j, i, pt, ln):
+            last = _chunk_last_key(ln[b], j, tile, C, NP * page_size)
+            return (pt[b, jnp.minimum(i * pages + r, last // page_size)],) \
+                + (0,) * (rank - 1)
+        return idx
+
+    paged = (*pools, *(a.astype(jnp.float32) for a in scales))
+    q_spec = pl.BlockSpec((1, H, D, tile),
+                          lambda b, j, i, pt, ln: (b, 0, 0, j))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, Cp // tile, -(-NP // pages)),
+        in_specs=[q_spec] + [
+            pl.BlockSpec((1,) + a.shape[1:], page_map(r, a.ndim))
+            for a in paged for r in range(pages)],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((HKV, pages * page_size, D), jnp.float32),
+            pltpu.VMEM((HKV, pages * page_size, D), jnp.float32),
+            pltpu.VMEM((H, 1, tile), jnp.float32),
+            pltpu.VMEM((H, 1, tile), jnp.float32),
+            pltpu.VMEM((H, D, tile), jnp.float32),
+        ],
+    )
+    # x64 OFF for the same Mosaic i64-index reason as _paged_pallas
+    with jax.enable_x64(False):
+        out = pl.pallas_call(
+            functools.partial(
+                _paged_chunk_kernel, page_size=page_size, scale=scale,
+                num_kv_heads=HKV, pages=pages, chunk=C, table_pages=NP,
+                quantized=bool(scales)),
+            name=name,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(qt.shape, jnp.float32),
+            interpret=interpret,
+            # slots and query tiles are independent; the page sweep carries
+            # the online-softmax state and stays sequential
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=vmem_limit),
+        )(table.astype(jnp.int32), lens.astype(jnp.int32), qt,
+          *(a for a in paged for _ in range(pages)))
+    return jnp.transpose(out, (0, 3, 1, 2))[:, :C].astype(q.dtype)
+
+
+def _paged_chunk_flash_pallas(q, k_pages, v_pages, table, lens, scale,
+                              interpret):
+    return _paged_chunk_pallas(q, (k_pages, v_pages), (), table, lens, scale,
+                               interpret)
+
+
+def _paged_chunk_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
+                                table, lens, scale, interpret):
+    return _paged_chunk_pallas(q, (k_pages, v_pages), (k_scales, v_scales),
+                               table, lens, scale, interpret,
+                               name="paged_chunk_q")
+
+
+def _chunk_attend(pallas_fn, q, pools, scales, table, lens):
+    """The TPU side of both chunk entries: the chunk kernel under the
+    scope the benchmark reads it by, head-sharded under an mp scope."""
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    with jax.named_scope("chunk_attention"):
+        if _MP_SCOPE[0] is not None:
+            return _flash_sharded(pallas_fn, q, pools, scales, table, lens,
+                                  scale, False)
+        return pallas_fn(q, *pools, *scales, table, lens, scale, False)
+
+
+def _chunk_lens(lens, C, capacity):
+    """[B, C] valid lengths of the dense chunk path: position t of slot b
+    sees ``lens[b] + t + 1`` keys, clipped to the table's reach."""
+    lens2 = lens.astype(jnp.int32)[:, None] + jnp.int32(1) \
+        + jnp.arange(C, dtype=jnp.int32)[None, :]
+    return jnp.minimum(lens2, jnp.int32(capacity))
+
+
 def paged_chunk_attend(q, k_pages, v_pages, table, lens):
     """Attend C query positions per slot against the global paged pools:
     position t of slot b sees tokens ``0 .. lens[b]+t`` (its own K/V
     included — the chunk is written before attending, and within-chunk
     causality falls out of the per-position valid lengths).
 
-    One :func:`paged_attention` call over a [B*C]-row expanded batch (each
-    chunk position is its own row sharing slot b's page table with its own
-    length), so the Pallas scalar-prefetch kernel and the dense reference
-    are reused unchanged.
+    On TPU one chunk kernel (:func:`_paged_chunk_pallas`): a slot's C
+    positions are one query block, and each of its K/V pages is walked once
+    for all of them.  Elsewhere the dense reference, which gathers the
+    slot's pages once for all C positions.
 
     q: [B, C, H, D] -> [B, C, H, D]."""
     B, C, H, D = q.shape
+    if jax.default_backend() == "tpu":
+        return _chunk_attend(_paged_chunk_flash_pallas, q,
+                             (k_pages, v_pages), (), table, lens)
     NP = table.shape[1]
     ps = k_pages.shape[1]
     HKV = k_pages.shape[2]
-    lens2 = lens.astype(jnp.int32)[:, None] + jnp.int32(1) \
-        + jnp.arange(C, dtype=jnp.int32)[None, :]            # [B, C]
-    lens2 = jnp.minimum(lens2, jnp.int32(NP * ps))
-    if jax.default_backend() != "tpu":
-        # gather each slot's pages ONCE for all C positions (the [B*C]
-        # expansion below would re-gather the full table width per
-        # position — C× the bytes for the same data)
-        k = k_pages[table].reshape(B, NP * ps, HKV, D)
-        v = v_pages[table].reshape(B, NP * ps, HKV, D)
-        return _gathered_chunk_attend(q, k, v, lens2,
-                                      1.0 / math.sqrt(D))
-    table2 = jnp.broadcast_to(table[:, None, :], (B, C, NP)).reshape(B * C, NP)
-    with jax.named_scope("chunk_attention"):
-        out = paged_attention(q.reshape(B * C, H, D), k_pages, v_pages,
-                              table2, lens2.reshape(-1))
-    return out.reshape(B, C, H, D)
+    lens2 = _chunk_lens(lens, C, NP * ps)
+    k = k_pages[table].reshape(B, NP * ps, HKV, D)
+    v = v_pages[table].reshape(B, NP * ps, HKV, D)
+    return _gathered_chunk_attend(q, k, v, lens2, 1.0 / math.sqrt(D))
 
 
 # --------------------------------------------------- int8 quantized pools
@@ -909,33 +1147,27 @@ def paged_attention_quantized(q, k_pages, v_pages, k_scales, v_scales,
 
 def paged_chunk_attend_quant(q, k_pages, v_pages, k_scales, v_scales,
                              table, lens):
-    """Quantized twin of :func:`paged_chunk_attend` (speculative verify
-    over int8 pools): the same [B*C]-row batch expansion, attention via
-    :func:`paged_attention_quantized`.  q: [B, C, H, D] -> [B, C, H, D]."""
+    """Quantized twin of :func:`paged_chunk_attend` (chunks over int8
+    pools): the same chunk kernel with the dequantizing page loads on TPU,
+    one gather + dequant per slot elsewhere.
+    q: [B, C, H, D] -> [B, C, H, D]."""
     B, C, H, D = q.shape
+    if jax.default_backend() == "tpu":
+        return _chunk_attend(_paged_chunk_q_flash_pallas, q,
+                             (k_pages, v_pages), (k_scales, v_scales),
+                             table, lens)
     NP = table.shape[1]
     ps = k_pages.shape[1]
     HKV = k_pages.shape[2]
-    lens2 = lens.astype(jnp.int32)[:, None] + jnp.int32(1) \
-        + jnp.arange(C, dtype=jnp.int32)[None, :]            # [B, C]
-    lens2 = jnp.minimum(lens2, jnp.int32(NP * ps))
-    if jax.default_backend() != "tpu":
-        # one gather + dequant per slot for all C positions (transient
-        # [B, T] working set, as in paged_attention_quantized_ref)
-        k = k_pages[table].astype(jnp.float32) \
-            * k_scales[table].astype(jnp.float32)[..., None]
-        v = v_pages[table].astype(jnp.float32) \
-            * v_scales[table].astype(jnp.float32)[..., None]
-        return _gathered_chunk_attend(
-            q, k.reshape(B, NP * ps, HKV, D),
-            v.reshape(B, NP * ps, HKV, D), lens2,
-            1.0 / math.sqrt(D)).astype(q.dtype)
-    table2 = jnp.broadcast_to(table[:, None, :], (B, C, NP)).reshape(B * C, NP)
-    with jax.named_scope("chunk_attention"):
-        out = paged_attention_quantized(
-            q.reshape(B * C, H, D), k_pages, v_pages, k_scales, v_scales,
-            table2, lens2.reshape(-1))
-    return out.reshape(B, C, H, D)
+    lens2 = _chunk_lens(lens, C, NP * ps)
+    # transient [B, T] working set, as in paged_attention_quantized_ref
+    k = k_pages[table].astype(jnp.float32) \
+        * k_scales[table].astype(jnp.float32)[..., None]
+    v = v_pages[table].astype(jnp.float32) \
+        * v_scales[table].astype(jnp.float32)[..., None]
+    return _gathered_chunk_attend(
+        q, k.reshape(B, NP * ps, HKV, D), v.reshape(B, NP * ps, HKV, D),
+        lens2, 1.0 / math.sqrt(D)).astype(q.dtype)
 
 
 class PagedKVCache:

@@ -522,11 +522,16 @@ def test_telemetry_statusz_shows_slot_table_mid_flight(model):
 def test_metrics_endpoint_matches_registry_exporter():
     from paddle_tpu.profiler import metrics as prof_metrics
 
-    prof_metrics.get_registry().counter(
-        "observability.test_scrape", "scrape parity probe").inc(3)
-    srv = telemetry.serve(0)
-    _, _, body = _get(srv.url + "/metrics")
-    assert "observability_test_scrape 3" in body.decode()
+    reg = prof_metrics.get_registry()
+    reg.counter("observability.test_scrape", "scrape parity probe").inc(3)
+    try:
+        srv = telemetry.serve(0)
+        _, _, body = _get(srv.url + "/metrics")
+        assert "observability_test_scrape 3" in body.decode()
+    finally:
+        # the registry is the process's: a probe left behind fails
+        # test_metric_families_match_readme_reference on the same worker
+        reg._metrics.pop("observability.test_scrape", None)
 
 
 def test_fault_with_times_and_seconds_still_cancellable():

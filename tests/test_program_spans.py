@@ -392,8 +392,12 @@ def test_paged_kernel_sites_carry_their_names(site):
 
 
 @pytest.mark.parametrize("quantized,want", [
-    (False, "chunk_attention"), (True, "chunk_attention/paged_decode_q")])
+    (False, "chunk_attention"), (True, "chunk_attention/paged_chunk_q")])
 def test_chunk_attention_names_the_kernel_behind_it(quantized, want):
+    """One kernel a call (no [B*C]-row expansion through the decode
+    kernel), under the scope ``chunk_attn_device_ms`` reads it by; the
+    bf16 one carries no name of its own, so its instruction is
+    ``%chunk_attention.N``."""
     pa = importlib.import_module("paddle_tpu.ops.paged_attention")
     fn = pa.paged_chunk_attend_quant if quantized else pa.paged_chunk_attend
     assert _pallas_scopes(fn, *_paged_args(quantized, chunk=3)) == [want]
