@@ -6,7 +6,9 @@ One process, the public entry points, full width, random weights from a seed:
 
 - kernels: every Pallas kernel a public entry dispatches to on TPU, compiled
   (not interpreted), against its ``*_ref`` at GPT-base shapes and at D=128
-  with GQA;
+  with GQA; flash attention also at latent attention's two head sizes (192
+  and 128), and one dropless expert layer at Kanana-2's widths against a
+  dense mask over its experts;
 - train_resnet: ResNet-50 through ``paddle.jit.TrainStep`` (B=128, 224x224,
   bf16 O2, Momentum), fed by a ``DataLoader`` with two process workers;
 - train_gpt: GPT-base LM step at S=1024, so attention runs the Pallas flash
@@ -51,7 +53,13 @@ FULL = {
     # (H, HKV, D): GPT-base heads, and GQA at head_dim 128
     "kernels": {"heads": [(12, 12, 64), (16, 4, 128)], "page_size": 16,
                 "table_pages": 64, "rows": 8, "chunk": 8,
-                "flash": [(2, 1024, 12, 64), (1, 2048, 4, 128)]},
+                # (B, S, H, D) or (B, S, H, D_qk, D_v): GPT heads, and
+                # latent attention's 192 / 128 at the trained length
+                "flash": [(2, 1024, 12, 64), (1, 2048, 4, 128),
+                          (1, 4096, 32, 192, 128)],
+                # one expert layer at Kanana-2's widths, one chip's share:
+                # (tokens, hidden, width, router experts, held, top-k)
+                "experts": (8192, 2048, 768, 128, 16, 6)},
     "resnet": {"arch": "resnet50", "classes": 1000, "batch": 128,
                "image": 224, "steps": 6},
     # GPTForCausalLM() defaults are GPT-base: 12 x 768, 12 heads, vocab 50304
@@ -152,18 +160,21 @@ def phase_kernels(cfg):
 
     # flash forward + both backward kernels through the public [B,S,H,D]
     # entry, against flash_attention._ref_attention in float32
-    for (b, s, h, d) in cfg["flash"]:
-        q, k, v, w = (jnp.asarray(rs.randn(b, s, h, d), jnp.bfloat16)
-                      for _ in range(4))
+    for (b, s, h, d, *rest) in cfg["flash"]:
+        dv = rest[0] if rest else d
+        q, k = (jnp.asarray(rs.randn(b, s, h, d), jnp.bfloat16)
+                for _ in range(2))
+        v, w = (jnp.asarray(rs.randn(b, s, h, dv), jnp.bfloat16)
+                for _ in range(2))
 
         def flash_out(q, k, v):
             return fa.flash_attention_fn(q, k, v, causal=True)
 
         def ref_out(q, k, v):
             o = fa._ref_attention(
-                *(jnp.moveaxis(x, 2, 1).reshape(b * h, s, d)
+                *(jnp.moveaxis(x, 2, 1).reshape(b * h, s, x.shape[3])
                   .astype(jnp.float32) for x in (q, k, v)), d ** -0.5, True)
-            return jnp.moveaxis(o.reshape(b, h, s, d), 1, 2)
+            return jnp.moveaxis(o.reshape(b, h, s, dv), 1, 2)
 
         def grads(attend):
             def loss(q, k, v, w):
@@ -171,7 +182,7 @@ def phase_kernels(cfg):
                                * w.astype(jnp.float32))
             return jax.grad(loss, argnums=(0, 1, 2))
 
-        tag = f"S{s}_H{h}_D{d}"
+        tag = f"S{s}_H{h}_D{d}" + (f"_DV{dv}" if rest else "")
         _check(f"flash_fwd/{tag}", jax.jit(flash_out), ref_out, (q, k, v), 1,
                out)
         # grad = forward (saving lse) + dk/dv kernel + dq kernel
@@ -231,9 +242,61 @@ def phase_kernels(cfg):
                        pa.paged_chunk_attend_quant(qc, kq, vq, ks, vs, table,
                                                    base)),
                chunk_q_ref, (qc, kq, vq, ks, vs, base), 1, out)
+    if "experts" in cfg:
+        _check_experts(cfg["experts"], rs, out)
     return {"checks": len(out), "max_rel_err": max(out.values()),
             "tolerance": KERNEL_TOL, "rel_err": out,
             "seconds": round(time.time() - t0, 1)}
+
+
+def _check_experts(sizes, rs, out):
+    """One dropless expert layer's routed part (sigmoid top-k over all the
+    router's experts, the held ones' grouped products), forward and
+    gradients, against a dense mask over the held experts in float32.  The
+    reference takes the program's own choice of experts: a bf16 score can
+    swap a token's last two."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    moe = importlib.import_module(
+        "paddle_tpu.distributed.fleet.meta_parallel.moe")
+    T, H, F, E, held, k = sizes
+    bf = jnp.bfloat16
+    x = jnp.asarray(rs.randn(T, H), bf)
+    rw = jnp.asarray(rs.randn(H, E) * 0.02, bf)
+    bias = jnp.zeros((E,), jnp.float32)
+    wg, wu = (jnp.asarray(rs.randn(held, H, F) * 0.02, bf) for _ in range(2))
+    wd = jnp.asarray(rs.randn(held, F, H) * 0.02, bf)
+    cot = jnp.asarray(rs.randn(T, H), bf)
+
+    def routed(x, rw, wg, wu, wd):
+        return moe.routed_experts(x, rw, bias, wg, wu, wd, top_k=k,
+                                  scale=2.448)[0]
+
+    def dense(x, rw, wg, wu, wd):
+        f32 = jnp.float32
+        x, rw, wg, wu, wd = (a.astype(f32) for a in (x, rw, wg, wu, wd))
+        idx, w = moe.sigmoid_topk(x, rw, bias, k, 2.448)
+        y = jnp.zeros_like(x)
+        for e in range(held):
+            mine = jnp.sum(jnp.where(idx == e, w, 0.0), -1)[:, None]
+            y = y + mine * ((jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e])
+        return y
+
+    def grads(f):
+        def loss(*a):
+            return jnp.sum(f(*a).astype(jnp.float32) * cot.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 2, 3, 4))
+
+    tag = f"T{T}_H{H}_F{F}_E{held}of{E}_top{k}"
+    args = (x, rw, wg, wu, wd)
+    # the grouped products become kernels in the TPU's compiler, after the
+    # lowering that ``_check`` counts Mosaic calls in: none there
+    _check(f"experts_fwd/{tag}", jax.jit(routed), dense, args, 0, out)
+    _check(f"experts_bwd/{tag}", jax.jit(grads(routed)), grads(dense), args,
+           0, out)
 
 
 # ------------------------------------------------------------------ trainer

@@ -13,7 +13,7 @@ from .sharding import (  # noqa: F401
 )
 from .pipeline_schedule import (  # noqa: F401
     spmd_pipeline, spmd_pipeline_1f1b, pipeline_tick_stats)
-from .moe import MoELayer, top2_gating  # noqa: F401
+from .moe import DroplessMoELayer, MoELayer, top2_gating  # noqa: F401
 from .sep_utils import (  # noqa: F401
     sep_attention, alltoall_seq_to_heads, alltoall_heads_to_seq,
 )

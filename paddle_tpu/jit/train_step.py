@@ -711,6 +711,12 @@ class TrainStep:
             from .. import amp as _amp
 
             _amp._m_loss_scale.set(float(s))
+        # counts a layer keeps on the device (an expert layer's tokens per
+        # expert) reach the metrics registry here, never inside a step
+        for layer in self.model.sublayers(include_self=True):
+            publish = getattr(layer, "publish_load", None)
+            if publish is not None:
+                publish()
         return self
 
     @property

@@ -19,6 +19,11 @@ cover) a chunked-XLA backward provides the same math.
 is differentiable IN BOTH outputs (d/dlse folds into the ds term as
 ``ds = p * (dp - delta + g_lse) * scale``), which is what ring attention
 needs to merge per-ring-step blocks exactly.
+
+Heads of two widths: q and k share one head size ``d``, v and the output
+have their own ``dv`` (latent attention: 192 and 128).  Each is padded to
+its own lane multiple, v never to q's size; at ``dv == d`` every call is
+the one it was.
 """
 
 from __future__ import annotations
@@ -71,7 +76,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         p = jnp.exp(s - m_new)                     # [BQ, BK]
         alpha = jnp.exp(m_prev - m_new)            # [BQ, 1]
         l_new = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)           # [BK, D]
+        v = v_ref[0].astype(jnp.float32)           # [BK, DV]
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_scr[:] = acc_scr[:] * alpha + pv
@@ -96,9 +101,10 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, causal_offset=0,
                with_lse=False):
-    """q,k,v: [BH, S, D] -> o [BH, S, D] (and lse [BH, S, 1] if with_lse)."""
+    """q,k: [BH, S, D], v: [BH, S, DV] -> o [BH, S, DV] (and lse
+    [BH, S, 1] if with_lse)."""
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
 
@@ -117,26 +123,26 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, causal_offset=0,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, b * 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, b * 0),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, b * 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, b * 0),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, b * 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, b * 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sq, dv), jnp.float32),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         cost_estimate=pl.CostEstimate(
-            flops=4 * bh * sq * sk * d, transcendentals=bh * sq * sk,
+            flops=2 * bh * sq * sk * (d + dv), transcendentals=bh * sq * sk,
             bytes_accessed=2 * (q.size + k.size + v.size) * q.dtype.itemsize),
     )(q, k, v)
     out = out.astype(q.dtype)
@@ -184,8 +190,8 @@ def _fa_bwd_dkdv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, r_ref,
     def compute():
         q = q_ref[0].astype(jnp.float32)           # [BQ, D]
         k = k_ref[0].astype(jnp.float32)           # [BK, D]
-        v = v_ref[0].astype(jnp.float32)           # [BK, D]
-        g = g_ref[0].astype(jnp.float32)           # [BQ, D]
+        v = v_ref[0].astype(jnp.float32)           # [BK, DV]
+        g = g_ref[0].astype(jnp.float32)           # [BQ, DV]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) \
             * jnp.float32(scale)
@@ -258,10 +264,10 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, r_ref,
 
 
 def _flash_bwd_pallas(q, k, v, g, lse, r, scale, causal, causal_offset):
-    """Pallas backward. q,k,v,g: [BH, S, D]; lse, r: [BH, S, 1] f32.
-    Returns (dq, dk, dv) in input dtypes."""
+    """Pallas backward. q,k: [BH, S, D]; v,g: [BH, S, DV]; lse, r:
+    [BH, S, 1] f32.  Returns (dq, dk, dv) in input dtypes."""
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     bq = min(BWD_BLOCK_Q, sq)
     bk = min(BWD_BLOCK_K, sk)
     nq = pl.cdiv(sq, bq)
@@ -270,6 +276,11 @@ def _flash_bwd_pallas(q, k, v, g, lse, r, scale, causal, causal_offset):
     q_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, j, b * 0),
                           memory_space=pltpu.VMEM)
     k_spec = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, b * 0),
+                          memory_space=pltpu.VMEM)
+    # v and dO walk the grid as k and q do, at v's width
+    g_spec = pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, j, b * 0),
+                          memory_space=pltpu.VMEM)
+    v_spec = pl.BlockSpec((1, bk, dv), lambda b, i, j: (b, i, b * 0),
                           memory_space=pltpu.VMEM)
     row_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, j, b * 0),
                             memory_space=pltpu.VMEM)
@@ -283,29 +294,34 @@ def _flash_bwd_pallas(q, k, v, g, lse, r, scale, causal, causal_offset):
                           block_q=bq, block_k=bk, nq=nq,
                           causal_offset=causal_offset),
         grid=(bh, nk, nq),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, g_spec, row_spec, row_spec],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, b * 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, b * 0),
+            pl.BlockSpec((1, bk, dv), lambda b, i, j: (b, i, b * 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sk, dv), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         cost_estimate=pl.CostEstimate(
-            flops=5 * bh * sq * sk * d, transcendentals=bh * sq * sk,
+            flops=bh * sq * sk * (3 * d + 2 * dv),
+            transcendentals=bh * sq * sk,
             bytes_accessed=3 * (q.size + k.size + v.size) * q.dtype.itemsize),
     )(q, k, v, g, lse, r)
 
     q_spec2 = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, b * 0),
                            memory_space=pltpu.VMEM)
     k_spec2 = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, b * 0),
+                           memory_space=pltpu.VMEM)
+    g_spec2 = pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, b * 0),
+                           memory_space=pltpu.VMEM)
+    v_spec2 = pl.BlockSpec((1, bk, dv), lambda b, i, j: (b, j, b * 0),
                            memory_space=pltpu.VMEM)
     row_spec2 = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, b * 0),
                              memory_space=pltpu.VMEM)
@@ -314,13 +330,13 @@ def _flash_bwd_pallas(q, k, v, g, lse, r, scale, causal, causal_offset):
                           block_q=bq, block_k=bk, nk=nk,
                           causal_offset=causal_offset),
         grid=(bh, nq, nk),
-        in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, row_spec2, row_spec2],
+        in_specs=[q_spec2, k_spec2, v_spec2, g_spec2, row_spec2, row_spec2],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, b * 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         cost_estimate=pl.CostEstimate(
-            flops=3 * bh * sq * sk * d, transcendentals=bh * sq * sk,
+            flops=bh * sq * sk * (2 * d + dv), transcendentals=bh * sq * sk,
             bytes_accessed=3 * (q.size + k.size + v.size) * q.dtype.itemsize),
     )(q, k, v, g, lse, r)
     return dq.astype(q.dtype), dkdv[0].astype(k.dtype), dkdv[1].astype(v.dtype)
@@ -363,7 +379,8 @@ def _chunked_attn_bwd(q, k, v, g, scale, causal, causal_offset, chunk,
         dk_c = jnp.einsum("bck,bcd->bkd", ds, qc)
         return (dk_acc + dk_c, dv_acc + dv_c), dq_c
 
-    zeros = (jnp.zeros((bh, sk, d), jnp.float32), jnp.zeros((bh, sk, d), jnp.float32))
+    zeros = (jnp.zeros((bh, sk, d), jnp.float32),
+             jnp.zeros((bh, sk, v.shape[2]), jnp.float32))
     (dk, dv), dq_chunks = jax.lax.scan(body, zeros, jnp.arange(nq))
     dq = jnp.moveaxis(dq_chunks, 0, 1).reshape(bh, sq, d)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
@@ -479,47 +496,41 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None):
 
 def flash_attention_fn(q, k, v, scale=None, causal=False,
                        block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
-    """Raw-array flash attention, [B, S, H, D] layout (paddle convention).
+    """Raw-array flash attention, [B, S, H, D] layout (paddle convention);
+    v may have a head size of its own, which is then the output's.
 
-    Pads S to the block size and D to the 128-lane tile when needed; falls
-    back to the reference einsum path off-TPU or for tiny shapes.
+    Pads S to the block size and each head size to the 128-lane tile when
+    needed; falls back to the reference einsum path off-TPU, for tiny
+    shapes and where keys would have to be padded without a causal mask.
     """
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[3]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     # shrink blocks for short sequences (stay 128-aligned)
     block_q = max(MIN_BLOCK, min(block_q, (sq // MIN_BLOCK) * MIN_BLOCK))
     block_k = max(MIN_BLOCK, min(block_k, (sk // MIN_BLOCK) * MIN_BLOCK))
-
-    plat = jax.default_backend()  # tracing-safe (tracers carry no devices)
-    if plat != "tpu" or sq < 2 * MIN_BLOCK:
-        bhq = jnp.moveaxis(q, 2, 1).reshape(b * h, sq, d)
-        bhk = jnp.moveaxis(k, 2, 1).reshape(b * h, sk, d)
-        bhv = jnp.moveaxis(v, 2, 1).reshape(b * h, sk, d)
-        o = _ref_attention(bhq, bhk, bhv, scale, causal)
-        return jnp.moveaxis(o.reshape(b, h, sq, d), 1, 2)
-
     sq_p = pl.cdiv(sq, block_q) * block_q
     sk_p = pl.cdiv(sk, block_k) * block_k
-    d_p = pl.cdiv(d, 128) * 128 if d % 128 else d  # lane-align the head dim
 
-    def prep(x, s_p):
-        x = jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], d)
-        x = _pad_to(x, s_p, 1)
-        return _pad_to(x, d_p, 2)
+    def heads_first(x, s_p=None, width=None):
+        x = jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], x.shape[3])
+        if s_p is None:
+            return x
+        return _pad_to(_pad_to(x, s_p, 1), width, 2)
 
-    qq, kk, vv = prep(q, sq_p), prep(k, sk_p), prep(v, sk_p)
-    if sk_p > sk and not causal:
-        # padded keys must not receive weight: handled by padding k with
-        # zeros -> scores 0*scale, NOT -inf. Mask via an extra bias trick:
-        # shift padded k rows to -inf by padding k with a huge negative on
-        # one feature? Simplest correct: fall back when padding keys.
-        bhq = jnp.moveaxis(q, 2, 1).reshape(b * h, sq, d)
-        bhk = jnp.moveaxis(k, 2, 1).reshape(b * h, sk, d)
-        bhv = jnp.moveaxis(v, 2, 1).reshape(b * h, sk, d)
-        o = _ref_attention(bhq, bhk, bhv, scale, causal)
-        return jnp.moveaxis(o.reshape(b, h, sq, d), 1, 2)
+    plat = jax.default_backend()  # tracing-safe (tracers carry no devices)
+    # padded keys must not receive weight, and a zero key row scores 0, not
+    # -inf: only the causal mask keeps them out
+    if plat != "tpu" or sq < 2 * MIN_BLOCK or (sk_p > sk and not causal):
+        o = _ref_attention(heads_first(q), heads_first(k), heads_first(v),
+                           scale, causal)
+        return jnp.moveaxis(o.reshape(b, h, sq, dv), 1, 2)
 
-    o = _flash(qq, kk, vv, scale, causal, block_q, block_k, sk - sq)
-    o = o[:, :sq, :d].reshape(b, h, sq, d)
+    def lanes(width):  # lane-align a head size
+        return pl.cdiv(width, 128) * 128
+
+    o = _flash(heads_first(q, sq_p, lanes(d)), heads_first(k, sk_p, lanes(d)),
+               heads_first(v, sk_p, lanes(dv)), scale, causal, block_q,
+               block_k, sk - sq)
+    o = o[:, :sq, :dv].reshape(b, h, sq, dv)
     return jnp.moveaxis(o, 1, 2)

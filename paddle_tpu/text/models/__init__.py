@@ -9,3 +9,6 @@ from .gpt import (  # noqa: F401
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel,
 )
+from .deepseek_v3 import (  # noqa: F401
+    DeepseekV3Config, DeepseekV3ForCausalLM, DeepseekV3Model,
+)

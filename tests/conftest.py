@@ -20,7 +20,22 @@ os.environ["XLA_FLAGS"] = (
 os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 
 import jax
+import pytest
 
 jax.config.update("jax_platforms", "cpu")
 
 assert jax.device_count() == 8, f"expected 8 virtual cpu devices, got {jax.devices()}"
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _leave_no_topology_behind():
+    """A test file leaves the process-wide hybrid topology as every file
+    finds it: none.  ``fleet.init`` in a fixture or a test has no teardown
+    of its own, and under ``--dist loadfile`` which files share a worker, and
+    in which order, changes with every run: a mesh left behind by one file
+    sharded the next file's parameters or refused its Mosaic kernels
+    (``test_zero_sp``, ``test_smoke_rehearsal``, the engine tests)."""
+    yield
+    from paddle_tpu.distributed import topology
+
+    topology.set_hybrid_communicate_group(None)

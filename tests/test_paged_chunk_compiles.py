@@ -53,3 +53,48 @@ def test_chunk_kernel_compiles_for_v5e(one_chip, name, B, C, H, HKV, D,
         sds((B, C, H, D), jnp.bfloat16), pool, pool, *scales,
         sds((B, NP), jnp.int32), sds((B,), jnp.int32)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+# ------------------------------------------- latent attention, expert layer
+def test_flash_kernels_compile_at_latent_attentions_head_sizes(one_chip):
+    """Forward, dk/dv and dq at q/k 192 (256 lanes) and v 128, the batch and
+    length ``kanana2_30b_a3b_ep8.lm_train_4k`` trains at: 64 (batch, head)
+    rows of 4,096 positions, the forward's 512 x 1,024 blocks."""
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    BH, S, D, DV = 64, 4096, 256, 128
+    scale, bf = 192 ** -0.5, jnp.bfloat16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, v = sds((BH, S, D), bf), sds((BH, S, DV), bf)
+    forward = jax.jit(lambda q, k, v: fa._flash_fwd(
+        q, k, v, scale, True, fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K, 0,
+        with_lse=True)).lower(q, q, v).compile()
+    assert forward.as_text().count("tpu_custom_call") == 1
+    row = sds((BH, S, 1), jnp.float32)
+    backward = jax.jit(lambda q, k, v, g, lse, r: fa._flash_bwd_pallas(
+        q, k, v, g, lse, r, scale, True, 0)).lower(
+        q, q, v, v, row, row).compile()
+    assert backward.as_text().count("tpu_custom_call") == 2
+
+
+def test_grouped_products_compile_at_published_widths(one_chip):
+    """``jax.lax.ragged_dot`` over 16 held experts of 2,048 x 768 and a
+    buffer as long as all 8,192 x 6 assignments, forward and both
+    gradients: the TPU's compiler gives each a grouped kernel of its own
+    (no dense product per group)."""
+    M, K, N, G = 8192 * 6, 2048, 768, 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, w, sizes):
+        return jnp.sum(jax.lax.ragged_dot(x, w, sizes).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        sds((M, K), jnp.bfloat16), sds((G, K, N), jnp.bfloat16),
+        sds((G,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('op_name="ragged-dot') + text.count("%ragged-dot") >= 2
+    assert compiled.cost_analysis()["flops"] < 4 * 2 * M * K * N

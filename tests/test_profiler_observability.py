@@ -310,6 +310,9 @@ def test_metrics_flusher_env_gated(tmp_path, monkeypatch):
     assert os.path.exists(tmp_path / "metrics.jsonl")
     assert os.path.exists(tmp_path / "metrics.prom")
     assert "flush_probe" in (tmp_path / "metrics.prom").read_text()
+    # the probe is this test's own: left in the process-wide registry it
+    # fails the README drift guard where both run in one worker
+    prof_metrics.get_registry()._metrics.pop("flush_probe", None)
 
 
 # ------------------------------------------------------- TrainStep accounting

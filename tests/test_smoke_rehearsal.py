@@ -18,7 +18,9 @@ _GPT = dict(vocab_size=128, hidden_size=64, num_hidden_layers=1,
             num_attention_heads=4)
 TOY = {
     "kernels": {"heads": [(4, 2, 64)], "page_size": 8, "table_pages": 4,
-                "rows": 4, "chunk": 3, "flash": [(1, 256, 2, 64)]},
+                "rows": 4, "chunk": 3,
+                "flash": [(1, 256, 2, 64), (1, 256, 2, 24, 16)],
+                "experts": (64, 32, 16, 8, 4, 3)},
     "resnet": {"arch": "resnet18", "classes": 10, "batch": 8, "image": 32,
                "steps": 3},
     "gpt": {"model": dict(_GPT, max_position_embeddings=256), "batch": 2,
