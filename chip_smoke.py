@@ -13,9 +13,11 @@ One process, the public entry points, full width, random weights from a seed:
   bf16 O2, Momentum), fed by a ``DataLoader`` with two process workers;
 - train_gpt: GPT-base LM step at S=1024, so attention runs the Pallas flash
   forward and both backward kernels;
-- serve_bf16 / serve_int8: ``ServingEngine`` at GPT-base answering mixed
+- serve_bf16 / serve_int8: ``ServingEngine`` at gpt2-medium's widths and
+  GPT-base's depth answering mixed
   requests through ``submit()/result()`` and ``stream()``, with the lowered
-  decode and prefill-chunk programs checked for one Mosaic call per layer;
+  decode, prefill-chunk and prefill programs checked for their Mosaic calls
+  per layer and their compiled text for any instruction over a whole pool;
 - multichip: with >= 4 chips, data-parallel ResNet-50, the all-reduce probe,
   ring attention and tensor-parallel serving, each with its arrays checked
   to sit on four distinct devices.  On fewer chips: ``skipped: N device``.
@@ -33,6 +35,7 @@ from __future__ import annotations
 
 import gc
 import json
+import re
 import sys
 import time
 
@@ -64,7 +67,11 @@ FULL = {
                "image": 224, "steps": 6},
     # GPTForCausalLM() defaults are GPT-base: 12 x 768, 12 heads, vocab 50304
     "gpt": {"model": {}, "batch": 4, "seq": 1024, "steps": 4},
-    "serve": {"model": {}, "num_slots": 4, "page_size": 16, "chunk": 64,
+    # served at gpt2-medium's widths (16 heads of 64: they fill the sublane
+    # tiles, so the device lays the pools out as the kernels take them and
+    # the programs must leave them alone), GPT-base's depth
+    "serve": {"model": {"hidden_size": 1024, "num_attention_heads": 16},
+              "num_slots": 4, "page_size": 16, "chunk": 64,
               # (prompt tokens, new tokens): two monolithic prefill buckets
               # (16, 48) and prompts past `chunk` that ingest by chunks
               "requests": [(9, 24), (40, 12), (150, 16), (14, 40), (45, 8),
@@ -203,14 +210,26 @@ def phase_kernels(cfg):
         lens = jnp.asarray(lens, jnp.int32)
         tag = f"H{H}_HKV{HKV}_D{D}"
 
-        _check(f"paged_flash/{tag}", jax.jit(pa.paged_attention),
-               pa.paged_attention_ref, (q, kp, vp, table, lens), 1, out)
+        # the kernels read their pools as the engine holds them: stacked
+        # over layers, the layer an index (here the second of two); the
+        # references read that layer sliced out
+        def stacked(entry, *tail):
+            return jax.jit(lambda x, *pools: entry(
+                x, *(jnp.stack([jnp.zeros_like(p), p]) for p in pools),
+                *tail, layer=1))
+
+        _check(f"paged_flash/{tag}", stacked(pa.paged_attention, table, lens),
+               lambda q, kp, vp: pa.paged_attention_ref(q, kp, vp, table,
+                                                        lens),
+               (q, kp, vp), 1, out)
 
         kq, ks = pa.quantize_kv(jnp.asarray(kf))
         vq, vs = pa.quantize_kv(jnp.asarray(vf))
-        _check(f"paged_q_flash/{tag}", jax.jit(pa.paged_attention_quantized),
-               pa.paged_attention_quantized_ref,
-               (q, kq, vq, ks, vs, table, lens), 1, out)
+        _check(f"paged_q_flash/{tag}",
+               stacked(pa.paged_attention_quantized, table, lens),
+               lambda q, *a: pa.paged_attention_quantized_ref(q, *a, table,
+                                                              lens),
+               (q, kq, vq, ks, vs), 1, out)
 
         # the chunk path: C positions per slot, each with its own length —
         # the reference attends the [B*C]-row expansion densely
@@ -234,14 +253,36 @@ def phase_kernels(cfg):
                 l2).reshape(B, C, H, D)
 
         _check(f"paged_chunk/{tag}",
-               jax.jit(lambda qc, kp, vp, base: pa.paged_chunk_attend(
-                   qc, kp, vp, table, base)),
+               jax.jit(lambda qc, kp, vp, base: stacked(
+                   pa.paged_chunk_attend, table, base)(qc, kp, vp)),
                chunk_ref, (qc, kp, vp, base), 1, out)
         _check(f"paged_chunk_quant/{tag}",
-               jax.jit(lambda qc, kq, vq, ks, vs, base:
-                       pa.paged_chunk_attend_quant(qc, kq, vq, ks, vs, table,
-                                                   base)),
+               jax.jit(lambda qc, kq, vq, ks, vs, base: stacked(
+                   pa.paged_chunk_attend_quant, table, base)(
+                       qc, kq, vq, ks, vs)),
                chunk_q_ref, (qc, kq, vq, ks, vs, base), 1, out)
+
+        # the pool writer against the plain scatter, bit for bit (0.0): a
+        # decode token, and a chunk that enters its first page part of the
+        # way down; slot 0 runs out of table and the last slot's table is
+        # one page under every entry, so merges meet in the output block
+        kn, vn = (jnp.asarray(rs.randn(B, C, HKV, D), jnp.bfloat16)
+                  for _ in range(2))
+        wlens = base.at[0].set(NP * ps - 2)
+        wtable = table.at[B - 1].set(P - 1)
+        for name, pools in (("paged_write", (kp, vp)),
+                            ("paged_write_quant", (kq, vq, ks, vs))):
+            pools = tuple(jnp.stack([p, p]) for p in pools)
+            for width in (1, C):
+                _check(f"{name}/C{width}_{tag}",
+                       jax.jit(lambda kn, vn, *pl: pa.paged_pool_write(
+                           pl, kn[:, :width], vn[:, :width], wtable, wlens,
+                           1)),
+                       lambda kn, vn, *pl: tuple(
+                           pa.paged_table_chunk_write(p, x, wtable, wlens, 1)
+                           for p, x in zip(pl, pa._pool_rows(
+                               pl, kn[:, :width], vn[:, :width]))),
+                       (kn, vn, *pools), 1, out)
     if "experts" in cfg:
         _check_experts(cfg["experts"], rs, out)
     return {"checks": len(out), "max_rel_err": max(out.values()),
@@ -434,26 +475,118 @@ def _serve_pass(engine, prompts):
     return outs + [streamed]
 
 
-def _expect_engine_mosaic(engine, chunk, layers):
-    """One Mosaic call per layer in the lowered decode and prefill-chunk
-    programs (the dispatch argument layouts of ServingEngine._warm_step /
-    _warm_prefill_chunk)."""
+def _engine_programs(engine, chunk):
+    """The engine's decode, prefill-chunk and (smallest bucket) prefill
+    programs, each with the arguments it is dispatched with (the layouts of
+    ServingEngine._warm_step / _warm_prefill_chunk / _warm_prefill)."""
     def tail(b):
         return (engine._numeric_inject(b),) if engine._numeric_guard else ()
 
-    expect_mosaic(
-        "serving decode program", engine._step_program()[0],
-        (engine._params, engine._bufs, engine._h_last, *engine._pools,
-         engine._h_table, engine._h_lens, engine._h_temps, engine._base_key,
-         *tail(None)), layers)
-    expect_mosaic(
-        "serving prefill-chunk program",
-        engine._prefill_chunk_program(chunk)[0],
-        (engine._params, engine._bufs, np.zeros((1, chunk), np.int64),
-         np.zeros((1,), np.int32), *engine._pools,
-         np.zeros((1, engine.table_width), np.int32),
-         np.zeros((1,), np.int32), np.zeros((1,), np.float32),
-         engine._base_key, *tail(1)), layers)
+    def row(width, dtype):
+        return np.zeros((1, width), dtype) if width else np.zeros((1,), dtype)
+
+    one = (np.full((1, engine.table_width), engine._scratch, np.int32),
+           row(0, np.int32), row(0, np.float32), engine._base_key, *tail(1))
+    s_pad = engine._prefill_bucket(1)
+    return {
+        "decode": (engine._step_program()[0], (
+            engine._params, engine._bufs, engine._h_last, *engine._pools,
+            engine._h_table, engine._h_lens, engine._h_temps,
+            engine._base_key, *tail(None))),
+        "prefill_chunk": (engine._prefill_chunk_program(chunk)[0], (
+            engine._params, engine._bufs, row(chunk, np.int64),
+            row(0, np.int32), *engine._pools, *one)),
+        "prefill": (engine._prefill_program(s_pad)[0], (
+            engine._params, engine._bufs, row(s_pad, np.int64),
+            *engine._pools, *one)),
+    }
+
+
+def _expect_engine_mosaic(engine, chunk, layers):
+    """Mosaic calls in the lowered serving programs: the pool writer
+    (``paged_write``) ONCE, a function of its shapes that every layer
+    calls, and in every layer the decode kernel in the decode program and
+    the chunk kernel in the prefill-chunk program; a whole-prompt prefill
+    attends densely and only writes."""
+    want = {"decode": layers + 1, "prefill_chunk": layers + 1, "prefill": 1}
+    for name, (prog, args) in _engine_programs(engine, chunk).items():
+        expect_mosaic(f"serving {name} program", prog, args, want[name])
+    return want
+
+
+_HLO_RESULT = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(")
+_HLO_ARRAY = re.compile(r"\b[a-z]+[0-9]+\[([0-9,]*)\]")
+#: instructions that name a buffer and move nothing
+_HLO_NO_DATA = ("parameter", "get-tuple-element", "tuple", "bitcast")
+
+
+def pool_sized_instructions(text, pools):
+    """Instructions of a compiled program's text whose result holds as many
+    elements as one of ``pools`` (shapes) or as one layer of it, Mosaic
+    calls left out: a copy, slice, update, concatenation, transpose or
+    fusion over the stacked pool.  A program that serves from the pool in
+    place has none: only its kernels touch the pool, a page at a time."""
+    sizes = {int(np.prod(shape[at:])) for shape in pools for at in (0, 1)}
+    found = []
+    for line in text.splitlines():
+        m = _HLO_RESULT.match(line)
+        if m is None or m.group(2) in _HLO_NO_DATA \
+                or MOSAIC_CALL in line:
+            continue
+        for dims in _HLO_ARRAY.findall(m.group(1)):
+            if int(np.prod([int(d) for d in dims.split(",") if d])) in sizes:
+                found.append(line.strip()[:160])
+                break
+    return found
+
+
+def compiled_pool_facts(jitted, args, pools):
+    """Compile ``jitted`` for ``args`` as it is dispatched (committed
+    arrays keep their sharding; nothing runs, nothing is donated) and
+    return what it does to ``pools`` outside its kernels: the pool-sized
+    instructions of its text, and its temporary bytes."""
+    import jax
+
+    def spec(a):
+        if not (hasattr(a, "shape") and hasattr(a, "dtype")):
+            return a
+        placed = a.sharding if getattr(a, "committed", False) else None
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=placed)
+
+    compiled = jitted.lower(*jax.tree_util.tree_map(spec, args)).compile()
+    shapes = [p.addressable_shards[0].data.shape if hasattr(
+        p, "addressable_shards") else p.shape for p in pools]
+    return {"pool_sized": pool_sized_instructions(compiled.as_text(), shapes),
+            "temp_bytes": int(compiled.memory_analysis().temp_size_in_bytes)}
+
+
+def _expect_pools_in_place(engine, chunk):
+    """What the real compiler's output for the engine's programs does to
+    the pools outside the kernels, printed per program: instructions over a
+    whole pool or a whole layer of it, and temporary bytes.  Where the
+    device's own layout of every pool is row-major, the kernels', there
+    must be none, and no temporaries of a pool's size (at this engine's
+    small pools the activations' are a quarter of one).  Elsewhere — heads
+    that do not fill the device's sublane tiles (a shard of an mp engine),
+    the int8 engine's 16-lane scale pools — XLA converts a pool on the way
+    in and out, and the print says how often."""
+    pools = engine._pools
+    shard_bytes = pools[0].addressable_shards[0].data.nbytes
+    row_major = all(
+        p.format.layout.major_to_minor == tuple(range(p.ndim)) for p in pools)
+    out = {"pools_row_major": row_major}
+    for name, (prog, args) in _engine_programs(engine, chunk).items():
+        facts = compiled_pool_facts(prog, args, pools)
+        log(f"  {name}: {len(facts['pool_sized'])} pool-sized instructions "
+            f"outside the kernels, {facts['temp_bytes']} temporary bytes "
+            f"(one pool: {shard_bytes}; pools row-major: {row_major})")
+        if row_major and (facts["pool_sized"]
+                          or facts["temp_bytes"] >= shard_bytes):
+            raise AssertionError(
+                f"serving {name} program moves the pool: {facts}")
+        out[name] = {"pool_sized": len(facts["pool_sized"]),
+                     "temp_bytes": facts["temp_bytes"]}
+    return out
 
 
 def _counter(name, **labels):
@@ -500,7 +633,8 @@ def phase_serve(cfg, kv_dtype, bf16=True, mesh=None, replica=None):
         if restarts or faults:
             raise AssertionError(f"{restarts} engine restarts, {faults} "
                                  "numeric faults")
-        _expect_engine_mosaic(engine, cfg["chunk"], layers)
+        mosaic = _expect_engine_mosaic(engine, cfg["chunk"], layers)
+        in_place = _expect_pools_in_place(engine, cfg["chunk"])
         pool_devices = distinct_devices(engine._pools[0])
         param_devices = max(distinct_devices(v)
                             for v in engine._params.values())
@@ -509,7 +643,7 @@ def phase_serve(cfg, kv_dtype, bf16=True, mesh=None, replica=None):
              "cold_pass_s": round(t1 - t0, 1),
              "steady_pass_s": round(t2 - t1, 2), "programs": traces,
              "step_traces": 1, "engine_restarts": 0, "numeric_faults": 0,
-             "mosaic_calls": {"decode": layers, "prefill_chunk": layers},
+             "mosaic_calls": mosaic, "programs_outside_kernels": in_place,
              "pool_devices": pool_devices, "param_devices": param_devices}
     return facts, ids
 

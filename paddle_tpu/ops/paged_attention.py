@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e30
+_LANES = 128
 
 
 def _paged_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
@@ -193,25 +194,26 @@ def mp_shard_scope(mesh, axis="model"):
 
 
 def _flash_sharded(pallas_fn, q, pools, scales, page_table, seq_lens,
-                   scale, interpret):
+                   scale, interpret, layer):
     """shard_map wrapper for a flash Pallas entry: q and the pools shard
     the head dim, table/lens replicate, out follows q.  ``q`` is the decode
-    [B, h, d] or the chunk [B, C, h, d]; ``pools`` are the [P, ps, h, d]
-    payload arrays, ``scales`` the optional [P, ps, h] scale pools
-    (quantized path)."""
+    [B, h, d] or the chunk [B, C, h, d]; ``pools`` are the stacked
+    [L, P, ps, h, d] payload arrays, ``scales`` the optional [L, P, ps, h]
+    scale pools (quantized path); every shard reads layer ``layer`` of its
+    own heads."""
     from jax.sharding import PartitionSpec as P
 
     mesh, ax = _MP_SCOPE[0]
     q_spec = P(*(None,) * (q.ndim - 2), ax, None)
-    pool_spec = P(None, None, ax, None)
-    scale_spec = P(None, None, ax)
+    pool_spec = P(None, None, None, ax, None)
+    scale_spec = P(None, None, None, ax)
     in_specs = (q_spec,) + (pool_spec,) * len(pools) \
         + (scale_spec,) * len(scales) + (P(), P())
 
     def local(q_, *rest):
         kv = rest[:len(pools) + len(scales)]
         table_, lens_ = rest[-2:]
-        return pallas_fn(q_, *kv, table_, lens_, scale, interpret)
+        return pallas_fn(q_, *kv, table_, lens_, scale, interpret, layer)
 
     f = jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=q_spec,
                       check_vma=False)
@@ -224,22 +226,57 @@ def _last_page(seq_len, page_size):
     return jnp.maximum((seq_len + page_size - 1) // page_size - 1, 0)
 
 
-def _bounded_page_map(page_size):
-    """BlockSpec index map for [P, ps, ...] page pools that clamps the
-    sweep: steps past the row's last valid page re-present that page so
-    the revisited block is not re-fetched."""
-    def idx(b, i, pt, ln):
-        return (pt[b, jnp.minimum(i, _last_page(ln[b], page_size))],
-                0, 0, 0)
-    return idx
+def pool_lane_dim(head_dim):
+    """Width of a row of the serving engine's payload pools: the head size,
+    on the TPU rounded up to whole 128-lane rows (64 is stored as 128,
+    zeros behind it).  The device holds a ``[h, d]`` tile 128 lanes wide
+    whatever ``d`` is, and lays an array whose last dim is narrower out
+    with ANOTHER dim minor-most (``[L, P, ps, h, 64]``: the page dim),
+    which every program would have to convert to the kernels' row-major
+    operands on entry and back on exit.  With the padding in the shape the
+    device's own layout is the kernels'."""
+    if jax.default_backend() != "tpu":
+        return head_dim
+    return -(-head_dim // _LANES) * _LANES
 
 
-def _bounded_scale_map(page_size):
-    """Same clamp for the [P, ps, HKV] scale pools of the int8 path."""
+def _to_lanes(x, width):
+    """``x [..., d]`` zero-padded along its last dim to a pool's row width."""
+    pad = width - x.shape[-1]
+    return x if not pad else jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+
+
+def _as_stack(pools, layer):
+    """``(stacked pools, layer)`` for the kernels, which take the serving
+    engine's stacked ``[L, P, ps, ...]`` pools and a layer: with ``layer``
+    None the pools are ONE layer's ``[P, ps, ...]``, a stack of one."""
+    if layer is None:
+        return tuple(p[None] for p in pools), 0
+    return tuple(pools), int(layer)
+
+
+def _gather_pages(pool, table, layer):
+    """The dense fall-backs' gather of a table's pages ``[B, NP, ps, ...]``
+    out of layer ``layer`` of a stacked pool (None: a one-layer pool)."""
+    return pool[table] if layer is None else pool[layer, table]
+
+
+def _bounded_page_spec(pool, layer):
+    """BlockSpec of one page of layer ``layer`` of a stacked
+    ``[L, P, ps, ...]`` pool (the payload's ``[h, d]`` or the scale pools'
+    ``[h]`` behind): the layer is a block dimension of one that the kernel
+    does not see, at a block index fixed at trace time, so a layer is
+    read where it lies in the pool.  The index map clamps the sweep: steps
+    past the row's last valid page re-present that page so the revisited
+    block is not re-fetched."""
+    from jax.experimental import pallas as pl
+
+    page_size = pool.shape[2]
+
     def idx(b, i, pt, ln):
-        return (pt[b, jnp.minimum(i, _last_page(ln[b], page_size))],
-                0, 0)
-    return idx
+        return (layer, pt[b, jnp.minimum(i, _last_page(ln[b], page_size))]) \
+            + (0,) * (pool.ndim - 2)
+    return pl.BlockSpec((None, 1) + pool.shape[2:], idx)
 
 
 def _accum_page(q_ref, valid, load_k, load_v, scale, num_kv_heads,
@@ -316,23 +353,23 @@ def _paged_flash_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _paged_flash_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
-                        interpret):
+                        interpret, layer):
+    """q [B, H, D] against layer ``layer`` of the stacked pools
+    [L, P, ps, HKV, D]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
-    HKV = k_pages.shape[2]
-    page_size = k_pages.shape[1]
+    page_size, HKV = k_pages.shape[2:4]
     NP = page_table.shape[1]
 
-    page_map = _bounded_page_map(page_size)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, NP),
         in_specs=[
             pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0)),
-            pl.BlockSpec((1, page_size, HKV, D), page_map),
-            pl.BlockSpec((1, page_size, HKV, D), page_map),
+            _bounded_page_spec(k_pages, layer),
+            _bounded_page_spec(v_pages, layer),
         ],
         out_specs=pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0)),
         scratch_shapes=[
@@ -407,24 +444,25 @@ def _gathered_chunk_attend(q, k, v, lens2, scale):
 
 
 def paged_attention_ref(q, k_pages, v_pages, page_table, seq_lens,
-                        scale=None):
+                        scale=None, layer=None):
     """Dense-gather reference with identical semantics (oracle + fallback).
 
     GQA: q may carry g*HKV heads against HKV-head pools (q head h attends
     kv head h//g, matching jnp.repeat(kv, g, axis=heads))."""
     B, H, D = q.shape
-    HKV = k_pages.shape[2]
-    page_size = k_pages.shape[1]
-    NP = page_table.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    k = k_pages[page_table].reshape(B, NP * page_size, HKV, D)
-    v = v_pages[page_table].reshape(B, NP * page_size, HKV, D)
-    return _gathered_attend(q, k, v, seq_lens, scale)
+    k = _gather_pages(k_pages, page_table, layer)[..., :D]
+    v = _gather_pages(v_pages, page_table, layer)[..., :D]
+    HKV = k.shape[3]
+    return _gathered_attend(q, k.reshape(B, -1, HKV, D),
+                            v.reshape(B, -1, HKV, D), seq_lens, scale)
 
 
 def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
-                    interpret=None):
+                    interpret=None, layer=None):
     """Decode attention over a paged KV cache (see module docstring).
+    With ``layer`` the pools are the serving engine's stacked
+    ``[L, P, ps, HKV, D]`` and that layer of them is attended, in place.
 
     Uses the length-bounded flash Pallas kernel on TPU (each row's page
     sweep stops at its last valid page — dead table slots cost no DMA);
@@ -435,20 +473,24 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
     heads.
     """
     B, H, D = q.shape
-    if H % k_pages.shape[2]:
+    if H % k_pages.shape[-2]:
         raise ValueError(f"q heads {H} not a multiple of kv heads "
-                         f"{k_pages.shape[2]}")
+                         f"{k_pages.shape[-2]}")
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     if interpret is None:
         if jax.default_backend() != "tpu":
             return paged_attention_ref(q, k_pages, v_pages, page_table,
-                                       seq_lens, scale)
+                                       seq_lens, scale, layer)
         interpret = False
+    pools, layer = _as_stack((k_pages, v_pages), layer)
+    q = _to_lanes(q, k_pages.shape[-1])     # rows as wide as the pool's
     if _MP_SCOPE[0] is not None:
-        return _flash_sharded(_paged_flash_pallas, q, (k_pages, v_pages),
-                              (), page_table, seq_lens, scale, interpret)
-    return _paged_flash_pallas(q, k_pages, v_pages, page_table, seq_lens,
-                               scale, interpret)
+        out = _flash_sharded(_paged_flash_pallas, q, pools, (), page_table,
+                             seq_lens, scale, interpret, layer)
+    else:
+        out = _paged_flash_pallas(q, *pools, page_table, seq_lens, scale,
+                                  interpret, layer)
+    return out[..., :D]
 
 
 # --------------------------------------------------------- decode-loop utils
@@ -514,55 +556,33 @@ def paged_decode_attend(q, k_pages, v_pages, pos, scale=None):
 
 # ------------------------------------------------- serving-engine utils
 # Table-addressed variants for the continuous-batching engine
-# (paddle_tpu.serving): ONE global pool [P, ps, h, d] shared by every
-# sequence through an explicit page table, and PER-SLOT lengths — each slot
-# decodes at its own position, which is what iteration-level batching needs
-# (the lock-step helpers above share one scalar ``pos`` across the batch).
+# (paddle_tpu.serving): ONE global pool shared by every sequence through an
+# explicit page table, and PER-SLOT lengths — each slot decodes at its own
+# position, which is what iteration-level batching needs (the lock-step
+# helpers above share one scalar ``pos`` across the batch).
+#
+# The engine's pool holds every layer, stacked: [L, P, ps, h, d].  Each
+# entry here takes ``layer`` and reads or writes THAT layer of the stacked
+# pool where it lies (``layer=None``: the pool is one layer's [P, ps, h, d]).
+# Nothing slices a layer out or stacks layers back: the kernels get the
+# layer as a leading block dimension of one at a fixed block index, the
+# dense fall-backs gather ``pool[layer, table]``, and a write touches the
+# rows it writes.  Three writers, one mechanism: a chunk of C tokens per
+# slot at the slot's own position (a decode token is a chunk of one, a whole
+# prompt a chunk at length zero) — a scatter off the TPU, and on it one
+# Pallas call a layer over the whole pool tuple, aliased in and out
+# (``_paged_write_pallas`` under ``paged_pool_write``).
 
 
-def paged_table_prefill_write(pool, kv, table):
-    """Write whole prompts into their table pages at position 0.
-
-    pool: [P, ps, *rest]; kv: [B, S, *rest]; table: [B, NP] int32.  S is a
-    trace-time constant; each row's S tokens land in pages
-    ``table[b, 0:ceil(S/ps)]`` (rows shorter than S are right-padded by the
-    caller — the junk tokens go into pages that per-slot ``seq_lens``
-    masking keeps invisible, or into the caller's scratch page).  The
-    trailing dims are generic: K/V payload pools carry ``[h, d]``, the
-    quantized path's scale pools carry ``[h]``."""
-    B, S = kv.shape[:2]
-    rest = kv.shape[2:]
-    ps = pool.shape[1]
-    pad = (ps - S % ps) % ps
-    if pad:
-        kv = jnp.pad(kv, ((0, 0), (0, pad)) + ((0, 0),) * len(rest))
-    chunks = kv.reshape((B, -1, ps) + rest)
-    nc = chunks.shape[1]
-    idx = table[:, :nc].reshape(-1)
-    return pool.at[idx].set(
-        chunks.reshape((B * nc, ps) + rest).astype(pool.dtype))
-
-
-def paged_table_token_write(pool, tok, table, lens):
-    """Write one token's K or V per slot at each slot's OWN position.
-
-    pool: [P, ps, *rest]; tok: [B, *rest]; table: [B, NP]; lens: [B] int32
-    — slot b's token lands in page ``table[b, lens[b]//ps]`` slot
-    ``lens[b]%ps``.  All args may be traced (scatter write)."""
-    B = tok.shape[0]
-    ps = pool.shape[1]
-    lens = lens.astype(jnp.int32)
-    pages = table[jnp.arange(B, dtype=jnp.int32), lens // ps]
-    return pool.at[pages, lens % ps].set(tok.astype(pool.dtype))
-
-
-def paged_table_chunk_write(pool, kv, table, lens):
+def paged_table_chunk_write(pool, kv, table, lens, layer=None):
     """Write a CHUNK of C tokens per slot at positions ``lens[b] ..
-    lens[b]+C-1`` (speculative verify: the last sampled token plus C-1
-    draft tokens land in one call).
+    lens[b]+C-1`` (a prefill chunk; speculative verify: the last sampled
+    token plus C-1 draft tokens land in one call).
 
-    pool: [P, ps, *rest]; kv: [B, C, *rest]; table: [B, NP]; lens: [B]
-    int32.
+    pool: [P, ps, *rest], or with ``layer`` the stacked [L, P, ps, *rest]
+    of which only that layer's rows are written; kv: [B, C, *rest]; table:
+    [B, NP]; lens: [B] int32.  The trailing dims are generic: K/V payload
+    pools carry ``[h, d]``, the quantized path's scale pools ``[h]``.
     Lanes past the table's reach (pad drafts of a slot near the model cap)
     are DROPPED, not clamped: a clamp would make the pad lane collide with
     the chunk's own last real write in the same scatter, and duplicate-
@@ -573,16 +593,45 @@ def paged_table_chunk_write(pool, kv, table, lens):
     overwrites them."""
     B, C = kv.shape[:2]
     rest = kv.shape[2:]
-    ps = pool.shape[1]
+    at = () if layer is None else (layer,)
+    ps = pool.shape[len(at) + 1]
     NP = table.shape[1]
     pos = lens.astype(jnp.int32)[:, None] \
         + jnp.arange(C, dtype=jnp.int32)[None, :]            # [B, C]
     in_range = pos < jnp.int32(NP * ps)
     pos_c = jnp.minimum(pos, jnp.int32(NP * ps - 1))
     pages = jnp.take_along_axis(table.astype(jnp.int32), pos_c // ps, axis=1)
-    pages = jnp.where(in_range, pages, jnp.int32(-1))  # OOB sentinel
-    return pool.at[pages.reshape(-1), (pos_c % ps).reshape(-1)].set(
+    # the sentinel is one past the last page: -1 would wrap to it
+    pages = jnp.where(in_range, pages, jnp.int32(pool.shape[len(at)]))
+    return pool.at[(*at, pages.reshape(-1), (pos_c % ps).reshape(-1))].set(
         kv.reshape((B * C,) + rest).astype(pool.dtype), mode="drop")
+
+
+def paged_table_token_write(pool, tok, table, lens, layer=None):
+    """Write one token's K or V per slot at each slot's OWN position: a
+    chunk of one.  tok: [B, *rest] — slot b's token lands in page
+    ``table[b, lens[b]//ps]`` slot ``lens[b]%ps``."""
+    return paged_table_chunk_write(pool, tok[:, None], table, lens, layer)
+
+
+def paged_table_prefill_write(pool, kv, table, layer=None):
+    """Write whole prompts into their table pages at position 0: a chunk
+    at length zero, of whole pages.  kv: [B, S, *rest], S a trace-time
+    constant; each row's S tokens land in pages ``table[b, 0:ceil(S/ps)]``,
+    the last one filled up with zeros (rows shorter than S are right-padded
+    by the caller — the junk tokens go into pages that per-slot
+    ``seq_lens`` masking keeps invisible, or into the caller's scratch
+    page)."""
+    ps = pool.shape[1 if layer is None else 2]
+    return paged_table_chunk_write(
+        pool, _pad_to_pages(kv, ps), table,
+        jnp.zeros((kv.shape[0],), jnp.int32), layer)
+
+
+def _pad_to_pages(kv, page_size):
+    """kv [B, S, *rest] zero-padded along S to whole pages."""
+    pad = -kv.shape[1] % page_size
+    return jnp.pad(kv, ((0, 0), (0, pad)) + ((0, 0),) * (kv.ndim - 2))
 
 
 # ---------------------------------------------------------- chunk attention
@@ -604,8 +653,7 @@ def paged_table_chunk_write(pool, kv, table, lens):
 # table), so the two products of a step have a full contraction tile.
 # Position t of slot b sees keys 0 .. lens[b]+t: one mask per step replaces
 # the per-row seq_len, and the sweep stops at the page of the tile's LAST
-# position (same re-present-the-last-page clamp as _bounded_page_map).
-_LANES = 128
+# position (same re-present-the-last-page clamp as _bounded_page_spec).
 
 
 def _chunk_blocking(q, k_pages, NP):
@@ -617,7 +665,7 @@ def _chunk_blocking(q, k_pages, NP):
     ask Mosaic for where a wide model needs more than its 16 MiB default
     (``None``: the default does)."""
     _, C, H, D = q.shape
-    _, page_size, HKV, _ = k_pages.shape
+    page_size, HKV = k_pages.shape[-3:-1]
     kv_bytes = k_pages.dtype.itemsize
     Cp = -(-C // _LANES) * _LANES
     pages = max(1, min(_LANES // page_size, 8, NP))
@@ -739,14 +787,16 @@ def _paged_chunk_kernel(pt_ref, lens_ref, q_ref, *refs, page_size, scale,
 
 
 def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
-                        name=None):
-    """q [B, C, H, D] against ``pools`` (K, V: [P, ps, HKV, D]) and, for
-    int8 pools, ``scales`` (K, V: [P, ps, HKV]) -> [B, C, H, D]."""
+                        layer, name=None):
+    """q [B, C, H, D] against layer ``layer`` of the stacked ``pools`` (K,
+    V: [L, P, ps, HKV, D]) and, for int8 pools, ``scales`` (K, V:
+    [L, P, ps, HKV]) -> [B, C, H, D].  As in :func:`_bounded_page_spec`
+    the layer is a squeezed block dimension at a fixed index."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, C, H, D = q.shape
-    page_size, HKV = pools[0].shape[1:3]
+    page_size, HKV = pools[0].shape[2:4]
     NP = table.shape[1]
     Cp, tile, pages, vmem_limit = _chunk_blocking(q, pools[0], NP)
     qt = jnp.transpose(jnp.pad(q, ((0, 0), (0, Cp - C), (0, 0), (0, 0))),
@@ -755,8 +805,9 @@ def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
     def page_map(r, rank):
         def idx(b, j, i, pt, ln):
             last = _chunk_last_key(ln[b], j, tile, C, NP * page_size)
-            return (pt[b, jnp.minimum(i * pages + r, last // page_size)],) \
-                + (0,) * (rank - 1)
+            return (layer,
+                    pt[b, jnp.minimum(i * pages + r, last // page_size)]) \
+                + (0,) * (rank - 2)
         return idx
 
     paged = (*pools, *(a.astype(jnp.float32) for a in scales))
@@ -766,7 +817,7 @@ def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
         num_scalar_prefetch=2,
         grid=(B, Cp // tile, -(-NP // pages)),
         in_specs=[q_spec] + [
-            pl.BlockSpec((1,) + a.shape[1:], page_map(r, a.ndim))
+            pl.BlockSpec((None, 1) + a.shape[2:], page_map(r, a.ndim))
             for a in paged for r in range(pages)],
         out_specs=q_spec,
         scratch_shapes=[
@@ -799,27 +850,35 @@ def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
 
 
 def _paged_chunk_flash_pallas(q, k_pages, v_pages, table, lens, scale,
-                              interpret):
+                              interpret, layer):
     return _paged_chunk_pallas(q, (k_pages, v_pages), (), table, lens, scale,
-                               interpret)
+                               interpret, layer)
 
 
 def _paged_chunk_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
-                                table, lens, scale, interpret):
+                                table, lens, scale, interpret, layer):
     return _paged_chunk_pallas(q, (k_pages, v_pages), (k_scales, v_scales),
-                               table, lens, scale, interpret,
+                               table, lens, scale, interpret, layer,
                                name="paged_chunk_q")
 
 
-def _chunk_attend(pallas_fn, q, pools, scales, table, lens):
+def _chunk_attend(pallas_fn, q, pools, scales, table, lens, layer):
     """The TPU side of both chunk entries: the chunk kernel under the
     scope the benchmark reads it by, head-sharded under an mp scope."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    D = q.shape[-1]
+    scale = 1.0 / math.sqrt(D)
+    n = len(pools)
+    stacked, layer = _as_stack((*pools, *scales), layer)
+    pools, scales = stacked[:n], stacked[n:]
+    q = _to_lanes(q, pools[0].shape[-1])    # rows as wide as the pool's
     with jax.named_scope("chunk_attention"):
         if _MP_SCOPE[0] is not None:
-            return _flash_sharded(pallas_fn, q, pools, scales, table, lens,
-                                  scale, False)
-        return pallas_fn(q, *pools, *scales, table, lens, scale, False)
+            out = _flash_sharded(pallas_fn, q, pools, scales, table, lens,
+                                 scale, False, layer)
+        else:
+            out = pallas_fn(q, *pools, *scales, table, lens, scale, False,
+                            layer)
+    return out[..., :D]
 
 
 def _chunk_lens(lens, C, capacity):
@@ -830,8 +889,9 @@ def _chunk_lens(lens, C, capacity):
     return jnp.minimum(lens2, jnp.int32(capacity))
 
 
-def paged_chunk_attend(q, k_pages, v_pages, table, lens):
-    """Attend C query positions per slot against the global paged pools:
+def paged_chunk_attend(q, k_pages, v_pages, table, lens, layer=None):
+    """Attend C query positions per slot against the global paged pools
+    (with ``layer``: that layer of the stacked pools, in place):
     position t of slot b sees tokens ``0 .. lens[b]+t`` (its own K/V
     included — the chunk is written before attending, and within-chunk
     causality falls out of the per-position valid lengths).
@@ -845,13 +905,12 @@ def paged_chunk_attend(q, k_pages, v_pages, table, lens):
     B, C, H, D = q.shape
     if jax.default_backend() == "tpu":
         return _chunk_attend(_paged_chunk_flash_pallas, q,
-                             (k_pages, v_pages), (), table, lens)
+                             (k_pages, v_pages), (), table, lens, layer)
     NP = table.shape[1]
-    ps = k_pages.shape[1]
-    HKV = k_pages.shape[2]
+    ps, HKV = k_pages.shape[-3:-1]
     lens2 = _chunk_lens(lens, C, NP * ps)
-    k = k_pages[table].reshape(B, NP * ps, HKV, D)
-    v = v_pages[table].reshape(B, NP * ps, HKV, D)
+    k, v = (_gather_pages(p, table, layer)[..., :D].reshape(
+        B, NP * ps, HKV, D) for p in (k_pages, v_pages))
     return _gathered_chunk_attend(q, k, v, lens2, 1.0 / math.sqrt(D))
 
 
@@ -885,31 +944,32 @@ def quantize_kv(kv, bits=8):
     return q, jnp.squeeze(scale, -1)
 
 
-def paged_table_prefill_write_quant(pool, spool, kv, table):
+def paged_table_prefill_write_quant(pool, spool, kv, table, layer=None):
     """Quantizing twin of :func:`paged_table_prefill_write`: rounds the
     prompt's K or V into the int8 pool AND writes the per-(slot, head)
     scale tiles into the parallel scale pool.  pool: [P, ps, h, d] int8;
-    spool: [P, ps, h] f32; kv: [B, S, h, d]; returns (pool, spool)."""
+    spool: [P, ps, h] f32 (with ``layer``: both stacked); kv: [B, S, h, d];
+    returns (pool, spool)."""
     qv, sc = quantize_kv(kv)
-    return (paged_table_prefill_write(pool, qv, table),
-            paged_table_prefill_write(spool, sc, table))
+    return (paged_table_prefill_write(pool, qv, table, layer),
+            paged_table_prefill_write(spool, sc, table, layer))
 
 
-def paged_table_token_write_quant(pool, spool, tok, table, lens):
+def paged_table_token_write_quant(pool, spool, tok, table, lens, layer=None):
     """Quantizing twin of :func:`paged_table_token_write` (one token per
     slot at its own position).  tok: [B, h, d]; returns (pool, spool)."""
     qv, sc = quantize_kv(tok)
-    return (paged_table_token_write(pool, qv, table, lens),
-            paged_table_token_write(spool, sc, table, lens))
+    return (paged_table_token_write(pool, qv, table, lens, layer),
+            paged_table_token_write(spool, sc, table, lens, layer))
 
 
-def paged_table_chunk_write_quant(pool, spool, kv, table, lens):
+def paged_table_chunk_write_quant(pool, spool, kv, table, lens, layer=None):
     """Quantizing twin of :func:`paged_table_chunk_write` (speculative
     verify: C tokens per slot in one scatter, same drop-OOB semantics).
     kv: [B, C, h, d]; returns (pool, spool)."""
     qv, sc = quantize_kv(kv)
-    return (paged_table_chunk_write(pool, qv, table, lens),
-            paged_table_chunk_write(spool, sc, table, lens))
+    return (paged_table_chunk_write(pool, qv, table, lens, layer),
+            paged_table_chunk_write(spool, sc, table, lens, layer))
 
 
 def _paged_q_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
@@ -1017,7 +1077,7 @@ def _paged_q_flash_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref,
                           page_size, scale, num_kv_heads):
     """Length-bounded twin of :func:`_paged_q_kernel`: the flash sweep
     clamp of :func:`_paged_flash_kernel` with dequant fused into the page
-    loads — int8 engines (``served_q``/``served_chunk_q``) ride the same
+    loads — int8 engines ride the same
     dead-page elision."""
     from jax.experimental import pallas as pl
 
@@ -1053,25 +1113,25 @@ def _paged_q_flash_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref,
 
 
 def _paged_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
-                          page_table, seq_lens, scale, interpret):
+                          page_table, seq_lens, scale, interpret, layer):
+    """q [B, H, D] against layer ``layer`` of the stacked int8 pools
+    [L, P, ps, HKV, D] and their scale pools [L, P, ps, HKV]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
-    HKV = k_pages.shape[2]
-    page_size = k_pages.shape[1]
+    HKV = k_pages.shape[3]
     NP = page_table.shape[1]
+    k_scales = k_scales.astype(jnp.float32)
+    v_scales = v_scales.astype(jnp.float32)
 
-    page_spec = pl.BlockSpec((1, page_size, HKV, D),
-                             _bounded_page_map(page_size))
-    scale_spec = pl.BlockSpec((1, page_size, HKV),
-                              _bounded_scale_map(page_size))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, NP),
         in_specs=[
             pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0)),
-            page_spec, page_spec, scale_spec, scale_spec,
+            *(_bounded_page_spec(a, layer)
+              for a in (k_pages, v_pages, k_scales, v_scales)),
         ],
         out_specs=pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0)),
         scratch_shapes=[
@@ -1083,8 +1143,9 @@ def _paged_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
     # x64 OFF for the same Mosaic i64-index reason as _paged_pallas
     with jax.enable_x64(False):
         out = pl.pallas_call(
-            functools.partial(_paged_q_flash_kernel, page_size=page_size,
-                              scale=scale, num_kv_heads=HKV),
+            functools.partial(_paged_q_flash_kernel,
+                              page_size=k_pages.shape[2], scale=scale,
+                              num_kv_heads=HKV),
             name="paged_decode_q",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
@@ -1092,61 +1153,271 @@ def _paged_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
         )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-          q, k_pages, v_pages, k_scales.astype(jnp.float32),
-          v_scales.astype(jnp.float32))
+          q, k_pages, v_pages, k_scales, v_scales)
     return out.astype(q.dtype)
 
 
+def _gather_dequant(pages, scales, table, layer, D):
+    """A table's int8 pages (their first ``D`` lanes) and their scale
+    tiles, gathered and dequantized to f32 [B, NP * ps, HKV, D]: a
+    transient [B, T] working set, never a full pool copy."""
+    x = _gather_pages(pages, table, layer)[..., :D].astype(jnp.float32) \
+        * _gather_pages(scales, table, layer).astype(jnp.float32)[..., None]
+    return x.reshape((table.shape[0], -1) + x.shape[-2:])
+
+
+def _pool_rows(pools, k, v):
+    """K and V ``[B, C, h, d]`` as the rows the pool tuple stores, one
+    array per pool: themselves for ``(kp, vp)``; for the int8 path's
+    ``(kp, vp, ks, vs)`` rounded onto the int8 grid, with their scales.
+    Payload rows are filled up with zeros to the pools' row width
+    (:func:`pool_lane_dim`)."""
+    width = pools[0].shape[-1]          # whole lanes of the head size
+    if len(pools) == 2:
+        return _to_lanes(k, width), _to_lanes(v, width)
+    (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+    return _to_lanes(k, width), _to_lanes(v, width), ks, vs
+
+
+def _write_page(pt_ref, lens_ref, b, i, page_size, chunk, table_pages):
+    """``(page, first lane)`` of write step ``i`` of slot ``b``: the i-th
+    page the slot's chunk touches, and the chunk lane that lands in the
+    page's slot 0 (negative in the chunk's first page, which it enters part
+    of the way down).  Steps past the chunk's last page, or past the
+    table's reach, re-present the last page they may touch, so they are
+    the same merge once more and dropped lanes are never visited; a slot
+    wholly out of reach presents the table's last page and merges nothing
+    into it."""
+    seq_len = lens_ref[b]
+    last = jnp.minimum((seq_len + chunk - 1) // page_size, table_pages - 1)
+    j = jnp.minimum(seq_len // page_size + i, last)
+    return pt_ref[b, j], j * page_size - seq_len
+
+
+def _paged_write_kernel(pt_ref, lens_ref, layer_ref, *refs, page_size, chunk,
+                        table_pages):
+    """Grid (slot b, page step i); the layer is the index maps' business.
+    ``refs``: for each pool of the tuple
+    the ``page_size`` rows of the (padded) chunk that line up with the
+    page's slots, then each pool's page as it is, then each pool's page as
+    it shall be (the same HBM: the pools are aliased in and out).  A page
+    is loaded, the chunk's lanes are merged into it by a position mask,
+    and it is stored; nothing else of the pool is touched."""
+    from jax.experimental import pallas as pl
+
+    n = len(refs) // 3
+    new_refs, old_refs, out_refs = refs[:n], refs[n:2 * n], refs[2 * n:]
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+    steps = pl.num_programs(1)
+    where = functools.partial(_write_page, pt_ref, lens_ref,
+                              page_size=page_size, chunk=chunk,
+                              table_pages=table_pages)
+    page, first = where(b, i)
+    # A page is fetched, and written back, when the block index CHANGES
+    # from one grid step to the next.  Where the step before presented the
+    # same page (a repeated last page; two table entries that are one page,
+    # as the scratch page is), its merge is still in the output block and
+    # the input block is stale: merge into the output block then.  (A page
+    # that two steps APART present — the scratch page under idle slots —
+    # keeps whichever merge lands last: it holds junk either way.)
+    before = where(jnp.maximum(jnp.where(i == 0, b - 1, b), 0),
+                   jnp.where(i == 0, steps - 1, i - 1))[0]
+    fresh = ((b == 0) & (i == 0)) | (before != page)
+
+    def merge(base_refs):
+        for new_ref, base_ref, out_ref in zip(new_refs, base_refs, out_refs):
+            shape = out_ref.shape[1:]                      # [ps, h(, d)]
+            lane = first + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            valid = (lane >= 0) & (lane < chunk)
+            if len(shape) == 3:                            # payload rows
+                out_ref[0] = jnp.where(valid, new_ref[0], base_ref[0])
+            else:       # scale rows, handed over one [1, h] tile a row
+                for r in range(page_size):
+                    out_ref[0, r:r + 1, :] = jnp.where(
+                        valid[r:r + 1], new_ref[0, r], base_ref[0, r:r + 1, :])
+
+    pl.when(fresh)(lambda: merge(old_refs))
+    pl.when(jnp.logical_not(fresh))(lambda: merge(out_refs))
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _paged_write_pallas(pools, rows, table, lens, interpret, layer):
+    """``rows`` (one ``[B, C, h(, d)]`` array per pool) into layer ``layer``
+    of the stacked ``pools`` at positions ``lens[b] .. lens[b]+C-1``, one
+    Pallas call for the whole tuple with every pool aliased to its output:
+    the only operations that touch a pool are kernels, each at its pages
+    (a scatter into the stacked pool made XLA copy the donated pool before
+    the first write and re-lay it around the kernels).  The layer rides as
+    a third prefetched scalar and the call is a jitted function of its
+    shapes, so a program traces and lowers ONE writer for all its layers
+    (a kernel with the layer baked in costs that once a layer: 2 s more of
+    set-up for each serving program)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, C = rows[0].shape[:2]
+    page_size = pools[0].shape[2]
+    NP = table.shape[1]
+    where = functools.partial(_write_page, page_size=page_size, chunk=C,
+                              table_pages=NP)
+
+    def row_spec(x):
+        # the rows that meet a page's slots start at any lane of the chunk,
+        # so this operand is indexed by ELEMENT (every dimension, as Mosaic
+        # wants it), over a chunk padded by a page at both ends; a row is a
+        # MAJOR dimension there (the scales get a unit one behind it)
+        def idx(b, i, pt, ln, ly):
+            first = where(pt, ln, b, i)[1]
+            return (b, jnp.clip(first + page_size, 0, C + page_size)) \
+                + (0,) * (x.ndim - 2)
+        return pl.BlockSpec(
+            tuple(pl.Element(d) for d in (1, page_size) + x.shape[2:]), idx)
+
+    def page_spec(pool):
+        def idx(b, i, pt, ln, ly):
+            return (ly[0], where(pt, ln, b, i)[0]) + (0,) * (pool.ndim - 2)
+        return pl.BlockSpec((None, 1) + pool.shape[2:], idx)
+
+    padded = []
+    for x, pool in zip(rows, pools):
+        x = jnp.pad(x.astype(pool.dtype), ((0, 0), (page_size, page_size))
+                    + ((0, 0),) * (x.ndim - 2))
+        padded.append(x if x.ndim == 4 else x[:, :, None, :])
+    n = len(pools)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, (C + page_size - 2) // page_size + 1),
+        in_specs=[row_spec(x) for x in padded]
+        + [page_spec(pool) for pool in pools],
+        out_specs=[page_spec(pool) for pool in pools],
+    )
+    # x64 OFF for the same Mosaic i64-index reason as _paged_pallas.  The
+    # name keeps this call out of ``paged_decode_roofline``, which sums the
+    # decode program's kernels that carry none.
+    with jax.enable_x64(False):
+        return tuple(pl.pallas_call(
+            functools.partial(_paged_write_kernel, page_size=page_size,
+                              chunk=C, table_pages=NP),
+            name="paged_write",
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+            input_output_aliases={3 + n + k: k for k in range(n)},
+            interpret=interpret,
+            # sequential: a step may merge into the page the step before
+            # it left in the output block
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+        )(table.astype(jnp.int32), lens.astype(jnp.int32),
+          jnp.asarray(layer, jnp.int32).reshape(1), *padded, *pools))
+
+
+def _write_sharded(pools, rows, table, lens, layer):
+    """shard_map wrapper of the writer under an mp scope: pools
+    ``[L, P, ps, h, ...]`` and rows ``[B, C, h, ...]`` shard the head dim,
+    table/lens replicate, as in :func:`_flash_sharded`."""
+    from jax.sharding import PartitionSpec as P
+
+    mesh, ax = _MP_SCOPE[0]
+    n = len(pools)
+
+    def heads_at(x, dim):
+        return P(*(ax if d == dim else None for d in range(x.ndim)))
+
+    pool_specs = tuple(heads_at(p, 3) for p in pools)
+
+    def local(*a):
+        return _paged_write_pallas(a[:n], a[n:2 * n], a[-2], a[-1], False,
+                                   layer)
+
+    f = jax.shard_map(
+        local, mesh=mesh, out_specs=pool_specs, check_vma=False,
+        in_specs=pool_specs + tuple(heads_at(x, 2) for x in rows)
+        + (P(), P()))
+    return f(*pools, *rows, table, lens)
+
+
+def _pool_write(pools, rows, table, lens, layer):
+    if jax.default_backend() == "tpu":
+        if _MP_SCOPE[0] is not None:
+            return _write_sharded(pools, rows, table, lens, layer)
+        return _paged_write_pallas(pools, rows, table, lens, False, layer)
+    return tuple(paged_table_chunk_write(pool, x, table, lens, layer)
+                 for pool, x in zip(pools, rows))
+
+
+def paged_pool_write(pools, k, v, table, lens, layer):
+    """One layer's K and V chunk ``[B, C, h, d]`` into the serving engine's
+    stacked pool tuple at positions ``lens[b] .. lens[b]+C-1`` of each slot
+    (a decode token is a chunk of one): ``(kp, vp)``, each
+    [L, P, ps, h, d], or with the int8 path's scale pools
+    ``(kp, vp, ks, vs)``, for which K and V are quantized on the way in.
+    Only the rows written move: every other layer and page of the pools
+    stays where it lies (:func:`paged_table_chunk_write` says which lanes
+    are dropped).  Returns the pool tuple."""
+    return _pool_write(pools, _pool_rows(pools, k, v), table, lens, layer)
+
+
+def paged_pool_prefill_write(pools, k, v, table, layer):
+    """Whole prompts ``[B, S, h, d]`` into their table pages at position 0:
+    a chunk at length zero, filled up to whole pages with zeros (the
+    :func:`paged_table_prefill_write` contract)."""
+    rows = tuple(_pad_to_pages(x, pools[0].shape[2])
+                 for x in _pool_rows(pools, k, v))
+    return _pool_write(pools, rows, table,
+                       jnp.zeros((k.shape[0],), jnp.int32), layer)
+
+
 def paged_attention_quantized_ref(q, k_pages, v_pages, k_scales, v_scales,
-                                  page_table, seq_lens, scale=None):
+                                  page_table, seq_lens, scale=None,
+                                  layer=None):
     """Dense-gather oracle/fallback for the quantized pools: gather the
     int8 pages AND their scale tiles, dequantize the gathered working set
     (transient [B, T] — never a full pool copy), then the shared reference
     math."""
-    B, H, D = q.shape
-    HKV = k_pages.shape[2]
-    page_size = k_pages.shape[1]
-    NP = page_table.shape[1]
+    D = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    k = k_pages[page_table].astype(jnp.float32) \
-        * k_scales[page_table].astype(jnp.float32)[..., None]
-    v = v_pages[page_table].astype(jnp.float32) \
-        * v_scales[page_table].astype(jnp.float32)[..., None]
-    k = k.reshape(B, NP * page_size, HKV, D)
-    v = v.reshape(B, NP * page_size, HKV, D)
-    return _gathered_attend(q, k, v, seq_lens, scale)
+    return _gathered_attend(
+        q, _gather_dequant(k_pages, k_scales, page_table, layer, D),
+        _gather_dequant(v_pages, v_scales, page_table, layer, D), seq_lens,
+        scale)
 
 
 def paged_attention_quantized(q, k_pages, v_pages, k_scales, v_scales,
                               page_table, seq_lens, scale=None,
-                              interpret=None):
+                              interpret=None, layer=None):
     """Decode attention over int8 paged pools with dequant fused into the
     kernel (see the section comment above).
 
     q [B, H, D]; k_pages/v_pages [P, ps, HKV, D] int8; k_scales/v_scales
-    [P, ps, HKV] f32; page_table [B, NP] int32; seq_lens [B] int32.  Same
+    [P, ps, HKV] f32 (with ``layer``: that layer of pools stacked
+    ``[L, ...]``); page_table [B, NP] int32; seq_lens [B] int32.  Same
     table/masking/GQA contract as :func:`paged_attention`."""
     B, H, D = q.shape
-    if H % k_pages.shape[2]:
+    if H % k_pages.shape[-2]:
         raise ValueError(f"q heads {H} not a multiple of kv heads "
-                         f"{k_pages.shape[2]}")
+                         f"{k_pages.shape[-2]}")
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     if interpret is None:
         if jax.default_backend() != "tpu":
             return paged_attention_quantized_ref(
                 q, k_pages, v_pages, k_scales, v_scales, page_table,
-                seq_lens, scale)
+                seq_lens, scale, layer)
         interpret = False
+    stacked, layer = _as_stack((k_pages, v_pages, k_scales, v_scales), layer)
+    q = _to_lanes(q, k_pages.shape[-1])
     if _MP_SCOPE[0] is not None:
-        return _flash_sharded(_paged_q_flash_pallas, q, (k_pages, v_pages),
-                              (k_scales, v_scales), page_table, seq_lens,
-                              scale, interpret)
-    return _paged_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
-                                 page_table, seq_lens, scale, interpret)
+        out = _flash_sharded(_paged_q_flash_pallas, q, stacked[:2],
+                             stacked[2:], page_table, seq_lens, scale,
+                             interpret, layer)
+    else:
+        out = _paged_q_flash_pallas(q, *stacked, page_table, seq_lens, scale,
+                                    interpret, layer)
+    return out[..., :D]
 
 
 def paged_chunk_attend_quant(q, k_pages, v_pages, k_scales, v_scales,
-                             table, lens):
+                             table, lens, layer=None):
     """Quantized twin of :func:`paged_chunk_attend` (chunks over int8
     pools): the same chunk kernel with the dequantizing page loads on TPU,
     one gather + dequant per slot elsewhere.
@@ -1155,18 +1426,11 @@ def paged_chunk_attend_quant(q, k_pages, v_pages, k_scales, v_scales,
     if jax.default_backend() == "tpu":
         return _chunk_attend(_paged_chunk_q_flash_pallas, q,
                              (k_pages, v_pages), (k_scales, v_scales),
-                             table, lens)
-    NP = table.shape[1]
-    ps = k_pages.shape[1]
-    HKV = k_pages.shape[2]
-    lens2 = _chunk_lens(lens, C, NP * ps)
-    # transient [B, T] working set, as in paged_attention_quantized_ref
-    k = k_pages[table].astype(jnp.float32) \
-        * k_scales[table].astype(jnp.float32)[..., None]
-    v = v_pages[table].astype(jnp.float32) \
-        * v_scales[table].astype(jnp.float32)[..., None]
+                             table, lens, layer)
+    lens2 = _chunk_lens(lens, C, table.shape[1] * k_pages.shape[-3])
     return _gathered_chunk_attend(
-        q, k.reshape(B, NP * ps, HKV, D), v.reshape(B, NP * ps, HKV, D),
+        q, _gather_dequant(k_pages, k_scales, table, layer, D),
+        _gather_dequant(v_pages, v_scales, table, layer, D),
         lens2, 1.0 / math.sqrt(D)).astype(q.dtype)
 
 

@@ -4,11 +4,28 @@ An adapter reduces a causal LM to closures over explicit jax state (the
 engine wraps them in ``jax.jit`` with DONATED pools, once per
 (batch-shape, sampler) tuple — the ``_decode.py`` discipline).  The KV
 state is an adapter-defined POOL TUPLE of ``n_pools`` arrays: the base
-:class:`GPTAdapter` carries ``(kp, vp)`` per-layer global page pools; the
-quantized :class:`~paddle_tpu.serving.quant.QuantizedGPTAdapter` carries
+:class:`GPTAdapter` carries ``(kp, vp)`` global page pools, every layer
+stacked in each; the quantized
+:class:`~paddle_tpu.serving.quant.QuantizedGPTAdapter` carries
 ``(kp, vp, k_scales, v_scales)`` — int8 payloads plus parallel scale
 pools.  The engine treats the tuple opaquely (build, donate, rebind), so
 one scheduler serves every pool layout.
+
+The pools are served IN PLACE.  A closure hands the model ONE cache,
+``(tag, pools, table, lens)``, and every decoder layer reads and writes
+the stacked pools at its own index (``ops.paged_attention``: the kernels'
+block specs take the layer as a leading block dimension of one, the writer
+merges a chunk's rows into the pages it touches through aliased operands)
+and hands the tuple to the next: no program slices a layer out of a pool
+or stacks layers back.  On the TPU a payload pool's rows are as wide as
+the chip's lanes (``ops.paged_attention.pool_lane_dim``: a ``d`` of 64 is
+stored 128 wide, zeros behind it), which is how the device holds a
+``[ps, h, d]`` page for the kernels anyway; said in the SHAPE, the
+device's own layout of the pool is the one the kernels take, and with the
+pools donated a compiled program holds no operation over a whole pool,
+only its kernels' pages (where the heads of one device fill its sublane
+tiles — 16 rows of bf16, 8 of f32; otherwise XLA still converts the pool
+on the way in and out: ROADMAP, Speed).
 
 - ``prefill(params, bufs, ids, *pools, table, lens)`` — run the
   (right-padded) prompts ``ids [B, S]`` densely, write their K/V into the
@@ -42,11 +59,13 @@ import jax.numpy as jnp
 class GPTAdapter:
     """Adapter for :class:`paddle_tpu.text.models.GPTForCausalLM` (and any
     model exposing the same ``.gpt`` decoder structure with the "served"
-    cache variant).  Subclasses override the pool hooks (``init_pools`` /
-    ``_layer_caches`` / ``_stack_pools``) and the cache tags to change the
-    KV storage format without touching the closure shapes."""
+    cache variant).  Subclasses override the pool hooks (``init_pools``,
+    ``page_bytes``, ``pool_owners``, ``pool_pspecs``) to change the KV
+    storage format without touching the closure shapes: the model's served
+    branch tells the formats apart by the pool tuple it is handed."""
 
-    #: GPTDecoderLayer cache-variant tags this adapter drives
+    #: GPTDecoderLayer cache-variant tags this adapter drives: one token or
+    #: a whole prompt per slot, and a chunk at the slot's own position
     tag = "served"
     chunk_tag = "served_chunk"
     #: number of arrays in the pool tuple (the engine donates all of them)
@@ -137,9 +156,12 @@ class GPTAdapter:
 
     # ----------------------------------------------------------- pool hooks
     def init_pools(self, num_pages):
-        """Zeroed per-layer K/V pools ``(kp, vp)``, each [L, P, ps, h, d]."""
+        """Zeroed K/V pools ``(kp, vp)``, each [L, P, ps, h, d]: every
+        layer's pages in one array, ``d`` the head size in whole lanes."""
+        from ..ops.paged_attention import pool_lane_dim
+
         shape = (self.num_layers, int(num_pages), self.page_size,
-                 self.num_kv_heads, self.head_dim)
+                 self.num_kv_heads, pool_lane_dim(self.head_dim))
         kp = jnp.zeros(shape, self.dtype)
         return kp, jnp.zeros_like(kp)
 
@@ -147,8 +169,11 @@ class GPTAdapter:
         """HBM bytes ONE page costs across all layers, K and V (the unit
         BlockManager capacity math and the serving.kv_bytes_per_token
         gauge are denominated in)."""
+        from ..ops.paged_attention import pool_lane_dim
+
         return (2 * self.num_layers * self.page_size * self.num_kv_heads
-                * self.head_dim * jnp.dtype(self.dtype).itemsize)
+                * pool_lane_dim(self.head_dim)
+                * jnp.dtype(self.dtype).itemsize)
 
     def pool_owners(self):
         """Memory-ledger owner labels over the pool tuple: ``(owner,
@@ -156,19 +181,6 @@ class GPTAdapter:
         engine's ledger registration attributes payload and scale pools
         separately (observability.memory owner taxonomy)."""
         return (("kv.pages", tuple(range(self.n_pools))),)
-
-    def _layer_caches(self, pools, table, lens, tag):
-        """Per-layer GPTDecoderLayer cache tuples from the pool tuple."""
-        from ..tensor.tensor import Tensor
-
-        kp, vp = pools
-        return [(tag, Tensor(kp[i]), Tensor(vp[i]), Tensor(table),
-                 Tensor(lens)) for i in range(self.num_layers)]
-
-    def _stack_pools(self, new_cache):
-        """Re-stack the per-layer cache tuples into the pool tuple."""
-        return (jnp.stack([c[1]._value for c in new_cache]),
-                jnp.stack([c[2]._value for c in new_cache]))
 
     # ------------------------------------------------------------- closures
     def _run(self, params, bufs, ids, pools, table, lens, pos_ids, tag,
@@ -182,11 +194,13 @@ class GPTAdapter:
         with no_grad_ctx(), _rng.rng_scope(jax.random.key(0)), \
                 self.model.bind(params, bufs), \
                 mp_shard_scope(self.mp_mesh, self.mp_axis):
-            lc = self._layer_caches(pools, table, lens, tag)
-            x, new_cache = gpt(Tensor(ids), position_ids=Tensor(pos_ids),
-                               cache=lc, lora=lora)
+            # ONE cache for all layers: each reads and writes the stacked
+            # pools in place at its own index and hands the tuple on
+            x, pools = gpt(Tensor(ids), position_ids=Tensor(pos_ids),
+                           cache=(tag, tuple(Tensor(p) for p in pools),
+                                  Tensor(table), Tensor(lens)), lora=lora)
             w = gpt.word_embeddings.weight._value
-            return x._value, w, self._stack_pools(new_cache)
+            return x._value, w, tuple(p._value for p in pools)
 
     def _split(self, args):
         """``(*pools, table, lens)`` -> (pools tuple, table, lens)."""

@@ -9,20 +9,19 @@ four arrays instead of two:
 - ``k_scales, v_scales``: float32 scale pools ``[L, P, ps, h]`` — one
   absmax scale per (page slot, kv head), addressed by the SAME page table.
 
-Quantization happens inside the compiled programs: the ``served_q`` /
-``served_chunk_q`` cache variants of :class:`GPTDecoderLayer` round K/V
-onto the int8 grid on the way into every pool scatter
-(``ops.paged_attention.paged_table_*_write_quant``) and the paged
-attention consumers dequantize in-kernel
+Quantization happens inside the compiled programs: handed a pool tuple
+with scale pools, the served cache branch of :class:`GPTDecoderLayer`
+rounds K/V onto the int8 grid on the way into every pool write
+(``ops.paged_attention.paged_pool_write``) and the paged attention
+consumers dequantize in-kernel
 (``paged_attention_quantized`` / ``paged_chunk_attend_quant``), so no
 full-precision copy of the cache ever materializes in HBM.  Rollback,
 prefix pages, scratch-page masking and the chunk-write drop semantics are
 all untouched — the scale pool rides the exact same table addressing.
 
 Chunked prefill (``ServingEngine(prefill_chunk_tokens=N)``) rides the
-inherited :meth:`GPTAdapter.prefill_chunk` unchanged: ``chunk_tag`` is
-``"served_chunk_q"``, so each chunk quantizes on the way into the pools
-and the engine's ``prefill_chunk/<c>@int8`` program family stays
+inherited :meth:`GPTAdapter.prefill_chunk` unchanged: each chunk
+quantizes on the way into the pools and the engine's ``prefill_chunk/<c>@int8`` program family stays
 byte-identical to the monolithic int8 prefill.  On TPU the decode side of
 the same batch runs the int8 flash kernel (``decode@flash@int8``).
 
@@ -44,20 +43,20 @@ from ..adapter import GPTAdapter
 
 class QuantizedGPTAdapter(GPTAdapter):
     """``ServingEngine(kv_dtype="int8")`` builds one of these (see module
-    docstring).  Drives the ``served_q``/``served_chunk_q`` cache variants
-    with a 4-array pool tuple."""
+    docstring).  Drives the same cache variants as its base with a 4-array
+    pool tuple."""
 
-    tag = "served_q"
-    chunk_tag = "served_chunk_q"
     n_pools = 4
     kv_dtype = "int8"
 
     def init_pools(self, num_pages):
         """Zeroed ``(kp, vp, k_scales, v_scales)``: int8 payload pools
         [L, P, ps, h, d] + f32 scale pools [L, P, ps, h]."""
+        from ...ops.paged_attention import pool_lane_dim
+
         P = int(num_pages)
         shape = (self.num_layers, P, self.page_size, self.num_kv_heads,
-                 self.head_dim)
+                 pool_lane_dim(self.head_dim))
         kp = jnp.zeros(shape, jnp.int8)
         ks = jnp.zeros(shape[:-1], jnp.float32)
         return kp, jnp.zeros_like(kp), ks, jnp.zeros_like(ks)
@@ -67,7 +66,10 @@ class QuantizedGPTAdapter(GPTAdapter):
         position per head) + f32 scale (4 bytes per position per head) —
         (d + 4) / (2 d) of the bf16 cost, so ~1.9x pages per HBM byte at
         d=64 and ~1.94x at d=128."""
-        per_pos_head = self.head_dim * 1 + 4   # int8 payload + f32 scale
+        from ...ops.paged_attention import pool_lane_dim
+
+        # int8 payload (whole lanes of it) + f32 scale
+        per_pos_head = pool_lane_dim(self.head_dim) * 1 + 4
         return (2 * self.num_layers * self.page_size * self.num_kv_heads
                 * per_pos_head)
 
@@ -86,17 +88,3 @@ class QuantizedGPTAdapter(GPTAdapter):
         payload = P(None, None, None, axis, None)
         scales = P(None, None, None, axis)
         return (payload, payload, scales, scales)
-
-    def _layer_caches(self, pools, table, lens, tag):
-        from ...tensor.tensor import Tensor
-
-        kp, vp, ks, vs = pools
-        return [(tag, Tensor(kp[i]), Tensor(vp[i]), Tensor(ks[i]),
-                 Tensor(vs[i]), Tensor(table), Tensor(lens))
-                for i in range(self.num_layers)]
-
-    def _stack_pools(self, new_cache):
-        return (jnp.stack([c[1]._value for c in new_cache]),
-                jnp.stack([c[2]._value for c in new_cache]),
-                jnp.stack([c[3]._value for c in new_cache]),
-                jnp.stack([c[4]._value for c in new_cache]))

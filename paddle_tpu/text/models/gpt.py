@@ -113,140 +113,73 @@ class GPTDecoderLayer(Layer):
         heads_here = qkv.shape[-1] // (3 * self.head_dim)
         qkv = qkv.reshape([B, S, heads_here, 3, self.head_dim])
         q, k, v = qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
-        if cache is not None and len(cache) == 7 \
-                and cache[0] in ("served_q", "served_chunk_q"):
-            # QUANTIZED paged serving (paddle_tpu.serving.quant): the same
-            # global-pool/page-table/per-slot-lens contract as the "served"
-            # and "served_chunk" variants below, but the pools hold int8
-            # payloads with parallel per-(slot, head) scale pools — quant
-            # is fused into every pool write and dequant into the paged
-            # attention consumers (ops.paged_attention int8 section), so a
-            # full-precision cache copy never materializes in HBM.
-            from ...ops.paged_attention import (
-                paged_attention_quantized, paged_chunk_attend_quant,
-                paged_table_chunk_write_quant, paged_table_prefill_write_quant,
-                paged_table_token_write_quant)
-
-            tag, kp, vp, ks, vs, table, lens = cache
-            if tag == "served_chunk_q":
-                # speculative verify chunk: C tokens per slot, one
-                # quantizing scatter each for K and V, then every position
-                # attends with its own valid length
-                kp, ks = _apply(paged_table_chunk_write_quant, kp, ks, k,
-                                table, lens, n_outs=None,
-                                op_name="paged_write")
-                vp, vs = _apply(paged_table_chunk_write_quant, vp, vs, v,
-                                table, lens, n_outs=None,
-                                op_name="paged_write")
-                attn = _apply(paged_chunk_attend_quant, q, kp, vp, ks, vs,
-                              table, lens, op_name="paged_attention")
-            elif S > 1:
-                # admit-time prefill: dense causal attention over the
-                # full-precision prompt activations (only the CACHE is
-                # quantized), quantizing page writes
-                attn = F.scaled_dot_product_attention(
-                    q, k, v, is_causal=True, dropout_p=0.0, training=False)
-                kp, ks = _apply(paged_table_prefill_write_quant, kp, ks, k,
-                                table, n_outs=None, op_name="paged_write")
-                vp, vs = _apply(paged_table_prefill_write_quant, vp, vs, v,
-                                table, n_outs=None, op_name="paged_write")
-            else:
-                kp, ks = _apply(
-                    lambda pool, sp, kk, tb, ln:
-                        paged_table_token_write_quant(pool, sp, kk[:, 0],
-                                                      tb, ln),
-                    kp, ks, k, table, lens, n_outs=None,
-                    op_name="paged_write")
-                vp, vs = _apply(
-                    lambda pool, sp, vv, tb, ln:
-                        paged_table_token_write_quant(pool, sp, vv[:, 0],
-                                                      tb, ln),
-                    vp, vs, v, table, lens, n_outs=None,
-                    op_name="paged_write")
-                attn = _apply(
-                    lambda qq, kpl, vpl, ksc, vsc, tb, ln:
-                        paged_attention_quantized(
-                            qq[:, 0], kpl, vpl, ksc, vsc, tb,
-                            ln.astype(jnp.int32) + 1)[:, None],
-                    q, kp, vp, ks, vs, table, lens,
-                    op_name="paged_attention")
-            attn = attn.reshape([B, S, heads_here * self.head_dim])
-            x = residual + self.dropout(self._lin("out_proj", attn, lora))
-            residual = x
-            h = self.ln2(x)
-            h = self._lin("ffn2", self.act(self._lin("ffn1", h, lora)), lora)
-            x = residual + self.dropout(h)
-            return x, (tag, kp, vp, ks, vs, table, lens)
-        if cache is not None and len(cache) == 5 and cache[0] == "served_chunk":
-            # SPECULATIVE VERIFY chunk (paddle_tpu.serving.speculative): the
-            # S tokens of each row are the slot's last sampled token plus
-            # S-1 draft tokens at per-slot positions lens[b]..lens[b]+S-1.
-            # All S K/V land in the global pools through the page table in
-            # one chunk write, then every position attends against the
-            # pools with its OWN valid length — no dense in-chunk fallback;
-            # causality within the chunk comes from the per-position lens
-            # (ops.paged_attention.paged_chunk_attend).
-            from ...ops.paged_attention import (paged_chunk_attend,
-                                                paged_table_chunk_write)
-
-            _, kp, vp, table, lens = cache
-            kp = _apply(paged_table_chunk_write, kp, k, table, lens,
-                        op_name="paged_write")
-            vp = _apply(paged_table_chunk_write, vp, v, table, lens,
-                        op_name="paged_write")
-            attn = _apply(paged_chunk_attend, q, kp, vp, table, lens,
-                          op_name="paged_attention")
-            attn = attn.reshape([B, S, heads_here * self.head_dim])
-            x = residual + self.dropout(self._lin("out_proj", attn, lora))
-            residual = x
-            h = self.ln2(x)
-            h = self._lin("ffn2", self.act(self._lin("ffn1", h, lora)), lora)
-            x = residual + self.dropout(h)
-            return x, ("served_chunk", kp, vp, table, lens)
-        if cache is not None and len(cache) == 5 and cache[0] == "served":
+        if cache is not None and len(cache) == 5 \
+                and cache[0] in ("served", "served_chunk"):
             # SERVED cache (continuous-batching engine, paddle_tpu.serving):
-            # ONE global page pool [P, ps, h, d] shared by every slot
-            # through an explicit per-slot page table [B, NP], and per-slot
-            # lengths [B] — each slot decodes at its OWN position, which is
-            # what iteration-level batching needs (the "paged" branch below
-            # locks the whole batch to a single scalar ``pos``).
-            from ...ops.paged_attention import (paged_attention,
-                                                paged_table_prefill_write,
-                                                paged_table_token_write)
+            # ONE global page pool shared by every slot through an explicit
+            # per-slot page table [B, NP], and per-slot lengths [B] — each
+            # slot runs at its OWN position, which is what iteration-level
+            # batching needs (the "paged" branch below locks the whole
+            # batch to a single scalar ``pos``).  ``pools`` is the engine's
+            # pool tuple, every layer STACKED in it ([L, P, ps, h, d]): this
+            # layer is the index ``li`` into it, written and attended where
+            # it lies (ops.paged_attention.paged_pool_write), and the tuple
+            # goes on to the next layer.  With the int8 engine's scale
+            # pools in the tuple (paddle_tpu.serving.quant) quantization is
+            # fused into every pool write and dequantization into the paged
+            # attention consumers, so a full-precision cache copy never
+            # materializes in HBM.
+            from ...ops import paged_attention as pa
 
-            _, kp, vp, table, lens = cache
-            if S > 1:
+            tag, li, pools, table, lens = cache
+            quantized = len(pools) == 4
+            if tag == "served" and S > 1:
                 # admit-time prefill: dense causal attention over the
-                # (right-padded) prompt; positions past a row's true length
-                # write junk into pages that per-slot seq_lens masking (or
-                # the engine's scratch page) keeps invisible
+                # (right-padded) full-precision prompt; positions past a
+                # row's true length write junk into pages that per-slot
+                # seq_lens masking (or the engine's scratch page) keeps
+                # invisible
                 attn = F.scaled_dot_product_attention(
                     q, k, v, is_causal=True, dropout_p=0.0, training=False)
-                kp = _apply(paged_table_prefill_write, kp, k, table,
-                            op_name="paged_write")
-                vp = _apply(paged_table_prefill_write, vp, v, table,
-                            op_name="paged_write")
+                pools = _apply(
+                    lambda kk, vv, tb, *pl: pa.paged_pool_prefill_write(
+                        pl, kk, vv, tb, li),
+                    k, v, table, *pools, n_outs=None, op_name="paged_write")
             else:
-                kp = _apply(
-                    lambda pgs, kk, tb, ln:
-                        paged_table_token_write(pgs, kk[:, 0], tb, ln),
-                    kp, k, table, lens, op_name="paged_write")
-                vp = _apply(
-                    lambda pgs, vv, tb, ln:
-                        paged_table_token_write(pgs, vv[:, 0], tb, ln),
-                    vp, v, table, lens, op_name="paged_write")
-                attn = _apply(
-                    lambda qq, kps, vps, tb, ln:
-                        paged_attention(qq[:, 0], kps, vps, tb,
-                                        ln.astype(jnp.int32) + 1)[:, None],
-                    q, kp, vp, table, lens, op_name="paged_attention")
+                # a decode token, or the C tokens of a chunk (chunked
+                # prefill; speculative verify: the slot's last sampled
+                # token plus C-1 drafts) at positions lens[b]..lens[b]+C-1:
+                # all land in the pools in one write, then every position
+                # attends against the pools with its OWN valid length —
+                # causality within a chunk comes from the per-position
+                # lengths (ops.paged_attention.paged_chunk_attend)
+                pools = _apply(
+                    lambda kk, vv, tb, ln, *pl: pa.paged_pool_write(
+                        pl, kk, vv, tb, ln, li),
+                    k, v, table, lens, *pools, n_outs=None,
+                    op_name="paged_write")
+                if tag == "served_chunk":
+                    attend = pa.paged_chunk_attend_quant if quantized \
+                        else pa.paged_chunk_attend
+                    attn = _apply(
+                        lambda qq, tb, ln, *pl: attend(qq, *pl, tb, ln,
+                                                       layer=li),
+                        q, table, lens, *pools, op_name="paged_attention")
+                else:
+                    attend = pa.paged_attention_quantized if quantized \
+                        else pa.paged_attention
+                    attn = _apply(
+                        lambda qq, tb, ln, *pl: attend(
+                            qq[:, 0], *pl, tb, ln.astype(jnp.int32) + 1,
+                            layer=li)[:, None],
+                        q, table, lens, *pools, op_name="paged_attention")
             attn = attn.reshape([B, S, heads_here * self.head_dim])
             x = residual + self.dropout(self._lin("out_proj", attn, lora))
             residual = x
             h = self.ln2(x)
             h = self._lin("ffn2", self.act(self._lin("ffn1", h, lora)), lora)
             x = residual + self.dropout(h)
-            return x, ("served", kp, vp, table, lens)
+            return x, pools
         if cache is not None and len(cache) == 4 and cache[0] == "paged":
             # PAGED cache (serving decode): per-layer page pools
             # [B, PP, ps, h, d] — HBM bound by pages allocated, not a dense
@@ -364,11 +297,20 @@ class GPTModel(Layer):
         # ``lora``: per-layer multi-tenant adapter slices (see
         # GPTDecoderLayer._lin / paddle_tpu.serving.multitenant) — a list
         # of per-layer dicts, or None for the base model
+        # ``cache``: a list of per-layer caches, or the serving engine's ONE
+        # ``(tag, pools, table, lens)`` whose stacked pools every layer
+        # reads and writes at its own index (GPTDecoderLayer's "served"
+        # branch): the pool tuple is threaded through the layers and what
+        # the last one returns comes back in the cache's place
         x = self.embed(input_ids, position_ids)
-        new_cache = []
+        served = isinstance(cache, tuple)
+        new_cache = cache[1] if served else []
         for i, layer in enumerate(self.layers):
             li = lora[i] if lora is not None else None
-            if cache is not None:
+            if served:
+                x, new_cache = layer(
+                    x, (cache[0], i, new_cache) + cache[2:], lora=li)
+            elif cache is not None:
                 x, c = layer(x, cache[i], lora=li)
                 new_cache.append(c)
             else:

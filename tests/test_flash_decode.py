@@ -67,7 +67,8 @@ def test_flash_parity_ragged_lens(lens):
 
     q, k, v, table, seq_lens = _mk_paged(lens=lens)
     ref = paged_attention_ref(q, k, v, table, seq_lens, scale=0.25)
-    out = _paged_flash_pallas(q, k, v, table, seq_lens, 0.25, True)
+    out = _paged_flash_pallas(q, k[None], v[None], table, seq_lens, 0.25, True,
+                            0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
@@ -121,7 +122,8 @@ def test_flash_empty_rows_match_legacy_kernel():
     q, k, v, table, seq_lens = _mk_paged(lens=(0, 7, 40), seed=11)
     legacy = np.asarray(_paged_pallas(q, k, v, table, seq_lens, 0.25, True))
     flash = np.asarray(
-        _paged_flash_pallas(q, k, v, table, seq_lens, 0.25, True))
+        _paged_flash_pallas(q, k[None], v[None], table, seq_lens, 0.25, True,
+                            0))
     np.testing.assert_array_equal(flash[0], legacy[0])     # empty row
     ref = np.asarray(paged_attention_ref(q, k, v, table, seq_lens,
                                          scale=0.25))
@@ -141,7 +143,8 @@ def test_flash_dead_pages_never_read():
     lens = (5, 17, 31)
     q, k, v, table, seq_lens = _mk_paged(lens=lens, seed=13)
     clean = np.asarray(
-        _paged_flash_pallas(q, k, v, table, seq_lens, 0.25, True))
+        _paged_flash_pallas(q, k[None], v[None], table, seq_lens, 0.25, True,
+                            0))
     ps = k.shape[1]
     kp, vp = np.array(k, copy=True), np.array(v, copy=True)
     tab = np.asarray(table)
@@ -155,7 +158,8 @@ def test_flash_dead_pages_never_read():
                     kp[page, s] = 1e6
                     vp[page, s] = -1e6
     poisoned = np.asarray(_paged_flash_pallas(
-        q, jnp.asarray(kp), jnp.asarray(vp), table, seq_lens, 0.25, True))
+        q, jnp.asarray(kp)[None], jnp.asarray(vp)[None], table, seq_lens,
+        0.25, True, 0))
     np.testing.assert_array_equal(clean, poisoned)
 
 

@@ -45,9 +45,9 @@ def _make(B, C, H, HKV, kind, seed=0):
 def _kernel(q, pools, scales, table, lens):
     fn = pa._paged_chunk_q_flash_pallas if scales \
         else pa._paged_chunk_flash_pallas
-    return np.asarray(fn(q, *pools, *scales, table,
+    return np.asarray(fn(q, *(a[None] for a in (*pools, *scales)), table,
                          jnp.asarray(lens, jnp.int32),
-                         1.0 / math.sqrt(D), True))
+                         1.0 / math.sqrt(D), True, 0))
 
 
 def _reference(q, pools, scales, table, lens):

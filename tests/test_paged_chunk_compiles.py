@@ -1,7 +1,9 @@
-"""The chunk attention kernel at the widths the chip runs it, compiled by
-the TPU's own compiler for a described v5e (no chip attached): what Mosaic
-refuses — a misaligned slice, too much VMEM, a product it cannot lower —
-fails here and costs no chip time.  Nothing runs, so nothing here is a
+"""The chunk attention kernel at the widths the chip runs it, and the
+serving adapter's whole programs over donated pools, compiled by the TPU's
+own compiler for a described v5e (no chip attached): what Mosaic refuses —
+a misaligned slice, too much VMEM, a product it cannot lower — fails here
+and costs no chip time, and so does a program that copies, slices or
+re-lays the KV pool around its kernels.  Nothing runs, so nothing here is a
 number.  Keep every such compile in THIS file: one process may load the
 TPU's library at a time, and pytest-xdist hands a file to one worker."""
 
@@ -44,15 +46,107 @@ def test_chunk_kernel_compiles_for_v5e(one_chip, name, B, C, H, HKV, D,
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = sds((P, ps, HKV, D), jnp.int8 if quantized else jnp.bfloat16)
-    scales = (sds((P, ps, HKV), jnp.float32),) * 2 if quantized else ()
+    pool = sds((2, P, ps, HKV, D), jnp.int8 if quantized else jnp.bfloat16)
+    scales = (sds((2, P, ps, HKV), jnp.float32),) * 2 if quantized else ()
     fn = pa._paged_chunk_q_flash_pallas if quantized \
         else pa._paged_chunk_flash_pallas
     compiled = jax.jit(
-        lambda *a: fn(*a, 1.0 / math.sqrt(D), False)).lower(
+        lambda *a: fn(*a, 1.0 / math.sqrt(D), False, 1)).lower(
         sds((B, C, H, D), jnp.bfloat16), pool, pool, *scales,
         sds((B, NP), jnp.int32), sds((B,), jnp.int32)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+# ------------------------------------------------ the pool, served in place
+def _served_program(monkeypatch, one_chip, closure, layers, pages, kv_dtype):
+    """``GPTAdapter.<closure>`` of a ``layers``-deep model at gpt2-medium's
+    widths over ``pages``-page pools, compiled as the engine jits it: the
+    pools donated, every argument in the device's own layout."""
+    import chip_smoke
+    import paddle_tpu as paddle
+    from paddle_tpu.serving.adapter import GPTAdapter
+    from paddle_tpu.serving.quant import QuantizedGPTAdapter
+    from paddle_tpu.text.models import GPTForCausalLM
+
+    paddle.seed(0)
+    model = GPTForCausalLM(
+        vocab_size=50257, hidden_size=1024, num_hidden_layers=layers,
+        num_attention_heads=16, max_position_embeddings=1024).eval().bfloat16()
+    adapter = (QuantizedGPTAdapter if kv_dtype == "int8"
+               else GPTAdapter)(model, page_size=16)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, bufs = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), adapter.params_and_buffers())
+    # the adapter asks the backend how wide the chip's lanes are, and which
+    # attention to trace: the TPU's, and the kernels
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pools = tuple(sds(p.shape, p.dtype)
+                  for p in jax.eval_shape(lambda: adapter.init_pools(pages)))
+    assert pools[0].shape[-1] == 128            # d = 64 in whole lanes
+    B, width = (16, 1) if closure == "step" else \
+        (1, 256 if closure == "prefill_chunk" else 128)
+    lead = (sds((B, width), jnp.int64),) + (
+        (sds((B,), jnp.int32),) if closure == "prefill_chunk" else ())
+    first = 2 + len(lead)
+    jitted = jax.jit(getattr(adapter, closure),
+                     donate_argnums=tuple(range(first, first + len(pools))))
+    compiled = jitted.lower(params, bufs, *lead, *pools,
+                            sds((B, 64), jnp.int32),
+                            sds((B,), jnp.int32)).compile()
+    # the int8 engine's scale pools [L, P, ps, h] f32 are 16 lanes wide and
+    # still converted on the way in and out (PERF.md section 7): payloads
+    moved = chip_smoke.pool_sized_instructions(
+        compiled.as_text(), [p.shape for p in pools[:2]])
+    return compiled, moved, math.prod(pools[0].shape) * pools[0].dtype.itemsize
+
+
+@pytest.mark.parametrize("closure,kv_dtype,kernels_a_layer", [
+    ("step", None, 2), ("prefill_chunk", None, 2), ("prefill", None, 1),
+    ("step", "int8", 2)])
+def test_served_programs_touch_the_pool_in_their_kernels_only(
+        monkeypatch, one_chip, closure, kv_dtype, kernels_a_layer):
+    """No instruction outside the Mosaic calls has a pool's or a layer's
+    element count as its result (no copy, slice, update, concatenation,
+    transpose or fusion over the stacked pool), and the program's
+    temporaries are a small fraction of one pool."""
+    compiled, moved, pool_bytes = _served_program(
+        monkeypatch, one_chip, closure, 2, 1025, kv_dtype)
+    assert moved == []
+    assert compiled.as_text().count("tpu_custom_call") == 2 * kernels_a_layer
+    # (the int8 engine's two scale pools are still converted: room for them)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / (
+        4 if kv_dtype is None else 1)
+
+
+def test_chunk_program_fits_the_chip_at_4097_pages(monkeypatch, one_chip):
+    """24 layers over 4,097-page pools: with the pool copied around the
+    kernels the chunk program needed 19.06G of the chip's 15.75G."""
+    compiled, moved, _ = _served_program(
+        monkeypatch, one_chip, "prefill_chunk", 24, 4097, None)
+    mem = compiled.memory_analysis()
+    assert moved == []
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) < 15.75e9
+
+
+def test_pool_sized_instructions_finds_what_moves_a_pool():
+    import chip_smoke
+
+    text = """
+  %p.1 = bf16[2,65,16,16,64]{4,3,2,1,0:T(8,128)(2,1)} parameter(3)
+  %copy.18 = bf16[2,65,16,16,64]{4,3,2,1,0:T(8,128)(2,1)} copy(%p.1)
+  %slice.2 = bf16[1,65,16,16,64]{4,3,2,1,0} slice(%p.1), slice={[0:1]}
+  %fusion.3 = (bf16[2,65,16,16,64]{4,3,2,1,0}, s32[]) fusion(%copy.18)
+  %custom-call.5 = bf16[2,65,16,16,64]{4,3,2,1,0} custom-call(%p.1), custom_call_target="tpu_custom_call"
+  %gte.1 = bf16[2,65,16,16,64]{4,3,2,1,0} get-tuple-element(%fusion.3), index=0
+  ROOT %dot.9 = f32[16,50257]{1,0} dot(%a, %b)
+"""
+    found = chip_smoke.pool_sized_instructions(text, [(2, 65, 16, 16, 64)])
+    assert [f.split(" = ")[0] for f in found] == [
+        "%copy.18", "%slice.2", "%fusion.3"]
 
 
 # ------------------------------------------- latent attention, expert layer

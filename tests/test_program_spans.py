@@ -386,8 +386,13 @@ PAGED_SITES = {
 def test_paged_kernel_sites_carry_their_names(site):
     pa = importlib.import_module("paddle_tpu.ops.paged_attention")
     fn, quantized, want = PAGED_SITES[site]
+    q, *pools, table, lens = _paged_args(quantized)
+    tail = ()
+    if "flash" in fn:       # the served kernels: stacked pools and a layer
+        pools, tail = [jnp.stack([p, p]) for p in pools], (1,)
     scopes = _pallas_scopes(
-        lambda *a: getattr(pa, fn)(*a, 0.125, True), *_paged_args(quantized))
+        lambda *a: getattr(pa, fn)(*a, 0.125, True, *tail), q, *pools,
+        table, lens)
     assert scopes == [want]
 
 

@@ -67,6 +67,12 @@ def as_tpu(monkeypatch):
     # against a real TPU lowering in test_served_programs_lower_to_mosaic
     monkeypatch.setattr(chip_smoke, "expect_mosaic",
                         lambda what, jitted, args, want: want)
+    # an interpreted kernel is a loop over the whole pool; what the TPU's
+    # compiler makes of the served programs is checked, for a described
+    # v5e, in tests/test_paged_chunk_compiles.py
+    monkeypatch.setattr(chip_smoke, "compiled_pool_facts",
+                        lambda jitted, args, pools: {"pool_sized": [],
+                                                     "temp_bytes": 0})
 
 
 @pytest.mark.parametrize("phase", ["kernels", "train_resnet", "train_gpt",
@@ -94,9 +100,10 @@ def test_multichip_skips_below_four_devices(monkeypatch):
 
 
 def test_served_programs_lower_to_mosaic(monkeypatch):
-    """With the backend reporting "tpu", the decode and prefill-chunk
-    programs of a served engine lower (for the TPU platform, from here) to
-    one Mosaic custom call per layer — and to none on the reference path."""
+    """With the backend reporting "tpu", the programs of a served engine
+    lower (for the TPU platform, from here) to their Mosaic custom calls —
+    one pool writer a program, and in every layer the decode or the chunk
+    kernel — and to none on the reference path."""
     import paddle_tpu as paddle
     from paddle_tpu.serving import ServingEngine
     from paddle_tpu.text.models import GPTForCausalLM
