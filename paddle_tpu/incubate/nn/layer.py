@@ -194,8 +194,9 @@ class FusedMultiTransformer(Layer):
         KV footprint and the attention math still runs its softmax in f32).
 
         impl="paged": block-paged pools [B, PP, page, H, D] instead of the
-        dense [B, max_length] rectangle — decode attention runs the Pallas
-        scalar-prefetch paged kernel and serving HBM is bounded by pages
+        dense [B, max_length] rectangle, each entry ``(tag, k_pages,
+        v_pages)`` — decode attention runs the Pallas scalar-prefetch paged
+        kernel and serving HBM is bounded by pages
         (ceil(max_length/page_size) per sequence), the property the
         reference's paged engine exists for.
         """
@@ -208,7 +209,7 @@ class FusedMultiTransformer(Layer):
         if impl == "paged":
             pp = -(-max_length // page_size)
             shape = (batch_size, pp, page_size, self.num_heads, self.head_dim)
-            return [("paged", Tensor(jnp.zeros(shape, dtype)),
+            return [("served", Tensor(jnp.zeros(shape, dtype)),
                      Tensor(jnp.zeros(shape, dtype)))
                     for _ in range(self.num_layers)]
         if impl != "dense":
@@ -261,23 +262,22 @@ class _FusedMTBlock(Layer):
         qkv = self.qkv(h).reshape([B, T, 3, self.num_heads, self.head_dim])
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         new_cache = None
-        if cache is not None and len(cache) == 3 and cache[0] == "paged":
-            # PAGED serving cache (gen_cache(impl="paged")): prefill attends
-            # densely and writes pages; decode steps run the Pallas paged
-            # kernel (see ops/paged_attention).  Prefill must start at
+        if cache is not None and len(cache) == 3:
+            # PAGED serving cache (gen_cache(impl="paged")): this layer's
+            # pages [B, PP, ps, H, D] on the serving engine's cache contract
+            # (ops/paged_attention) as a pool of one layer, an identity page
+            # table, every length ``time_step``.  Prefill must start at
             # time_step 0; continuation chunks need the dense cache.
-            from ...ops.paged_attention import (paged_decode_attend,
-                                                paged_prefill_write,
-                                                paged_token_write)
+            from ...ops.paged_attention import paged_cache_attend
+            from ...tensor.tensor import Tensor
 
             if time_step is None:
                 raise ValueError("caches need time_step (decode position)")
             if attn_mask is not None:
                 raise NotImplementedError(
                     "paged FusedMultiTransformer caches do not take an "
-                    "attn_mask; per-sequence lengths belong in seq_lens "
-                    "(PagedKVCache)")
-            _, kp, vp = cache
+                    "attn_mask; per-sequence lengths belong in per-slot "
+                    "lengths (`ServingEngine`)")
             if T > 1:
                 ts_val = getattr(time_step, "_value", time_step)
                 try:
@@ -287,38 +287,22 @@ class _FusedMTBlock(Layer):
                             "the dense cache for continuation chunks")
                 except TypeError:
                     pass  # traced: the caller's contract
-                att = F.scaled_dot_product_attention(
-                    q, k, v, is_causal=True, training=False)
-                kp = apply(paged_prefill_write, kp, k, op_name="paged_write")
-                vp = apply(paged_prefill_write, vp, v, op_name="paged_write")
-            else:
-                def wr(pgs, tok, t_):
-                    return paged_token_write(pgs, tok[:, 0],
-                                             t_.astype(jnp.int32).reshape(()))
-
-                kp = apply(wr, kp, k, time_step, op_name="paged_write")
-                vp = apply(wr, vp, v, time_step, op_name="paged_write")
-                att = apply(
-                    lambda qq, kps, vps, t_:
-                        paged_decode_attend(
-                            qq[:, 0], kps, vps,
-                            t_.astype(jnp.int32).reshape(()))[:, None],
-                    q, kp, vp, time_step, op_name="paged_attention")
-            o = self.out_proj(att.reshape([B, T, -1]))
-            if self.dropout_rate and self.training:
-                o = F.dropout(o, p=self.dropout_rate, training=True)
-            src = src + o
-            if not self.normalize_before:
-                src = self.ln1(src)
-            h2 = self.fc1(self.ln2(src) if self.normalize_before else src)
-            h2 = self.fc2(getattr(F, self.activation)(h2))
-            if self.dropout_rate and self.training:
-                h2 = F.dropout(h2, p=self.dropout_rate, training=True)
-            out = src + h2
-            if not self.normalize_before:
-                out = self.ln2(out)
-            return out, ("paged", kp, vp)
-        if cache is not None:
+            tag, kp, vp = cache
+            paged = list(kp.shape)                      # [B, PP, ps, H, D]
+            one_layer = [1, B * paged[1]] + paged[2:]
+            table = Tensor(jnp.arange(B * paged[1], dtype=jnp.int32)
+                           .reshape(B, paged[1]))
+            lens = apply(
+                lambda t_: jnp.full((B,), t_.astype(jnp.int32).reshape(())),
+                time_step, op_name="paged_lens")
+            o, (kp, vp) = paged_cache_attend(
+                q, k, v,
+                (tag, 0, (kp.reshape(one_layer), vp.reshape(one_layer)),
+                 table, lens),
+                lambda q, k, v: F.scaled_dot_product_attention(
+                    q, k, v, is_causal=True, training=False))
+            new_cache = (tag, kp.reshape(paged), vp.reshape(paged))
+        elif cache is not None:
             ck, cv = cache
             if time_step is None:
                 raise ValueError("caches need time_step (decode position)")

@@ -155,19 +155,11 @@ def is_encode_family(family):
     return "@embed" in family or "@score" in family
 
 
-def is_flash_family(family):
-    """True for the length-bounded flash-decode families — on a TPU
-    backend the engine attributes its decode programs as ``decode@flash``
-    (``decode@flash@int8`` when quantized): the page sweep is clamped per
-    row by the prefetched seq_lens, so dead-page DMA is already gone."""
-    return "@flash" in family
-
-
 def is_mp_family(family):
     """True for the tensor-parallel serving families — a mesh-sharded
     engine attributes its programs as ``decode@mp<N>``,
     ``prefill/<bucket>@mp<N>``, ``verify/k<k>@mp<N>`` (the suffix composes
-    after ``@flash``/``@int8``: one SPMD program per family, dispatched
+    after ``@int8``: one SPMD program per family, dispatched
     over the ``model`` axis)."""
     return "@mp" in family
 
@@ -240,7 +232,6 @@ def candidate_hint(family, regime, temp_bytes=None, pool_bytes=None,
     fast as the cache hits is thrashing host<->device and wants a bigger
     ``PADDLE_KV_SPILL_BUDGET_BYTES``."""
     quant = is_quantized_family(family)
-    flash = is_flash_family(family)
     mp = is_mp_family(family)
     serving = family.split("@")[0].startswith(_KV_BOUND_FAMILIES)
     if temp_bytes and pool_bytes \
@@ -308,17 +299,6 @@ def candidate_hint(family, regime, temp_bytes=None, pool_bytes=None,
                     "over the model axis, so each chip sweeps 1/"
                     f"{n} of the KV heads — cut the per-shard bytes next "
                     "with int8 pools (kv_dtype=\"int8\")")
-        if flash:
-            if quant:
-                return ("HBM-bound int8 flash-decode program: the page "
-                        "sweep is length-bounded and KV dequant is fused "
-                        "— remaining levers are int8 weights "
-                        "(weight_dtype=\"int8\") and batch occupancy "
-                        "(more live slots per dispatch)")
-            return ("HBM-bound flash-decode program: dead-page DMA is "
-                    "already clamped by the length-bounded sweep — next "
-                    "lever is int8 KV pools (kv_dtype=\"int8\"), then "
-                    "int8 weights")
         if quant:
             return ("HBM-bound int8 serving program: KV dequant already "
                     "fused in-kernel — cut the remaining bytes (int8 "

@@ -1,23 +1,36 @@
-"""Paged attention — decode-time attention over a block-paged KV cache.
+"""Paged attention — attention over a block-paged KV cache, and the cache.
 
 Reference analog: the PagedAttention kernels serving stacks use for
 KV-cache memory management (and the reference inference engine's fused
-decode attention).  TPU-native design: the page table rides the kernel as
+decode attention).  TPU-native design: the page table rides the kernels as
 SCALAR PREFETCH — Pallas resolves each grid step's HBM block address from
 ``page_table[b, i]`` *before* the step runs, so pages stream HBM→VMEM with
 no gather materialization; online-softmax state (m, l, acc) lives in VMEM
 scratch across the page sweep, exactly like this repo's flash kernel
 (ops/flash_attention.py).
 
-Layout:
-    q          [B, H, D]           one decode token per sequence
-    k_pages    [P, page_size, H, D]  global page pool (shared across seqs)
-    v_pages    [P, page_size, H, D]
-    page_table [B, NP] int32       page ids per sequence (row-padded)
-    seq_lens   [B]     int32       valid token count per sequence
+ONE cache contract, the serving engine's, and one seam the models call
+(:func:`paged_cache_attend`): write this layer's K/V chunk, attend against
+the pages.  ``generate(cache_impl="paged")`` is the same contract with an
+identity page table and every length equal.
 
-Off-TPU (and for tiny shapes) the public entry falls back to a dense
-gather reference with identical semantics.
+Layout:
+    pools      (k, v) each [L, P, page_size, HKV, Dp]: every layer's pages
+               stacked in one array, shared by all sequences; a layer is an
+               index into it.  ``Dp`` is the head size in whole lanes on
+               the TPU (:func:`pool_lane_dim`).  The int8 cache adds the
+               scale pools (ks, vs) [L, P, page_size, HKV] f32 to the tuple.
+               (The entries also take ONE layer's [P, page_size, ...] with
+               ``layer=None``.)
+    q          [B, H, D] one decode token per slot, or a chunk [B, C, H, D]
+    page_table [B, NP] int32       page ids per slot (row-padded)
+    seq_lens   [B]     int32       valid token count per slot
+
+Four kernel bodies: decode (``_paged_flash_kernel``), decode over int8
+pools (``_paged_q_flash_kernel``), chunk (``_paged_chunk_kernel``) and the
+writer (``_paged_write_kernel``).  Off the TPU every public entry is a
+dense gather reference (a scatter, for the writer) with identical
+semantics.
 """
 
 from __future__ import annotations
@@ -28,131 +41,25 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..tensor.dispatch import apply as _apply
+
 NEG_INF = -1e30
 _LANES = 128
 
 
-def _paged_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, page_size, scale, num_kv_heads):
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, jnp.float32(NEG_INF))
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    seq_len = lens_ref[b]
-    num_q = q_ref.shape[1]
-    g = num_q // num_kv_heads  # query heads per kv head (GQA group; MHA=1)
-
-    @pl.when(i * page_size < seq_len)
-    def _compute():
-        # Mosaic discipline (mirrors ops/flash_attention.py): strictly 2-D
-        # tiles, keepdims reductions, f32 constants, plain-contracting
-        # dot_generals only.  KV heads run as a STATIC
-        # unrolled loop; each page streams HBM->VMEM ONCE and serves all g
-        # grouped query heads via two small MXU dots — GQA's bandwidth
-        # saving holds inside the kernel (no repeated-KV reads).
-        pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        valid = pos < seq_len                              # [1, page]
-        for j in range(num_kv_heads):
-            r = slice(j * g, (j + 1) * g)
-            q = q_ref[0, r, :].astype(jnp.float32)         # [g, D]
-            k = k_ref[0, :, j, :].astype(jnp.float32)      # [page, D]
-            v = v_ref[0, :, j, :].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * jnp.float32(scale)
-            s = jnp.where(valid, s, jnp.float32(NEG_INF))  # [g, page]
-            m_prev = m_scr[r, :]                           # [g, 1]
-            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)                         # [g, page]
-            alpha = jnp.exp(m_prev - m_new)                # [g, 1]
-            l_scr[r, :] = l_scr[r, :] * alpha + p.sum(axis=1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [g, D]
-            acc_scr[r, :] = acc_scr[r, :] * alpha + pv
-            m_scr[r, :] = m_new
-
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _fin():
-        # output stays f32; the public entry downcasts outside the kernel
-        o_ref[0] = acc_scr[...] / jnp.maximum(l_scr[...], jnp.float32(1e-30))
-
-
-def _paged_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
-                  interpret):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, H, D = q.shape
-    HKV = k_pages.shape[2]
-    page_size = k_pages.shape[1]
-    NP = page_table.shape[1]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, NP),
-        in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0)),
-            pl.BlockSpec((1, page_size, HKV, D),
-                         lambda b, i, pt, ln: (pt[b, i], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, HKV, D),
-                         lambda b, i, pt, ln: (pt[b, i], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, D), jnp.float32),
-        ],
-    )
-    # x64 OFF around the call: the framework enables jax_enable_x64 globally
-    # (paddle int64 tensor parity), and under it the literal 0s of the
-    # BlockSpec index maps trace as i64 constants, which Mosaic fails to
-    # legalize (checked against libtpu 0.0.34: "failed to legalize operation
-    # 'func.func'" on the index-map transform).  Every dtype in the kernel is
-    # pinned, so x32 promotion rules change nothing numerically.
-    with jax.enable_x64(False):
-        out = pl.pallas_call(
-            functools.partial(_paged_kernel, page_size=page_size, scale=scale,
-                              num_kv_heads=HKV),
-            name="paged_decode",
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-            interpret=interpret,
-        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-          q, k_pages, v_pages)
-    return out.astype(q.dtype)
-
-
-# ------------------------------------------------------------ flash decode
-# The length-bounded sweep: the legacy kernels above visit EVERY page slot
-# of the table width for every row — a 128-token row in a 2048-token table
-# pays 128 pages of DMA for 8 pages of data.  The flash variants clamp the
+# ------------------------------------------------------------------ decode
+# The length-bounded sweep: a kernel whose grid visits EVERY page slot of
+# the table width for every row makes a 128-token row in a 2048-token table
+# pay 128 pages of DMA for 8 pages of data.  The decode kernels clamp the
 # sweep per row using the scalar-prefetched seq_lens INSIDE the BlockSpec
 # index map: grid steps past the row's last valid page re-present that last
 # page's block index, and Pallas's revisiting-block optimization elides the
 # HBM->VMEM copy for a repeated index — dead pages are never DMA'd.  The
 # kernel body masks those steps out (i*page_size >= seq_len) and finalizes
 # at the row's LAST VALID page instead of the last grid step, so the
-# trailing steps are pure no-ops.  The batch dimension keeps leading the
-# grid and is declared "parallel" for megacore partitioning; the page sweep
-# stays "arbitrary" (sequential online-softmax accumulation).
-
-
-def flash_decode_active():
-    """True when :func:`paged_attention` will dispatch to the
-    length-bounded flash-decode Pallas path (i.e. a TPU backend is
-    active).  The serving engine uses this for perf-family attribution
-    (``decode@flash`` vs plain ``decode``)."""
-    return jax.default_backend() == "tpu"
+# trailing steps are pure no-ops.  The batch dimension leads the grid and is
+# declared "parallel" for megacore partitioning; the page sweep stays
+# "arbitrary" (sequential online-softmax accumulation).
 
 
 # ------------------------------------------------- tensor-parallel serving
@@ -283,7 +190,7 @@ def _accum_page(q_ref, valid, load_k, load_v, scale, num_kv_heads,
                 m_scr, l_scr, acc_scr):
     """One page's online-softmax update, shared by the flash kernels.
 
-    Mosaic discipline (mirrors _paged_kernel): strictly 2-D tiles,
+    Mosaic discipline (mirrors ops/flash_attention.py): strictly 2-D tiles,
     keepdims reductions, f32 constants, plain-contracting dot_generals
     only.  KV heads run as a STATIC
     unrolled loop; ``load_k(j)``/``load_v(j)`` return the page's f32
@@ -330,7 +237,7 @@ def _paged_flash_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     # finalize at the row's LAST VALID page, not the table edge — steps
     # past it present a repeated (un-fetched) block and do nothing.  The
     # clamp to the grid edge covers rows whose length overruns the table
-    # (callers mask with seq_lens, the legacy kernels behave the same).
+    # (callers mask with seq_lens).
     last = jnp.minimum(_last_page(seq_len, page_size),
                        pl.num_programs(1) - 1)
 
@@ -378,7 +285,12 @@ def _paged_flash_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
             pltpu.VMEM((H, D), jnp.float32),
         ],
     )
-    # x64 OFF for the same Mosaic i64-index reason as _paged_pallas.
+    # x64 OFF around the call: the framework enables jax_enable_x64 globally
+    # (paddle int64 tensor parity), and under it the literal 0s of the
+    # BlockSpec index maps trace as i64 constants, which Mosaic fails to
+    # legalize (checked against libtpu 0.0.34: "failed to legalize operation
+    # 'func.func'" on the index-map transform).  Every dtype in the kernel is
+    # pinned, so x32 promotion rules change nothing numerically.
     # This call alone carries no ``name="paged_decode"``: a name is the
     # innermost scope of the kernel's name stack and so becomes its HLO
     # instruction name, and the benchmark's ``paged_decode_roofline`` finds
@@ -493,85 +405,22 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
     return out[..., :D]
 
 
-# --------------------------------------------------------- decode-loop utils
-# Pure-jax helpers for the generate() paged path (one pool per layer, pages
-# laid out per sequence: row b*PP+i is page i of sequence b).  All shapes
-# static; `pos` may be traced, so decode writes use dynamic_update_slice.
-
-
-def paged_prefill_write(pages, kv):
-    """Write a whole prompt's K or V into the page pool at position 0.
-
-    pages: [B, PP, ps, h, d]; kv: [B, S, h, d] -> updated pages.  Static: S
-    is a trace-time constant, so this is a reshape + slice-assign, no
-    scatter."""
-    B, S, h, d = kv.shape
-    ps = pages.shape[2]
-    pad = (ps - S % ps) % ps
-    if pad:
-        kv = jnp.pad(kv, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    chunks = kv.reshape(B, -1, ps, h, d)
-    return pages.at[:, :chunks.shape[1]].set(chunks.astype(pages.dtype))
-
-
-def paged_token_write(pages, tok, pos):
-    """Write one token per sequence at (traced) position ``pos``.
-
-    pages: [B, PP, ps, h, d]; tok: [B, h, d]; pos: scalar int32."""
-    ps = pages.shape[2]
-    page_idx = (pos // ps).astype(jnp.int32)
-    slot = (pos % ps).astype(jnp.int32)
-    zero = jnp.zeros((), jnp.int32)
-    return jax.lax.dynamic_update_slice(
-        pages, tok[:, None, None].astype(pages.dtype),
-        (zero, page_idx, slot, zero, zero))
-
-
-def paged_decode_attend(q, k_pages, v_pages, pos, scale=None):
-    """One decode step of attention over per-seq paged K/V.
-
-    q: [B, hq, d]; k_pages/v_pages: [B, PP, ps, hkv, d]; pos: traced scalar
-    (tokens 0..pos are valid).  GQA (hq = g*hkv) is grouped INSIDE the
-    kernel — every page streams HBM->VMEM once for all g query heads, so
-    the cache bandwidth saving GQA exists for survives the kernel.  NOTE:
-    q head h must map to kv head h//g (jnp.repeat convention — what the
-    dense paths in gpt.py/llama.py use)."""
-    B, PP, ps, hkv, d = k_pages.shape
-    lens = jnp.full((B,), pos + 1, jnp.int32)
-    if jax.default_backend() != "tpu":
-        # the table below is the IDENTITY permutation of the reshaped
-        # pools, so the reference path's two [B, PP*ps] gathers are pure
-        # copies — skip them and attend the reshaped pools directly
-        # (trace-time static branch; big win for the CPU bench arm)
-        sc = scale if scale is not None else 1.0 / math.sqrt(d)
-        return _gathered_attend(q, k_pages.reshape(B, PP * ps, hkv, d),
-                                v_pages.reshape(B, PP * ps, hkv, d),
-                                lens, sc)
-    pool_k = k_pages.reshape(B * PP, ps, hkv, d)
-    pool_v = v_pages.reshape(B * PP, ps, hkv, d)
-    table = (jnp.arange(B, dtype=jnp.int32)[:, None] * PP
-             + jnp.arange(PP, dtype=jnp.int32)[None, :])
-    return paged_attention(q, pool_k, pool_v, table, lens, scale)
-
-
-# ------------------------------------------------- serving-engine utils
-# Table-addressed variants for the continuous-batching engine
-# (paddle_tpu.serving): ONE global pool shared by every sequence through an
-# explicit page table, and PER-SLOT lengths — each slot decodes at its own
-# position, which is what iteration-level batching needs (the lock-step
-# helpers above share one scalar ``pos`` across the batch).
+# ------------------------------------------------------------------ writes
+# ONE global pool shared by every sequence through an explicit page table,
+# and PER-SLOT lengths: each slot writes and decodes at its own position,
+# which is what iteration-level batching needs.
 #
-# The engine's pool holds every layer, stacked: [L, P, ps, h, d].  Each
-# entry here takes ``layer`` and reads or writes THAT layer of the stacked
-# pool where it lies (``layer=None``: the pool is one layer's [P, ps, h, d]).
-# Nothing slices a layer out or stacks layers back: the kernels get the
-# layer as a leading block dimension of one at a fixed block index, the
-# dense fall-backs gather ``pool[layer, table]``, and a write touches the
-# rows it writes.  Three writers, one mechanism: a chunk of C tokens per
-# slot at the slot's own position (a decode token is a chunk of one, a whole
-# prompt a chunk at length zero) — a scatter off the TPU, and on it one
-# Pallas call a layer over the whole pool tuple, aliased in and out
-# (``_paged_write_pallas`` under ``paged_pool_write``).
+# The pool holds every layer, stacked: [L, P, ps, h, d].  Each entry takes
+# ``layer`` and reads or writes THAT layer of the stacked pool where it lies
+# (``layer=None``: the pool is one layer's [P, ps, h, d]).  Nothing slices a
+# layer out or stacks layers back: the kernels get the layer as a leading
+# block dimension of one at a fixed block index, the dense fall-backs
+# gather ``pool[layer, table]``, and a write touches the rows it writes.
+# One write mechanism: a chunk of C tokens per slot at the slot's own
+# position (a decode token is a chunk of one, a whole prompt a chunk at
+# length zero) — a scatter off the TPU, and on it one Pallas call a layer
+# over the whole pool tuple, aliased in and out (``_paged_write_pallas``
+# under ``paged_pool_write``).
 
 
 def paged_table_chunk_write(pool, kv, table, lens, layer=None):
@@ -605,27 +454,6 @@ def paged_table_chunk_write(pool, kv, table, lens, layer=None):
     pages = jnp.where(in_range, pages, jnp.int32(pool.shape[len(at)]))
     return pool.at[(*at, pages.reshape(-1), (pos_c % ps).reshape(-1))].set(
         kv.reshape((B * C,) + rest).astype(pool.dtype), mode="drop")
-
-
-def paged_table_token_write(pool, tok, table, lens, layer=None):
-    """Write one token's K or V per slot at each slot's OWN position: a
-    chunk of one.  tok: [B, *rest] — slot b's token lands in page
-    ``table[b, lens[b]//ps]`` slot ``lens[b]%ps``."""
-    return paged_table_chunk_write(pool, tok[:, None], table, lens, layer)
-
-
-def paged_table_prefill_write(pool, kv, table, layer=None):
-    """Write whole prompts into their table pages at position 0: a chunk
-    at length zero, of whole pages.  kv: [B, S, *rest], S a trace-time
-    constant; each row's S tokens land in pages ``table[b, 0:ceil(S/ps)]``,
-    the last one filled up with zeros (rows shorter than S are right-padded
-    by the caller — the junk tokens go into pages that per-slot
-    ``seq_lens`` masking keeps invisible, or into the caller's scratch
-    page)."""
-    ps = pool.shape[1 if layer is None else 2]
-    return paged_table_chunk_write(
-        pool, _pad_to_pages(kv, ps), table,
-        jnp.zeros((kv.shape[0],), jnp.int32), layer)
 
 
 def _pad_to_pages(kv, page_size):
@@ -828,7 +656,7 @@ def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
             pltpu.VMEM((H, D, tile), jnp.float32),
         ],
     )
-    # x64 OFF for the same Mosaic i64-index reason as _paged_pallas
+    # x64 OFF for the same Mosaic i64-index reason as _paged_flash_pallas
     with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(
@@ -944,141 +772,14 @@ def quantize_kv(kv, bits=8):
     return q, jnp.squeeze(scale, -1)
 
 
-def paged_table_prefill_write_quant(pool, spool, kv, table, layer=None):
-    """Quantizing twin of :func:`paged_table_prefill_write`: rounds the
-    prompt's K or V into the int8 pool AND writes the per-(slot, head)
-    scale tiles into the parallel scale pool.  pool: [P, ps, h, d] int8;
-    spool: [P, ps, h] f32 (with ``layer``: both stacked); kv: [B, S, h, d];
-    returns (pool, spool)."""
-    qv, sc = quantize_kv(kv)
-    return (paged_table_prefill_write(pool, qv, table, layer),
-            paged_table_prefill_write(spool, sc, table, layer))
-
-
-def paged_table_token_write_quant(pool, spool, tok, table, lens, layer=None):
-    """Quantizing twin of :func:`paged_table_token_write` (one token per
-    slot at its own position).  tok: [B, h, d]; returns (pool, spool)."""
-    qv, sc = quantize_kv(tok)
-    return (paged_table_token_write(pool, qv, table, lens, layer),
-            paged_table_token_write(spool, sc, table, lens, layer))
-
-
-def paged_table_chunk_write_quant(pool, spool, kv, table, lens, layer=None):
-    """Quantizing twin of :func:`paged_table_chunk_write` (speculative
-    verify: C tokens per slot in one scatter, same drop-OOB semantics).
-    kv: [B, C, h, d]; returns (pool, spool)."""
-    qv, sc = quantize_kv(kv)
-    return (paged_table_chunk_write(pool, qv, table, lens, layer),
-            paged_table_chunk_write(spool, sc, table, lens, layer))
-
-
-def _paged_q_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                    o_ref, m_scr, l_scr, acc_scr, *, page_size, scale,
-                    num_kv_heads):
-    """The dequant-fused twin of :func:`_paged_kernel`: int8 page tiles
-    stream HBM->VMEM at half the bf16 bytes, and the per-(slot, head)
-    scale column multiplies them back to f32 IN VMEM — the full-precision
-    page never exists outside the register file."""
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, jnp.float32(NEG_INF))
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    seq_len = lens_ref[b]
-    num_q = q_ref.shape[1]
-    g = num_q // num_kv_heads
-
-    @pl.when(i * page_size < seq_len)
-    def _compute():
-        # same Mosaic discipline as _paged_kernel (2-D tiles, keepdims,
-        # f32 constants, plain-contracting dots); the only addition is the
-        # [page, 1] scale column applied right after the int8->f32 convert
-        pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        valid = pos < seq_len                              # [1, page]
-        for j in range(num_kv_heads):
-            r = slice(j * g, (j + 1) * g)
-            q = q_ref[0, r, :].astype(jnp.float32)         # [g, D]
-            k = k_ref[0, :, j, :].astype(jnp.float32) \
-                * ks_ref[0, :, j:j + 1]                    # [page, D] f32
-            v = v_ref[0, :, j, :].astype(jnp.float32) \
-                * vs_ref[0, :, j:j + 1]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * jnp.float32(scale)
-            s = jnp.where(valid, s, jnp.float32(NEG_INF))  # [g, page]
-            m_prev = m_scr[r, :]                           # [g, 1]
-            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)                         # [g, page]
-            alpha = jnp.exp(m_prev - m_new)                # [g, 1]
-            l_scr[r, :] = l_scr[r, :] * alpha + p.sum(axis=1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [g, D]
-            acc_scr[r, :] = acc_scr[r, :] * alpha + pv
-            m_scr[r, :] = m_new
-
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _fin():
-        o_ref[0] = acc_scr[...] / jnp.maximum(l_scr[...], jnp.float32(1e-30))
-
-
-def _paged_q_pallas(q, k_pages, v_pages, k_scales, v_scales, page_table,
-                    seq_lens, scale, interpret):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, H, D = q.shape
-    HKV = k_pages.shape[2]
-    page_size = k_pages.shape[1]
-    NP = page_table.shape[1]
-
-    page_spec = pl.BlockSpec((1, page_size, HKV, D),
-                             lambda b, i, pt, ln: (pt[b, i], 0, 0, 0))
-    scale_spec = pl.BlockSpec((1, page_size, HKV),
-                              lambda b, i, pt, ln: (pt[b, i], 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, NP),
-        in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0)),
-            page_spec, page_spec, scale_spec, scale_spec,
-        ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, D), jnp.float32),
-        ],
-    )
-    # x64 OFF for the same Mosaic i64-index reason as _paged_pallas
-    with jax.enable_x64(False):
-        out = pl.pallas_call(
-            functools.partial(_paged_q_kernel, page_size=page_size,
-                              scale=scale, num_kv_heads=HKV),
-            name="paged_decode_q",
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-            interpret=interpret,
-        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-          q, k_pages, v_pages, k_scales.astype(jnp.float32),
-          v_scales.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
 def _paged_q_flash_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref,
                           vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
                           page_size, scale, num_kv_heads):
-    """Length-bounded twin of :func:`_paged_q_kernel`: the flash sweep
-    clamp of :func:`_paged_flash_kernel` with dequant fused into the page
-    loads — int8 engines ride the same
-    dead-page elision."""
+    """The dequant-fused twin of :func:`_paged_flash_kernel`: int8 page
+    tiles stream HBM->VMEM at half the bf16 bytes, and the per-(slot, head)
+    scale column multiplies them back to f32 IN VMEM right after the
+    convert — the full-precision page never exists outside the register
+    file.  Same sweep clamp, same dead-page elision."""
     from jax.experimental import pallas as pl
 
     b = pl.program_id(0)
@@ -1140,7 +841,7 @@ def _paged_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
             pltpu.VMEM((H, D), jnp.float32),
         ],
     )
-    # x64 OFF for the same Mosaic i64-index reason as _paged_pallas
+    # x64 OFF for the same Mosaic i64-index reason as _paged_flash_pallas
     with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(_paged_q_flash_kernel,
@@ -1292,7 +993,7 @@ def _paged_write_pallas(pools, rows, table, lens, interpret, layer):
         + [page_spec(pool) for pool in pools],
         out_specs=[page_spec(pool) for pool in pools],
     )
-    # x64 OFF for the same Mosaic i64-index reason as _paged_pallas.  The
+    # x64 OFF for the same Mosaic i64-index reason as _paged_flash_pallas.  The
     # name keeps this call out of ``paged_decode_roofline``, which sums the
     # decode program's kernels that carry none.
     with jax.enable_x64(False):
@@ -1360,8 +1061,10 @@ def paged_pool_write(pools, k, v, table, lens, layer):
 
 def paged_pool_prefill_write(pools, k, v, table, layer):
     """Whole prompts ``[B, S, h, d]`` into their table pages at position 0:
-    a chunk at length zero, filled up to whole pages with zeros (the
-    :func:`paged_table_prefill_write` contract)."""
+    a chunk at length zero, the last page filled up with zeros.  S is a
+    trace-time constant; rows shorter than S are right-padded by the caller
+    (the junk tokens go into pages that per-slot ``seq_lens`` masking keeps
+    invisible, or into the caller's scratch page)."""
     rows = tuple(_pad_to_pages(x, pools[0].shape[2])
                  for x in _pool_rows(pools, k, v))
     return _pool_write(pools, rows, table,
@@ -1434,57 +1137,66 @@ def paged_chunk_attend_quant(q, k_pages, v_pages, k_scales, v_scales,
         lens2, 1.0 / math.sqrt(D)).astype(q.dtype)
 
 
-class PagedKVCache:
-    """Block-paged KV cache manager (the allocator side of PagedAttention).
+# ---------------------------------------------------------- the cache seam
+# What a decoder layer knows of the paged cache: this one call.  The format
+# (which pools the tuple holds, who writes, which kernel attends, what a
+# length means to each entry) stays in this file.  No scope and no jit of
+# its own: the benchmark finds the decode kernel by the instruction name it
+# has under NO scope (``%step.N``), the chunk kernel under
+# ``chunk_attention``, the writer by its kernel name.
 
-    Pages are fixed-size blocks from one global pool; sequences grow by
-    whole pages, so HBM fragmentation is bounded by page_size·B instead of
-    max_seq·B.  Pure-functional jax state: (k_pages, v_pages, page_table,
-    seq_lens) threads through ``append``; the host-side free-list is static
-    round-robin (page i of seq b = b·max_pages + i), keeping every shape
-    static for jit.
-    """
 
-    def __init__(self, num_seqs, max_pages_per_seq, page_size, num_heads,
-                 head_dim, dtype=jnp.bfloat16):
-        self.page_size = page_size
-        self.capacity = max_pages_per_seq * page_size
-        total = num_seqs * max_pages_per_seq
-        self.k_pages = jnp.zeros((total, page_size, num_heads, head_dim), dtype)
-        self.v_pages = jnp.zeros_like(self.k_pages)
-        self.page_table = (
-            jnp.arange(num_seqs)[:, None] * max_pages_per_seq
-            + jnp.arange(max_pages_per_seq)[None, :]).astype(jnp.int32)
-        self.seq_lens = jnp.zeros((num_seqs,), jnp.int32)
+def paged_cache_attend(q, k, v, cache, prefill_attend):
+    """One decoder layer's attention through the paged KV cache: this
+    layer's K/V chunk goes into the pools, then ``q`` attends the pages.
 
-    def append(self, k_tok, v_tok):
-        """Write one token's K/V per sequence ([B, H, D]) at each seq's
-        current length; returns self (rebound arrays).
+    q ``[B, C, H, D]``, k / v ``[B, C, HKV, D]`` (Tensors; keys as they
+    are to be stored, e.g. already rotated); ``cache`` is ``(tag, layer,
+    pools, table, lens)``: the stacked pool tuple (``(kp, vp)``, or the
+    int8 cache's ``(kp, vp, ks, vs)``: K/V are quantized on the way in and
+    dequantized in the attention), this layer's index into it, the page
+    table ``[B, NP]`` and every slot's length BEFORE this chunk ``[B]``.
+    The chunk lands at positions ``lens[b] .. lens[b]+C-1`` and position t
+    attends keys ``0 .. lens[b]+t``, its own included.  ``tag``:
 
-        Raises when any sequence is already at capacity (eager path; under
-        jit the lengths are traced, so the guard is best-effort — JAX index
-        clamping would otherwise silently overwrite the LAST page, ADVICE
-        r4).  Size ``max_pages_per_seq`` for the longest decode up front,
-        exactly like the dense cache's max_len.
-        """
-        import jax.core as _core
+    - ``"served"``: one token a slot (C == 1: the decode kernel), or, with
+      C > 1, whole right-padded prompts at position 0: their pages are
+      written and attention is ``prefill_attend(q, k, v)``, the model's own
+      dense causal attention (nothing is in the cache before a prompt);
+    - ``"served_chunk"``: C tokens a slot at the slot's own position (a
+      prefill chunk, a speculative verify) through the chunk kernel.
 
-        if not isinstance(self.seq_lens, _core.Tracer):
-            full = int(jnp.max(self.seq_lens))
-            if full >= self.capacity:
-                raise RuntimeError(
-                    f"PagedKVCache overflow: a sequence is at capacity "
-                    f"{self.capacity} tokens ({self.capacity // self.page_size}"
-                    " pages); grow max_pages_per_seq")
-        B = k_tok.shape[0]
-        page_idx = self.seq_lens // self.page_size
-        offset = self.seq_lens % self.page_size
-        pages = self.page_table[jnp.arange(B), page_idx]
-        self.k_pages = self.k_pages.at[pages, offset].set(k_tok)
-        self.v_pages = self.v_pages.at[pages, offset].set(v_tok)
-        self.seq_lens = self.seq_lens + 1
-        return self
-
-    def attend(self, q):
-        return paged_attention(q, self.k_pages, self.v_pages,
-                               self.page_table, self.seq_lens)
+    Returns ``(attn [B, C, H, D], pools)``."""
+    tag, layer, pools, table, lens = cache
+    if tag not in ("served", "served_chunk"):
+        raise ValueError(f"unknown paged cache tag {tag!r}")
+    if tag == "served" and q.shape[1] > 1:
+        attn = prefill_attend(q, k, v)
+        # positions past a row's true length write junk into pages that
+        # per-slot seq_lens masking (or the engine's scratch page) keeps
+        # invisible
+        pools = _apply(
+            lambda kk, vv, tb, *pl: paged_pool_prefill_write(
+                pl, kk, vv, tb, layer),
+            k, v, table, *pools, n_outs=None, op_name="paged_write")
+        return attn, pools
+    pools = _apply(
+        lambda kk, vv, tb, ln, *pl: paged_pool_write(
+            pl, kk, vv, tb, ln, layer),
+        k, v, table, lens, *pools, n_outs=None, op_name="paged_write")
+    quantized = len(pools) == 4
+    if tag == "served_chunk":
+        attend = paged_chunk_attend_quant if quantized else paged_chunk_attend
+        attn = _apply(
+            lambda qq, tb, ln, *pl: attend(qq, *pl, tb, ln, layer=layer),
+            q, table, lens, *pools, op_name="paged_attention")
+    else:
+        # the decode entries take ONE query row a slot and the length that
+        # INCLUDES the token just written
+        attend = paged_attention_quantized if quantized else paged_attention
+        attn = _apply(
+            lambda qq, tb, ln, *pl: attend(
+                qq[:, 0], *pl, tb, ln.astype(jnp.int32) + 1,
+                layer=layer)[:, None],
+            q, table, lens, *pools, op_name="paged_attention")
+    return attn, pools

@@ -4,7 +4,7 @@ ONE implementation of absmax scale selection / int-grid rounding /
 dequantization, used by three layers that previously could have drifted:
 
 - the serving engine's quantized paged KV pools
-  (``ops.paged_attention.quantize_kv`` and the ``*_quant`` pool writes),
+  (``ops.paged_attention.quantize_kv`` on the way into the pool writes),
 - :class:`paddle_tpu.quantization.Int8Linear`'s weight/activation grids,
 - the calibration harness (``serving.quant.calibrate``).
 

@@ -58,14 +58,16 @@ import jax.numpy as jnp
 
 class GPTAdapter:
     """Adapter for :class:`paddle_tpu.text.models.GPTForCausalLM` (and any
-    model exposing the same ``.gpt`` decoder structure with the "served"
-    cache variant).  Subclasses override the pool hooks (``init_pools``,
+    model exposing the same ``.gpt`` decoder structure over the paged
+    cache).  Subclasses override the pool hooks (``init_pools``,
     ``page_bytes``, ``pool_owners``, ``pool_pspecs``) to change the KV
-    storage format without touching the closure shapes: the model's served
-    branch tells the formats apart by the pool tuple it is handed."""
+    storage format without touching the closure shapes: the cache seam
+    (``ops.paged_attention.paged_cache_attend``) tells the formats apart
+    by the pool tuple it is handed."""
 
-    #: GPTDecoderLayer cache-variant tags this adapter drives: one token or
-    #: a whole prompt per slot, and a chunk at the slot's own position
+    #: paged-cache tags this adapter drives (``paged_cache_attend``): one
+    #: token or a whole prompt per slot, and a chunk at the slot's own
+    #: position
     tag = "served"
     chunk_tag = "served_chunk"
     #: number of arrays in the pool tuple (the engine donates all of them)

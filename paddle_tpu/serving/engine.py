@@ -41,8 +41,8 @@ and README "Speculative decoding"): each iteration drafts up to k tokens
 per slot by n-gram suffix match over the slot's own context (prompt-lookup
 — no second model) and verifies them in ONE compiled multi-token step (the
 ``("verify", k_pad, …)`` program family; K/V for all k+1 positions lands
-in the page pools through ``ops.paged_attention.paged_table_chunk_write``
-/ ``paged_chunk_attend``).  The scheduler consumes the longest accepted
+in the page pools through ``ops.paged_attention.paged_cache_attend``: one
+chunk write, one chunk attention).  The scheduler consumes the longest accepted
 prefix plus the bonus token — 1..k+1 tokens per dispatch — with EOS /
 deadline / cancel / budget checks per emitted token.  Greedy rows accept
 by exact argmax match, so greedy output is byte-identical to the
@@ -400,13 +400,6 @@ class ServingEngine:
             prefill_chunk_tokens = None
         self._chunk_tokens = prefill_chunk_tokens
         self._prefill_rr = 0    # round-robin cursor over prefilling slots
-        # decode perf-family attribution: on TPU the paged kernels run the
-        # length-bounded flash sweep — a different roofline than the
-        # full-width legacy sweep, so the family carries an @flash tag
-        # (perf.candidate_hint keys remediation advice on it)
-        from ..ops.paged_attention import flash_decode_active
-
-        self._flash_tag = "@flash" if flash_decode_active() else ""
         # quantized serving (serving/quant, README "Quantized serving"):
         # kv_dtype="int8" stores the paged KV pools as int8 with parallel
         # per-(page slot, head) scale pools — quant fused into the pool
@@ -2749,7 +2742,7 @@ class ServingEngine:
                 f"{self._fam_suffix}{self._mp_suffix}")
 
     def _decode_family(self):
-        return f"decode{self._flash_tag}{self._fam_suffix}{self._mp_suffix}"
+        return f"decode{self._fam_suffix}{self._mp_suffix}"
 
     def _verify_family(self):
         return f"verify/k{self._spec_k}{self._fam_suffix}{self._mp_suffix}"

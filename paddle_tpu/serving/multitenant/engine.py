@@ -210,7 +210,7 @@ class MultiTenantEngine(ServingEngine):
         return f"prefill/{s_pad}{self._fam_suffix}{self._lora_fam}"
 
     def _decode_family(self):
-        return f"decode{self._flash_tag}{self._fam_suffix}{self._lora_fam}"
+        return f"decode{self._fam_suffix}{self._lora_fam}"
 
     def _prefill_chunk_family(self, c):
         return f"prefill_chunk/{c}{self._fam_suffix}{self._lora_fam}"

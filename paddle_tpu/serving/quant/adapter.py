@@ -10,12 +10,11 @@ four arrays instead of two:
   absmax scale per (page slot, kv head), addressed by the SAME page table.
 
 Quantization happens inside the compiled programs: handed a pool tuple
-with scale pools, the served cache branch of :class:`GPTDecoderLayer`
-rounds K/V onto the int8 grid on the way into every pool write
-(``ops.paged_attention.paged_pool_write``) and the paged attention
-consumers dequantize in-kernel
-(``paged_attention_quantized`` / ``paged_chunk_attend_quant``), so no
-full-precision copy of the cache ever materializes in HBM.  Rollback,
+with scale pools, the cache seam the decoder layers call
+(``ops.paged_attention.paged_cache_attend``) rounds K/V onto the int8
+grid on the way into every pool write and the paged attention consumers
+dequantize in-kernel, so no full-precision copy of the cache ever
+materializes in HBM.  Rollback,
 prefix pages, scratch-page masking and the chunk-write drop semantics are
 all untouched — the scale pool rides the exact same table addressing.
 
@@ -23,7 +22,7 @@ Chunked prefill (``ServingEngine(prefill_chunk_tokens=N)``) rides the
 inherited :meth:`GPTAdapter.prefill_chunk` unchanged: each chunk
 quantizes on the way into the pools and the engine's ``prefill_chunk/<c>@int8`` program family stays
 byte-identical to the monolithic int8 prefill.  On TPU the decode side of
-the same batch runs the int8 flash kernel (``decode@flash@int8``).
+the same batch runs the int8 decode kernel (``decode@int8``).
 
 The hierarchical KV cache (``prefix_cache="radix"`` + ``kv_spill=True``)
 needs no int8-specific code: the engine's spill snapshot/restore hooks

@@ -253,9 +253,23 @@ def jitted_decode(model, fwd, ids0, max_new_tokens, cache_shape, cache_dtype,
 
 
 def paged_pool_shape(batch, max_len, num_kv_heads, head_dim, page_size=16):
-    """[B, PP, ps, h, d] pool shape covering max_len tokens."""
+    """[B * PP, ps, h, d] shape of one layer's pages covering max_len
+    tokens a sequence; rows as wide as the serving engine lays them out
+    (``ops.paged_attention.pool_lane_dim``)."""
+    from ...ops.paged_attention import pool_lane_dim
+
     pp = -(-max_len // page_size)
-    return (batch, pp, page_size, num_kv_heads, head_dim)
+    return (batch * pp, page_size, num_kv_heads, pool_lane_dim(head_dim))
+
+
+def paged_cache(pools, batch, pos):
+    """generate()'s page pools as the serving engine's cache ``(tag, pools,
+    table, lens)``: every sequence owns a run of pages (page i of sequence
+    b is row ``b * PP + i``: an identity table) and all stand at ``pos``."""
+    n = pools[0].shape[1]
+    table = jnp.arange(n, dtype=jnp.int32).reshape(batch, n // batch)
+    return ("served", tuple(Tensor(p) for p in pools), Tensor(table),
+            Tensor(jnp.full((batch,), pos, jnp.int32)))
 
 
 def beam_search(model, input_ids, max_new_tokens, num_beams=4,

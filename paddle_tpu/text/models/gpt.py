@@ -26,6 +26,7 @@ from ...nn import functional as F
 from ...nn.layer import Layer, LayerList
 from ...nn.layers.common import Dropout, Embedding, Linear
 from ...nn.layers.norm import LayerNorm
+from ...ops.paged_attention import paged_cache_attend
 from ...tensor.dispatch import apply as _apply
 from ...tensor.tensor import Tensor
 
@@ -113,118 +114,26 @@ class GPTDecoderLayer(Layer):
         heads_here = qkv.shape[-1] // (3 * self.head_dim)
         qkv = qkv.reshape([B, S, heads_here, 3, self.head_dim])
         q, k, v = qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
-        if cache is not None and len(cache) == 5 \
-                and cache[0] in ("served", "served_chunk"):
-            # SERVED cache (continuous-batching engine, paddle_tpu.serving):
-            # ONE global page pool shared by every slot through an explicit
-            # per-slot page table [B, NP], and per-slot lengths [B] — each
-            # slot runs at its OWN position, which is what iteration-level
-            # batching needs (the "paged" branch below locks the whole
-            # batch to a single scalar ``pos``).  ``pools`` is the engine's
-            # pool tuple, every layer STACKED in it ([L, P, ps, h, d]): this
-            # layer is the index ``li`` into it, written and attended where
-            # it lies (ops.paged_attention.paged_pool_write), and the tuple
-            # goes on to the next layer.  With the int8 engine's scale
-            # pools in the tuple (paddle_tpu.serving.quant) quantization is
-            # fused into every pool write and dequantization into the paged
-            # attention consumers, so a full-precision cache copy never
-            # materializes in HBM.
-            from ...ops import paged_attention as pa
-
-            tag, li, pools, table, lens = cache
-            quantized = len(pools) == 4
-            if tag == "served" and S > 1:
-                # admit-time prefill: dense causal attention over the
-                # (right-padded) full-precision prompt; positions past a
-                # row's true length write junk into pages that per-slot
-                # seq_lens masking (or the engine's scratch page) keeps
-                # invisible
-                attn = F.scaled_dot_product_attention(
-                    q, k, v, is_causal=True, dropout_p=0.0, training=False)
-                pools = _apply(
-                    lambda kk, vv, tb, *pl: pa.paged_pool_prefill_write(
-                        pl, kk, vv, tb, li),
-                    k, v, table, *pools, n_outs=None, op_name="paged_write")
-            else:
-                # a decode token, or the C tokens of a chunk (chunked
-                # prefill; speculative verify: the slot's last sampled
-                # token plus C-1 drafts) at positions lens[b]..lens[b]+C-1:
-                # all land in the pools in one write, then every position
-                # attends against the pools with its OWN valid length —
-                # causality within a chunk comes from the per-position
-                # lengths (ops.paged_attention.paged_chunk_attend)
-                pools = _apply(
-                    lambda kk, vv, tb, ln, *pl: pa.paged_pool_write(
-                        pl, kk, vv, tb, ln, li),
-                    k, v, table, lens, *pools, n_outs=None,
-                    op_name="paged_write")
-                if tag == "served_chunk":
-                    attend = pa.paged_chunk_attend_quant if quantized \
-                        else pa.paged_chunk_attend
-                    attn = _apply(
-                        lambda qq, tb, ln, *pl: attend(qq, *pl, tb, ln,
-                                                       layer=li),
-                        q, table, lens, *pools, op_name="paged_attention")
-                else:
-                    attend = pa.paged_attention_quantized if quantized \
-                        else pa.paged_attention
-                    attn = _apply(
-                        lambda qq, tb, ln, *pl: attend(
-                            qq[:, 0], *pl, tb, ln.astype(jnp.int32) + 1,
-                            layer=li)[:, None],
-                        q, table, lens, *pools, op_name="paged_attention")
-            attn = attn.reshape([B, S, heads_here * self.head_dim])
-            x = residual + self.dropout(self._lin("out_proj", attn, lora))
-            residual = x
-            h = self.ln2(x)
-            h = self._lin("ffn2", self.act(self._lin("ffn1", h, lora)), lora)
-            x = residual + self.dropout(h)
-            return x, pools
-        if cache is not None and len(cache) == 4 and cache[0] == "paged":
-            # PAGED cache (serving decode): per-layer page pools
-            # [B, PP, ps, h, d] — HBM bound by pages allocated, not a dense
-            # [B, max_len] rectangle.  Prefill attends densely (flash/sdpa
-            # over the prompt) and writes the prompt's K/V into pages;
-            # each decode step writes one token and runs the length-bounded
-            # Pallas flash-decode kernel (ops/paged_attention): the page
-            # sweep is clamped per row by the scalar-prefetched seq_lens,
-            # so dead table slots past a row's length are never DMA'd.
-            from ...ops.paged_attention import (paged_decode_attend,
-                                                paged_prefill_write,
-                                                paged_token_write)
-
-            _, kp, vp, pos = cache
-            if S > 1:  # prefill
-                attn = F.scaled_dot_product_attention(
-                    q, k, v, is_causal=True, dropout_p=0.0, training=False)
-                kp = _apply(paged_prefill_write, kp, k, op_name="paged_write")
-                vp = _apply(paged_prefill_write, vp, v, op_name="paged_write")
-            else:
-                kp = _apply(lambda pgs, kk, p: paged_token_write(pgs, kk[:, 0], p),
-                            kp, k, pos, op_name="paged_write")
-                vp = _apply(lambda pgs, vv, p: paged_token_write(pgs, vv[:, 0], p),
-                            vp, v, pos, op_name="paged_write")
-                attn = _apply(
-                    lambda qq, kps, vps, p:
-                        paged_decode_attend(qq[:, 0], kps, vps, p)[:, None],
-                    q, kp, vp, pos, op_name="paged_attention")
-            attn = attn.reshape([B, S, heads_here * self.head_dim])
-            x = residual + self.dropout(self._lin("out_proj", attn, lora))
-            residual = x
-            h = self.ln2(x)
-            h = self._lin("ffn2", self.act(self._lin("ffn1", h, lora)), lora)
-            x = residual + self.dropout(h)
-            return x, ("paged", kp, vp, pos)
-        if cache is not None and len(cache) == 3:
+        if cache is not None and len(cache) == 5:
+            # PAGED cache (the serving engine's; generate(cache_impl=
+            # "paged") with an identity page table): ``(tag, layer, pools,
+            # table, lens)``.  The pool tuple holds every layer and this
+            # layer is an index into it: K/V are written and attended where
+            # they lie and the tuple goes on to the next layer.  What a
+            # paged cache is, is ops.paged_attention's business; an
+            # admit-time prompt attends densely, nothing being cached yet.
+            attn, cache = paged_cache_attend(
+                q, k, v, cache,
+                lambda q, k, v: F.scaled_dot_product_attention(
+                    q, k, v, is_causal=True, dropout_p=0.0, training=False))
+        elif cache is not None and len(cache) == 3:
             # STATIC cache (jitted decode): fixed [B, T, h, d] buffers written
             # in place at ``pos`` — shapes never change, so every decode step
             # reuses one compiled program (donated cache, no concat growth)
-            import jax as _jax
-
             k_buf, v_buf, pos = cache
 
             def write(buf, new, p):
-                return _jax.lax.dynamic_update_slice_in_dim(buf, new, p, 1)
+                return jax.lax.dynamic_update_slice_in_dim(buf, new, p, 1)
 
             k_buf = _apply(write, k_buf, k, pos, op_name="cache_write")
             v_buf = _apply(write, v_buf, v, pos, op_name="cache_write")
@@ -240,22 +149,17 @@ class GPTDecoderLayer(Layer):
             attn = F.scaled_dot_product_attention(
                 q, k_buf, v_buf, attn_mask=mask, dropout_p=0.0,
                 training=False)
-            attn = attn.reshape([B, S, heads_here * self.head_dim])
-            x = residual + self.dropout(self._lin("out_proj", attn, lora))
-            residual = x
-            h = self.ln2(x)
-            h = self._lin("ffn2", self.act(self._lin("ffn1", h, lora)), lora)
-            x = residual + self.dropout(h)
-            return x, (k_buf, v_buf, pos)
-        if cache is not None:
-            from ...tensor import manipulation as M
+            cache = (k_buf, v_buf, pos)
+        else:
+            if cache is not None:
+                from ...tensor import manipulation as M
 
-            k = M.concat([cache[0], k], axis=1)
-            v = M.concat([cache[1], v], axis=1)
-            cache = (k, v)
-        attn = F.scaled_dot_product_attention(
-            q, k, v, is_causal=cache is None, dropout_p=self.attn_dropout,
-            training=self.training)
+                k = M.concat([cache[0], k], axis=1)
+                v = M.concat([cache[1], v], axis=1)
+                cache = (k, v)
+            attn = F.scaled_dot_product_attention(
+                q, k, v, is_causal=cache is None,
+                dropout_p=self.attn_dropout, training=self.training)
         attn = attn.reshape([B, S, heads_here * self.head_dim])
         x = residual + self.dropout(self._lin("out_proj", attn, lora))
         residual = x
@@ -297,9 +201,9 @@ class GPTModel(Layer):
         # ``lora``: per-layer multi-tenant adapter slices (see
         # GPTDecoderLayer._lin / paddle_tpu.serving.multitenant) — a list
         # of per-layer dicts, or None for the base model
-        # ``cache``: a list of per-layer caches, or the serving engine's ONE
+        # ``cache``: a list of per-layer caches, or ONE paged cache
         # ``(tag, pools, table, lens)`` whose stacked pools every layer
-        # reads and writes at its own index (GPTDecoderLayer's "served"
+        # reads and writes at its own index (GPTDecoderLayer's paged
         # branch): the pool tuple is threaded through the layers and what
         # the last one returns comes back in the cache's place
         x = self.embed(input_ids, position_ids)
@@ -363,13 +267,15 @@ class GPTForCausalLM(Layer):
         ``use_cache=False``: the eager full-prefix loop (reference parity /
         debug path).
 
-        ``cache_impl="paged"``: block-paged KV cache — per-layer page pools
-        instead of dense [B, T] rectangles, decode attention through the
-        length-bounded Pallas flash-decode kernel (ops/paged_attention):
-        each row's page sweep stops at its own last valid page.  Same
-        tokens as the dense path (tests/test_paged_attention.py); KV HBM is
-        bounded by pages allocated (ceil(T/page_size) per sequence), the
-        serving property the reference's paged engine exists for."""
+        ``cache_impl="paged"``: block-paged KV cache — page pools (every
+        layer stacked in one array) instead of dense [B, T] rectangles, on
+        the serving engine's cache contract with an identity page table:
+        decode attention through the length-bounded Pallas decode kernel
+        (ops/paged_attention), where each row's page sweep stops at its own
+        last valid page.  Same tokens as the dense path
+        (tests/test_paged_attention.py); KV HBM is bounded by pages
+        allocated (ceil(T/page_size) per sequence), the serving property
+        the reference's paged engine exists for."""
         if decode_strategy == "beam_search":
             from ._decode import beam_search
 
@@ -407,29 +313,25 @@ class GPTForCausalLM(Layer):
         dt = gpt.word_embeddings.weight._value.dtype
 
         if cache_impl == "paged":
-            from ._decode import decode_loop, paged_pool_shape
+            from ._decode import decode_loop, paged_cache, paged_pool_shape
 
-            pool = paged_pool_shape(B, T, h_heads, blk.head_dim, page_size)
+            pool = (L,) + paged_pool_shape(B, T, h_heads, blk.head_dim,
+                                           page_size)
 
-            def fwd_paged(params, bufs, ids, cache, pos):
-                kps, vps = cache
+            def fwd_paged(params, bufs, ids, pools, pos):
                 with no_grad_ctx(), _rng.rng_scope(jax.random.key(0)), \
                         self.bind(params, bufs):
                     S = ids.shape[1]
                     pos_ids = pos + jnp.arange(S, dtype=jnp.int32)[None, :]
-                    lc = [("paged", Tensor(kps[i]), Tensor(vps[i]),
-                           Tensor(pos)) for i in range(L)]
-                    x, new_cache = gpt(Tensor(ids),
-                                       position_ids=Tensor(pos_ids), cache=lc)
+                    x, pools = gpt(Tensor(ids), position_ids=Tensor(pos_ids),
+                                   cache=paged_cache(pools, B, pos))
                     w = gpt.word_embeddings.weight._value
                     logits = (x._value[:, -1].astype(jnp.float32)
                               @ w.T.astype(jnp.float32))
-                    kps = jnp.stack([c[1]._value for c in new_cache])
-                    vps = jnp.stack([c[2]._value for c in new_cache])
-                return logits, (kps, vps)
+                return logits, tuple(p._value for p in pools)
 
             def init_cache():
-                kp = jnp.zeros((L,) + pool, dt)
+                kp = jnp.zeros(pool, dt)
                 return kp, jnp.zeros_like(kp)
 
             return decode_loop(self, fwd_paged, ids0, max_new_tokens,

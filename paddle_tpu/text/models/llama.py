@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from ...nn import functional as F
 from ...nn.layer import Layer
 from ...nn.layers.norm import RMSNorm
+from ...ops.paged_attention import paged_cache_attend
 from ...tensor.dispatch import apply as _apply
 from ...tensor.tensor import Tensor
 from .gpt import _col_linear, _row_linear, _vocab_embedding
@@ -139,46 +140,29 @@ class LlamaAttention(Layer):
 
         qh, kh, vh = _apply(attend, q, k, v, rope[0], rope[1],
                             op_name="llama_rope", n_outs=3)
-        if cache is not None and len(cache) == 4 and cache[0] == "paged":
-            # PAGED cache: per-layer [B, PP, ps, hkv, hd] pools, keys stored
-            # pre-rotated like the dense path; GQA attends grouped against
-            # the pools (no repeated-KV materialization in HBM) via
-            # ops.paged_attention's length-bounded flash-decode kernel —
-            # each page streams once for all g query heads of its KV head,
-            # and the sweep is clamped per row by the prefetched seq_lens.
-            from ...ops.paged_attention import (paged_decode_attend,
-                                                paged_prefill_write,
-                                                paged_token_write)
-
-            _, kp, vp, pos = cache
+        if cache is not None and len(cache) == 5:
+            # PAGED cache ``(tag, layer, pools, table, lens)``, the serving
+            # engine's contract (see GPTDecoderLayer): keys stored
+            # pre-rotated like the dense path, pools at hkv heads — grouped
+            # attention against them is ops.paged_attention's business, and
+            # only a whole prompt's own dense attention repeats K/V.
             if attn_bias is not None:
                 raise NotImplementedError(
                     "paged cache + attention_mask: per-sequence padding "
-                    "masks belong in seq_lens (PagedKVCache) — the uniform "
-                    "generate() paged path does not take a mask")
-            if S > 1:  # prefill: dense causal attention + page write
-                kf, vf = kh, vh
+                    "masks belong in per-slot lengths (`ServingEngine`) — "
+                    "the uniform generate() paged path does not take a mask")
+
+            def prefill_attend(qh, kh, vh):
                 if rep > 1:
-                    kf = _apply(lambda t: jnp.repeat(t, rep, axis=2), kh,
+                    kh = _apply(lambda t: jnp.repeat(t, rep, axis=2), kh,
                                 op_name="gqa_repeat")
-                    vf = _apply(lambda t: jnp.repeat(t, rep, axis=2), vh,
+                    vh = _apply(lambda t: jnp.repeat(t, rep, axis=2), vh,
                                 op_name="gqa_repeat")
-                att = F.scaled_dot_product_attention(qh, kf, vf,
-                                                     is_causal=True,
-                                                     training=False)
-                kp = _apply(paged_prefill_write, kp, kh, op_name="paged_write")
-                vp = _apply(paged_prefill_write, vp, vh, op_name="paged_write")
-            else:
-                kp = _apply(lambda pgs, kk, p: paged_token_write(pgs, kk[:, 0], p),
-                            kp, kh, pos, op_name="paged_write")
-                vp = _apply(lambda pgs, vv, p: paged_token_write(pgs, vv[:, 0], p),
-                            vp, vh, pos, op_name="paged_write")
-                att = _apply(
-                    lambda qq, kps, vps, p:
-                        paged_decode_attend(qq[:, 0], kps, vps, p)[:, None],
-                    qh, kp, vp, pos, op_name="paged_attention")
-            att = att.reshape([B, S, hq * hd])
-            return self.o_proj(att), ("paged", kp, vp, pos)
+                return F.scaled_dot_product_attention(
+                    qh, kh, vh, is_causal=True, training=False)
+
+            att, pools = paged_cache_attend(qh, kh, vh, cache, prefill_attend)
+            return self.o_proj(att.reshape([B, S, hq * hd])), pools
         if cache is not None:
             # STATIC cache decode (GPT pattern): fixed [B, T, hkv, hd]
             # buffers updated in place at ``pos``; keys stored PRE-ROTATED
@@ -310,6 +294,15 @@ class LlamaModel(Layer):
 
                 bias = _apply(build_bias, attention_mask,
                               op_name="llama_mask")
+        if isinstance(cache, tuple):
+            # ONE paged cache ``(tag, pools, table, lens)`` for all layers
+            # (GPTModel.forward): each reads and writes the stacked pools
+            # at its own index and hands the tuple on
+            pools = cache[1]
+            for i, layer in enumerate(self.layers):
+                x, pools = layer(x, (cos, sin), bias,
+                                 (cache[0], i, pools) + cache[2:])
+            return self.norm(x), pools
         if cache is not None:
             new_caches = []
             for layer, c in zip(self.layers, cache):
@@ -399,33 +392,28 @@ class LlamaForCausalLM(Layer):
 
         dt0 = self.llama.embed_tokens.weight._value.dtype
         if cache_impl == "paged":
-            from ._decode import decode_loop, paged_pool_shape
+            from ._decode import decode_loop, paged_cache, paged_pool_shape
 
-            pool = paged_pool_shape(B, T, hkv, hd, page_size)
+            pool = (L,) + paged_pool_shape(B, T, hkv, hd, page_size)
 
-            def fwd_paged(params, bufs, ids, cache, pos):
-                kps, vps = cache
+            def fwd_paged(params, bufs, ids, pools, pos):
                 with no_grad_ctx(), _rng.rng_scope(jax.random.key(0)), \
                         self.bind(params, bufs):
                     S = ids.shape[1]
                     pos_ids = Tensor(pos + jnp.arange(S, dtype=jnp.int32))
-                    lc = [("paged", Tensor(kps[i]), Tensor(vps[i]),
-                           Tensor(pos)) for i in range(L)]
-                    hidden, new_cache = self.llama(Tensor(ids),
-                                                   position_ids=pos_ids,
-                                                   cache=lc)
+                    hidden, pools = self.llama(
+                        Tensor(ids), position_ids=pos_ids,
+                        cache=paged_cache(pools, B, pos))
                     h = hidden._value[:, -1].astype(jnp.float32)
                     if self.tie:
                         w = self.llama.embed_tokens.weight._value
                         logits = h @ w.T.astype(jnp.float32)
                     else:
                         logits = h @ self.lm_head.weight._value.astype(jnp.float32)
-                    kps = jnp.stack([c[1]._value for c in new_cache])
-                    vps = jnp.stack([c[2]._value for c in new_cache])
-                return logits, (kps, vps)
+                return logits, tuple(p._value for p in pools)
 
             def init_cache():
-                kp = jnp.zeros((L,) + pool, dt0)
+                kp = jnp.zeros(pool, dt0)
                 return kp, jnp.zeros_like(kp)
 
             return decode_loop(self, fwd_paged, ids0, max_new_tokens,

@@ -2,10 +2,10 @@
 
 Kernel side: interpret-mode parity of the length-bounded flash-decode
 Pallas path against the dense references across ragged seq_lens, GQA
-group sizes, and int8 pools; empty rows against the legacy kernel (the
-dense reference's softmax over an all-masked row is uniform, not zero —
-a pre-existing ref semantic, so lens=0 rows are compared kernel-vs-
-kernel); and the dead-page guarantee (garbage written past every row's
+group sizes, and int8 pools; empty rows against zeros (the dense
+reference's softmax over an all-masked row is uniform, not zero — a
+pre-existing ref semantic, so lens=0 rows are not compared with it); and
+the dead-page guarantee (garbage written past every row's
 length must not move the output by one bit).
 
 Scheduler side: ServingEngine(prefill_chunk_tokens=N) greedy byte-parity
@@ -112,19 +112,18 @@ def test_flash_int8_parity():
 
 def test_flash_empty_rows_match_legacy_kernel():
     """lens=0 rows: the dense reference's all-masked softmax is UNIFORM
-    (mean of V — a pre-existing ref semantic), while both kernels emit
-    zeros; flash must match the legacy kernel bit-for-bit there, and the
-    reference everywhere else."""
+    (mean of V — a pre-existing ref semantic), while the kernel emits the
+    zeros an empty row must produce (it initialises, accumulates nothing
+    and finalises at step 0), bit-for-bit; the reference everywhere
+    else."""
     from paddle_tpu.ops.paged_attention import (_paged_flash_pallas,
-                                                _paged_pallas,
                                                 paged_attention_ref)
 
     q, k, v, table, seq_lens = _mk_paged(lens=(0, 7, 40), seed=11)
-    legacy = np.asarray(_paged_pallas(q, k, v, table, seq_lens, 0.25, True))
     flash = np.asarray(
         _paged_flash_pallas(q, k[None], v[None], table, seq_lens, 0.25, True,
                             0))
-    np.testing.assert_array_equal(flash[0], legacy[0])     # empty row
+    np.testing.assert_array_equal(flash[0], np.zeros_like(flash[0]))
     ref = np.asarray(paged_attention_ref(q, k, v, table, seq_lens,
                                          scale=0.25))
     np.testing.assert_allclose(flash[1:], ref[1:], atol=2e-5)
@@ -413,8 +412,10 @@ def test_chunked_prefill_cancel_mid_prefill(model):
 
 # =================================================== perf-family plumbing
 def test_candidate_hint_flash_and_chunk_families():
-    """candidate_hint recognizes decode@flash / prefill_chunk/<c> — and
-    stops suggesting 'chunk the prefill' once a family is chunked."""
+    """candidate_hint recognizes decode{@int8} / prefill_chunk/<c> — and
+    stops suggesting 'chunk the prefill' once a family is chunked.  Decode
+    families carry no kernel tag: there is one decode sweep, the
+    length-bounded one, on every backend."""
     hint = perf_mod.candidate_hint("prefill/64", "bandwidth-bound",
                                    temp_bytes=9e6, pool_bytes=1e6)
     assert "prefill_chunk_tokens=N" in hint
@@ -422,12 +423,12 @@ def test_candidate_hint_flash_and_chunk_families():
                                    temp_bytes=9e6, pool_bytes=1e6)
     assert "chunk the prefill" not in hint
     assert "lower" in hint and "prefill_chunk_tokens" in hint
-    assert "length-bounded" in perf_mod.candidate_hint(
-        "decode@flash", "bandwidth-bound")
-    assert "int8 flash" in perf_mod.candidate_hint(
-        "decode@flash@int8", "bandwidth-bound")
-    assert perf_mod.is_flash_family("decode@flash@int8")
-    assert not perf_mod.is_flash_family("decode@int8")
+    assert 'kv_dtype="int8"' in perf_mod.candidate_hint(
+        "decode", "bandwidth-bound")
+    assert "dequant already fused" in perf_mod.candidate_hint(
+        "decode@int8", "bandwidth-bound")
+    assert perf_mod.is_quantized_family("decode@int8")
+    assert not perf_mod.is_quantized_family("decode")
     assert perf_mod.is_chunked_prefill_family("prefill_chunk/16@lora-r4")
     assert not perf_mod.is_chunked_prefill_family("prefill/64")
 
@@ -441,5 +442,8 @@ def test_engine_prefill_chunk_family_names(model):
     eng = ServingEngine(model, num_slots=2, page_size=PS,
                         max_model_len=MAXLEN, prefill_chunk_tokens=8)
     assert eng._prefill_chunk_family(8) == "prefill_chunk/8"
-    # CPU backend: no @flash tag (flash_decode_active() is TPU-only)
+    # decode{@int8}{@mpN} on every backend: no kernel tag
     assert eng._decode_family() == "decode"
+    q = ServingEngine(model, num_slots=2, page_size=PS, max_model_len=MAXLEN,
+                      kv_dtype="int8")
+    assert q._decode_family() == "decode@int8"

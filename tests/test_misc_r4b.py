@@ -181,7 +181,7 @@ def test_fused_multi_transformer_paged_cache_matches_dense():
     full = mt(x).numpy()
 
     caches = mt.gen_cache(2, 8, impl="paged", page_size=4)
-    assert caches[0][0] == "paged"
+    assert caches[0][0] == "served"
     assert tuple(caches[0][1].shape) == (2, 2, 4, 2, 8)  # [B, PP, ps, H, D]
     # prefill 3 tokens, then decode the rest one at a time
     o, caches = mt(paddle.to_tensor(x.numpy()[:, :3]), caches=caches,

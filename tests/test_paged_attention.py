@@ -1,5 +1,6 @@
-"""Paged attention kernel + PagedKVCache manager (SURVEY.md §2.1 inference
-engine row adjacency: the serving-side decode attention primitive)."""
+"""Paged attention kernels and the cache seam the models call (SURVEY.md
+§2.1 inference engine row adjacency: the serving-side decode attention
+primitive)."""
 
 import math
 
@@ -9,7 +10,7 @@ import jax.numpy as jnp
 import pytest
 
 from paddle_tpu.ops.paged_attention import (
-    PagedKVCache, paged_attention, paged_attention_ref, _paged_pallas,
+    paged_attention, paged_attention_ref, _paged_flash_pallas,
 )
 
 
@@ -51,9 +52,12 @@ def test_ref_matches_dense_oracle():
 
 def test_pallas_kernel_matches_ref_interpret():
     q, kp, vp, table, lens = _setup()
-    got = np.asarray(_paged_pallas(q, kp, vp, table, lens,
-                                   1.0 / math.sqrt(q.shape[-1]),
-                                   interpret=True))
+    # the length-bounded kernel takes stacked pools and a layer: layer 1
+    # of two, the other poisoned
+    kp2, vp2 = (jnp.stack([jnp.full_like(p, 9.0), p]) for p in (kp, vp))
+    got = np.asarray(_paged_flash_pallas(q, kp2, vp2, table, lens,
+                                         1.0 / math.sqrt(q.shape[-1]),
+                                         True, 1))
     want = np.asarray(paged_attention_ref(q, kp, vp, table, lens))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
@@ -64,31 +68,6 @@ def test_public_entry_dispatches_and_jits():
     got = np.asarray(f(q, kp, vp, table, lens))
     want = np.asarray(paged_attention_ref(q, kp, vp, table, lens))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
-
-
-def test_paged_kv_cache_decode_loop_matches_full_attention():
-    """Grow the cache token by token, attend each step; the final step must
-    equal full attention over the accumulated keys."""
-    rs = np.random.RandomState(2)
-    B, H, D, page, maxp = 2, 2, 8, 4, 3
-    cache = PagedKVCache(B, maxp, page, H, D, dtype=jnp.float32)
-    T = 10
-    ks = rs.randn(T, B, H, D).astype("float32") * 0.5
-    vs = rs.randn(T, B, H, D).astype("float32") * 0.5
-    for t in range(T):
-        cache = cache.append(jnp.asarray(ks[t]), jnp.asarray(vs[t]))
-    assert int(cache.seq_lens[0]) == T
-    q = jnp.asarray(rs.randn(B, H, D).astype("float32") * 0.5)
-    got = np.asarray(cache.attend(q))
-    # dense oracle over the T tokens in insertion order
-    for b in range(B):
-        for h in range(H):
-            s = np.stack([ks[t, b, h] for t in range(T)]) @ np.asarray(q[b, h])
-            s /= math.sqrt(D)
-            p = np.exp(s - s.max())
-            p /= p.sum()
-            want = p @ np.stack([vs[t, b, h] for t in range(T)])
-            np.testing.assert_allclose(got[b, h], want, rtol=2e-4, atol=2e-4)
 
 
 def test_padded_pages_are_masked():
@@ -154,3 +133,124 @@ def test_paged_pool_hbm_bound_by_pages():
     dense_elems = B * dense_max_len * hkv * hd
     assert paged_elems == B * 6 * ps * hkv * hd
     assert paged_elems * 20 < dense_elems
+
+
+# ------------------------------------------ one contract, no pool copies
+def _whole_pool_ops(jaxpr, pool_shape):
+    """Names of the slice / concatenate / dynamic_update_slice equations,
+    at any depth of ``jaxpr``, that read or produce a whole pool (or the
+    pool less its layer dim): what slicing a layer out of the stacked pool
+    and stacking layers back leaves in a traced program."""
+    sizes = {int(np.prod(pool_shape)), int(np.prod(pool_shape[1:]))}
+    found = []
+
+    def walk(j):
+        for e in j.eqns:
+            if e.primitive.name in ("slice", "dynamic_slice", "concatenate",
+                                    "dynamic_update_slice"):
+                avals = [v.aval for v in (*e.invars, *e.outvars)
+                         if hasattr(v, "aval")]
+                if any(int(np.prod(a.shape)) in sizes for a in avals
+                       if getattr(a, "shape", None)):
+                    found.append(e.primitive.name)
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jaxpr)
+    return found
+
+
+def _tiny_lm(family):
+    import paddle_tpu as paddle
+    from paddle_tpu.text.models import GPTForCausalLM, LlamaForCausalLM
+
+    paddle.seed(0)
+    kw = dict(vocab_size=96, hidden_size=32, num_hidden_layers=3,
+              num_attention_heads=4, max_position_embeddings=64)
+    if family == "gpt":
+        return GPTForCausalLM(**kw)
+    return LlamaForCausalLM(num_key_value_heads=2, intermediate_size=64, **kw)
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama"])
+def test_generate_paged_step_holds_no_whole_pool_copy(family):
+    """generate(cache_impl="paged") threads ONE stacked pool tuple through
+    the layers on the served contract: its traced step neither slices a
+    layer out of a pool nor stacks layers back nor updates a whole pool."""
+    import paddle_tpu as paddle
+    from paddle_tpu.text.models._decode import program_store
+
+    m = _tiny_lm(family)
+    ids = paddle.to_tensor(np.arange(10, dtype="int64").reshape(2, 5))
+    m.generate(ids, max_new_tokens=3, temperature=0.0, cache_impl="paged",
+               page_size=4)
+    (key, (_, step)), = [kv for kv in program_store(m).items()
+                         if kv[0][0] == "paged"]
+    params = {k: p._value for k, p in m.named_parameters()}
+    bufs = {k: b._value for k, b in m.named_buffers()}
+    L, B, PP = 3, 2, 2                      # T = 8 tokens: 2 pages of 4
+    hkv = 4 if family == "gpt" else 2
+    pool = jnp.zeros((L, B * PP, 4, hkv, 8), jnp.float32)
+    jaxpr = jax.make_jaxpr(step)(
+        params, bufs, jnp.zeros((B, 1), jnp.int64), (pool, pool),
+        np.int32(5), jax.random.key(0)).jaxpr
+    assert _whole_pool_ops(jaxpr, pool.shape) == []
+    # and the pool really is that one: every layer in it, written in place
+    text = str(jaxpr)
+    assert "scatter" in text and f"f32[{L},{B * PP},4,{hkv},8]" in text
+
+
+@pytest.mark.parametrize("chunk", [1, 4])
+def test_llama_layer_on_served_contract_matches_dense(chunk):
+    """LlamaModel on the serving engine's cache, every slot at its OWN
+    length (GQA, rotary positions per slot): a decode token (chunk 1) and
+    a chunk of 4 give the hidden states the dense forward gives at those
+    positions, through a scrambled page table."""
+    import paddle_tpu as paddle
+    from paddle_tpu.tensor.tensor import Tensor
+
+    m = _tiny_lm("llama").eval()
+    llama = m.llama
+    rs = np.random.RandomState(3)
+    B, ps, NP, L, hkv, hd = 3, 4, 5, 3, 2, 8
+    lens = np.array([2, 9, 5], "int32")          # tokens already cached
+    ids = rs.randint(0, 96, (B, int(lens.max()) + chunk)).astype("int64")
+    dense = [llama(Tensor(jnp.asarray(ids[b:b + 1, :lens[b] + chunk])))
+             .numpy()[0, lens[b]:] for b in range(B)]
+
+    table = rs.permutation(B * NP).reshape(B, NP).astype("int32")
+    pools = tuple(Tensor(jnp.zeros((L, B * NP + 1, ps, hkv, hd), jnp.float32))
+                  for _ in range(2))
+    # the cached prefix of each slot: right-padded prompts at position 0
+    S0 = int(lens.max())
+    prompt = np.where(np.arange(S0)[None, :] < lens[:, None], ids[:, :S0], 0)
+    _, pools = llama(Tensor(jnp.asarray(prompt)),
+                     cache=("served", pools, Tensor(jnp.asarray(table)),
+                            Tensor(jnp.asarray(lens))))
+    new = np.stack([ids[b, lens[b]:lens[b] + chunk] for b in range(B)])
+    pos = lens[:, None] + np.arange(chunk, dtype="int32")[None, :]
+    tag = "served" if chunk == 1 else "served_chunk"
+    got, _ = llama(Tensor(jnp.asarray(new)),
+                   position_ids=Tensor(jnp.asarray(pos)),
+                   cache=(tag, pools, Tensor(jnp.asarray(table)),
+                          Tensor(jnp.asarray(lens))))
+    for b in range(B):
+        np.testing.assert_allclose(got.numpy()[b], dense[b], rtol=2e-4,
+                                   atol=2e-4)
+
+
+def test_cache_seam_refuses_a_tag_it_does_not_know():
+    """The per-sequence ``"paged"`` cache tuple is gone: the seam serves
+    ``"served"`` and ``"served_chunk"`` and says so of anything else."""
+    from paddle_tpu.ops.paged_attention import paged_cache_attend
+    from paddle_tpu.tensor.tensor import Tensor
+
+    x = Tensor(jnp.zeros((1, 1, 2, 8), jnp.float32))
+    pool = Tensor(jnp.zeros((1, 2, 4, 2, 8), jnp.float32))
+    cache = ("paged", 0, (pool, pool), Tensor(jnp.zeros((1, 2), jnp.int32)),
+             Tensor(jnp.zeros((1,), jnp.int32)))
+    with pytest.raises(ValueError, match="unknown paged cache tag 'paged'"):
+        paged_cache_attend(x, x, x, cache, None)

@@ -375,9 +375,7 @@ def _paged_args(quantized=False, chunk=None):
 #: repointed before they are named ``paged_decode``, ``flash_dkdv`` and
 #: ``flash_dq``).
 PAGED_SITES = {
-    "legacy": ("_paged_pallas", False, "paged_decode"),
     "flash_decode": ("_paged_flash_pallas", False, ""),
-    "legacy_int8": ("_paged_q_pallas", True, "paged_decode_q"),
     "flash_decode_int8": ("_paged_q_flash_pallas", True, "paged_decode_q"),
 }
 

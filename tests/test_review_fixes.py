@@ -274,22 +274,6 @@ def test_dataloader_raises_on_killed_worker():
     assert err and "died" in str(err[0])
 
 
-def test_paged_cache_append_capacity_guard():
-    """append past max_pages_per_seq*page_size raises instead of silently
-    overwriting the last page (r4 advisor low)."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops.paged_attention import PagedKVCache
-
-    c = PagedKVCache(num_seqs=2, max_pages_per_seq=2, page_size=2,
-                     num_heads=1, head_dim=4)
-    tok = jnp.ones((2, 1, 4), jnp.bfloat16)
-    for _ in range(4):
-        c = c.append(tok, tok)
-    with np.testing.assert_raises(RuntimeError):
-        c.append(tok, tok)
-
-
 def test_asp_conv_mask_groups_reduction_tail():
     """Conv [Co,Ci,kh,kw] masks group along flattened Ci*kh*kw, keeping
     every output channel's K-groups 2:4 (r4 advisor low: grouping along Co

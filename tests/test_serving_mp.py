@@ -258,7 +258,7 @@ def test_candidate_hint_recognizes_mp_families():
 
     assert is_mp_family("decode@mp2") and is_mp_family("prefill/64@mp4")
     assert not is_mp_family("decode@int8")
-    assert mp_degree("decode@flash@mp4") == 4
+    assert mp_degree("decode@int8@mp4") == 4
     assert mp_degree("verify/k2@int8@mp2") == 2
     assert mp_degree("decode") == 1
     h = candidate_hint("decode@mp2", "bandwidth-bound")

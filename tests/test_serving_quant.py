@@ -146,46 +146,44 @@ def test_scale_selection_absmax_vs_percentile():
 
 
 def test_quantized_pool_writes_roundtrip():
-    """prefill/token/chunk quantizing writes agree with each other and
-    round-trip within the per-(slot, head) grid bound."""
-    from paddle_tpu.ops.paged_attention import (
-        paged_table_chunk_write_quant, paged_table_prefill_write_quant,
-        paged_table_token_write_quant)
+    """prefill/token/chunk writes into the four-pool tuple quantize on the
+    way in, agree with each other and round-trip within the per-(slot,
+    head) grid bound; only the layer written is touched."""
+    from paddle_tpu.ops.paged_attention import (paged_pool_prefill_write,
+                                                paged_pool_write)
 
     rs = np.random.RandomState(2)
-    B, S, h, d, ps, P = 2, 16, 2, 8, 4, 12
-    kv = jnp.asarray(rs.randn(B, S, h, d).astype("float32"))
+    B, S, h, d, ps, P, L = 2, 16, 2, 8, 4, 12, 2
+    k = jnp.asarray(rs.randn(B, S, h, d).astype("float32"))
+    v = jnp.asarray(rs.randn(B, S, h, d).astype("float32"))
     table = jnp.asarray(
         np.stack([np.arange(0, 4), np.arange(4, 8)]).astype("int32"))
 
     def pools():
-        return (jnp.zeros((P, ps, h, d), jnp.int8),
-                jnp.zeros((P, ps, h), jnp.float32))
+        return (jnp.zeros((L, P, ps, h, d), jnp.int8),) * 2 \
+            + (jnp.zeros((L, P, ps, h), jnp.float32),) * 2
 
-    # prefill: whole prompt in one shot
-    pool_a, sp_a = paged_table_prefill_write_quant(*pools(), kv, table)
-    got = dequantize(pool_a[table].reshape(B, S, h, d),
-                     sp_a[table].reshape(B, S, h)[..., None])
-    err = np.abs(np.asarray(got) - np.asarray(kv))
-    bound = np.abs(np.asarray(kv)).max(-1, keepdims=True) / 127 * 0.51 + 1e-7
-    assert (err <= bound).all()
-    # token-by-token at per-slot positions reproduces the same pool bytes
-    pool_b, sp_b = pools()
-    for t in range(S):
-        lens = jnp.full((B,), t, jnp.int32)
-        pool_b, sp_b = paged_table_token_write_quant(
-            pool_b, sp_b, kv[:, t], table, lens)
-    np.testing.assert_array_equal(np.asarray(pool_a), np.asarray(pool_b))
-    np.testing.assert_allclose(np.asarray(sp_a), np.asarray(sp_b))
-    # chunk writes (speculative verify) land the same bytes too
-    pool_c, sp_c = pools()
-    C = 4
-    for t in range(0, S, C):
-        lens = jnp.full((B,), t, jnp.int32)
-        pool_c, sp_c = paged_table_chunk_write_quant(
-            pool_c, sp_c, kv[:, t:t + C], table, lens)
-    np.testing.assert_array_equal(np.asarray(pool_a), np.asarray(pool_c))
-    np.testing.assert_allclose(np.asarray(sp_a), np.asarray(sp_c))
+    # prefill: whole prompt in one shot, into layer 1
+    a = paged_pool_prefill_write(pools(), k, v, table, 1)
+    for kv, pool, sp in ((k, a[0], a[2]), (v, a[1], a[3])):
+        got = dequantize(pool[1][table].reshape(B, S, h, d),
+                         sp[1][table].reshape(B, S, h)[..., None])
+        err = np.abs(np.asarray(got) - np.asarray(kv))
+        bound = np.abs(np.asarray(kv)).max(-1, keepdims=True) / 127 * 0.51 \
+            + 1e-7
+        assert (err <= bound).all()
+        assert not np.asarray(pool[0]).any() and not np.asarray(sp[0]).any()
+    # token-by-token (a chunk of one) and in chunks of 4 (speculative
+    # verify) at per-slot positions: the same pool bytes
+    for C in (1, 4):
+        b = pools()
+        for t in range(0, S, C):
+            b = paged_pool_write(b, k[:, t:t + C], v[:, t:t + C], table,
+                                 jnp.full((B,), t, jnp.int32), 1)
+        for x, y in zip(a[:2], b[:2]):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        for x, y in zip(a[2:], b[2:]):
+            np.testing.assert_allclose(np.asarray(x), np.asarray(y))
 
 
 def test_quantized_attention_matches_dequantized_reference():
@@ -220,7 +218,7 @@ def test_quantized_pallas_kernel_interpret_matches_ref():
     import math
 
     from paddle_tpu.ops.paged_attention import (
-        _paged_q_pallas, paged_attention_quantized_ref, quantize_kv)
+        _paged_q_flash_pallas, paged_attention_quantized_ref, quantize_kv)
 
     rs = np.random.RandomState(4)
     B, H, HKV, d, ps, NP = 3, 4, 2, 16, 8, 4
@@ -232,8 +230,12 @@ def test_quantized_pallas_kernel_interpret_matches_ref():
         rs.randn(total, ps, HKV, d).astype("float32") * 0.5))
     table = jnp.asarray(rs.permutation(total).reshape(B, NP).astype("int32"))
     lens = jnp.asarray(np.array([5, 17, 31], "int32"))
-    got = np.asarray(_paged_q_pallas(q, kq, vq, ks, vs, table, lens,
-                                     1.0 / math.sqrt(d), interpret=True))
+    # the length-bounded kernel: stacked pools and a layer (1 of two, the
+    # other's scales poisoned)
+    stacked = [jnp.stack([x, x]) for x in (kq, vq)] \
+        + [jnp.stack([x * 100.0, x]) for x in (ks, vs)]
+    got = np.asarray(_paged_q_flash_pallas(q, *stacked, table, lens,
+                                           1.0 / math.sqrt(d), True, 1))
     want = np.asarray(paged_attention_quantized_ref(q, kq, vq, ks, vs,
                                                     table, lens))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
