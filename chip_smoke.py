@@ -53,8 +53,11 @@ KERNEL_TOL = 2e-2
 #: the run's sizes.  tests/test_smoke_rehearsal.py rehearses the same phase
 #: functions on the CPU with a toy copy of this table.
 FULL = {
-    # (H, HKV, D): GPT-base heads, and GQA at head_dim 128
-    "kernels": {"heads": [(12, 12, 64), (16, 4, 128)], "page_size": 16,
+    # (H, HKV, D): GPT-base heads (rows of 64 lanes, and 12 heads are no
+    # whole tile: decoded a page a grid step), GQA at head_dim 128, the
+    # served pools' 16 rows of 128
+    "kernels": {"heads": [(12, 12, 64), (16, 4, 128), (16, 16, 128)],
+                "page_size": 16,
                 "table_pages": 64, "rows": 8, "chunk": 8,
                 # (B, S, H, D) or (B, S, H, D_qk, D_v): GPT heads, and
                 # latent attention's 192 / 128 at the trained length
@@ -204,11 +207,18 @@ def phase_kernels(cfg):
         kp, vp = jnp.asarray(kf, jnp.bfloat16), jnp.asarray(vf, jnp.bfloat16)
         table = jnp.asarray(rs.permutation(P - 1)[:B * NP].reshape(B, NP),
                             jnp.int32)
-        # lengths: 1, page edges, the full table, and random in between
+        # lengths: 1, page edges, the full table, a block's edge (128
+        # keys) and one key past it, one that overruns the table, and
+        # random in between
         lens = rs.randint(1, NP * ps + 1, (B,))
-        lens[:4] = [1, ps, ps + 1, NP * ps]
+        edges = [1, ps, ps + 1, NP * ps, 128, 129, NP * ps + 37]
+        lens[:len(edges)] = edges[:B]
         lens = jnp.asarray(lens, jnp.int32)
         tag = f"H{H}_HKV{HKV}_D{D}"
+        # a table that is no multiple of a block, narrower than one at
+        # the rehearsal's size: its first columns, the lengths as they are
+        # (most now overrun it)
+        narrow = table[:, :max(NP // 4 - 3, 1)]
 
         # the kernels read their pools as the engine holds them: stacked
         # over layers, the layer an index (here the second of two); the
@@ -218,18 +228,19 @@ def phase_kernels(cfg):
                 x, *(jnp.stack([jnp.zeros_like(p), p]) for p in pools),
                 *tail, layer=1))
 
-        _check(f"paged_flash/{tag}", stacked(pa.paged_attention, table, lens),
-               lambda q, kp, vp: pa.paged_attention_ref(q, kp, vp, table,
-                                                        lens),
-               (q, kp, vp), 1, out)
-
         kq, ks = pa.quantize_kv(jnp.asarray(kf))
         vq, vs = pa.quantize_kv(jnp.asarray(vf))
-        _check(f"paged_q_flash/{tag}",
-               stacked(pa.paged_attention_quantized, table, lens),
-               lambda q, *a: pa.paged_attention_quantized_ref(q, *a, table,
-                                                              lens),
-               (q, kq, vq, ks, vs), 1, out)
+        for tb, width in ((table, ""), (narrow, f"_NP{narrow.shape[1]}")):
+            _check(f"paged_flash/{tag}{width}",
+                   stacked(pa.paged_attention, tb, lens),
+                   lambda q, kp, vp, tb=tb: pa.paged_attention_ref(
+                       q, kp, vp, tb, lens),
+                   (q, kp, vp), 1, out)
+            _check(f"paged_q_flash/{tag}{width}",
+                   stacked(pa.paged_attention_quantized, tb, lens),
+                   lambda q, *a, tb=tb: pa.paged_attention_quantized_ref(
+                       q, *a, tb, lens),
+                   (q, kq, vq, ks, vs), 1, out)
 
         # the chunk path: C positions per slot, each with its own length —
         # the reference attends the [B*C]-row expansion densely
@@ -502,6 +513,27 @@ def _engine_programs(engine, chunk):
     }
 
 
+def _decode_sweep(engine):
+    """How the decode kernel walks one device's share of the engine's
+    pools: the kernel that attends, the pages of one block of its sweep,
+    and its grid steps a layer."""
+    import importlib
+
+    import jax
+
+    pa = importlib.import_module("paddle_tpu.ops.paged_attention")
+    local = engine._pools[0].addressable_shards[0].data
+    slots = engine._h_lens.shape[0]
+    blocking = pa._decode_blocking(
+        jax.ShapeDtypeStruct((slots,) + local.shape[-2:], local.dtype),
+        local, engine.table_width)
+    if blocking is None:        # no DMA takes a page of these pools
+        return {"kernel": "page", "pages_a_step": 1,
+                "grid_steps_a_layer": slots * engine.table_width}
+    return {"kernel": "decode", "pages_a_step": blocking[0],
+            "grid_steps_a_layer": slots}
+
+
 def _expect_engine_mosaic(engine, chunk, layers):
     """Mosaic calls in the lowered serving programs: the pool writer
     (``paged_write``) ONCE, a function of its shapes that every layer
@@ -634,6 +666,8 @@ def phase_serve(cfg, kv_dtype, bf16=True, mesh=None, replica=None):
             raise AssertionError(f"{restarts} engine restarts, {faults} "
                                  "numeric faults")
         mosaic = _expect_engine_mosaic(engine, cfg["chunk"], layers)
+        sweep = _decode_sweep(engine)
+        log(f"  decode sweep: {sweep}")
         in_place = _expect_pools_in_place(engine, cfg["chunk"])
         pool_devices = distinct_devices(engine._pools[0])
         param_devices = max(distinct_devices(v)
@@ -643,7 +677,8 @@ def phase_serve(cfg, kv_dtype, bf16=True, mesh=None, replica=None):
              "cold_pass_s": round(t1 - t0, 1),
              "steady_pass_s": round(t2 - t1, 2), "programs": traces,
              "step_traces": 1, "engine_restarts": 0, "numeric_faults": 0,
-             "mosaic_calls": mosaic, "programs_outside_kernels": in_place,
+             "mosaic_calls": mosaic, "decode_sweep": sweep,
+             "programs_outside_kernels": in_place,
              "pool_devices": pool_devices, "param_devices": param_devices}
     return facts, ids
 

@@ -26,11 +26,12 @@ Layout:
     page_table [B, NP] int32       page ids per slot (row-padded)
     seq_lens   [B]     int32       valid token count per slot
 
-Four kernel bodies: decode (``_paged_flash_kernel``), decode over int8
-pools (``_paged_q_flash_kernel``), chunk (``_paged_chunk_kernel``) and the
-writer (``_paged_write_kernel``).  Off the TPU every public entry is a
-dense gather reference (a scatter, for the writer) with identical
-semantics.
+Four kernel bodies: decode (``_paged_decode_kernel``: a block of pages at
+a time by its own DMAs), the decode of pools whose pages no DMA takes, int8
+pools among them (``_paged_page_kernel``: a page a grid step, scales
+optional), chunk (``_paged_chunk_kernel``) and the writer
+(``_paged_write_kernel``).  Off the TPU every public entry is a dense
+gather reference (a scatter, for the writer) with identical semantics.
 """
 
 from __future__ import annotations
@@ -48,26 +49,22 @@ _LANES = 128
 
 
 # ------------------------------------------------------------------ decode
-# The length-bounded sweep: a kernel whose grid visits EVERY page slot of
-# the table width for every row makes a 128-token row in a 2048-token table
-# pay 128 pages of DMA for 8 pages of data.  The decode kernels clamp the
-# sweep per row using the scalar-prefetched seq_lens INSIDE the BlockSpec
-# index map: grid steps past the row's last valid page re-present that last
-# page's block index, and Pallas's revisiting-block optimization elides the
-# HBM->VMEM copy for a repeated index — dead pages are never DMA'd.  The
-# kernel body masks those steps out (i*page_size >= seq_len) and finalizes
-# at the row's LAST VALID page instead of the last grid step, so the
-# trailing steps are pure no-ops.  The batch dimension leads the grid and is
-# declared "parallel" for megacore partitioning; the page sweep stays
-# "arbitrary" (sequential online-softmax accumulation).
+# The length-bounded sweep: a kernel that visits EVERY entry of the table
+# width for every row makes a 128-token row in a 2048-token table pay 128
+# pages of DMA for 8 pages of data.  The decode kernel walks a row's table
+# only as far as the row's length reaches (the scalar-prefetched seq_lens
+# bound its loop), a block of pages at a time, each page fetched through
+# the page table by a DMA of its own: dead table entries are never read,
+# and cost no grid step either -- a row is ONE grid step, whatever the
+# table's width.
 
 
 # ------------------------------------------------- tensor-parallel serving
 # ServingEngine(mesh=...) shards q and the page pools on the (KV-)head dim.
 # Off-TPU the dense-gather references below are plain jnp — GSPMD partitions
 # them from the operand shardings with no help.  The Pallas flash kernels
-# can't be GSPMD-partitioned (they bake num_kv_heads from the static shape
-# and unroll the head loop), so under an active scope the TPU entries wrap
+# can't be GSPMD-partitioned (they bake num_kv_heads from the static
+# shape), so under an active scope the TPU entries wrap
 # the kernel in shard_map with head-sharded specs: each shard's kernel
 # compiles against its LOCAL head count and sweeps only its own pool
 # shard's pages.  Per-head attention is embarrassingly parallel and the
@@ -127,12 +124,6 @@ def _flash_sharded(pallas_fn, q, pools, scales, page_table, seq_lens,
     return f(q, *pools, *scales, page_table, seq_lens)
 
 
-def _last_page(seq_len, page_size):
-    """Index of the last page a row's sweep must visit (>= 0, so empty
-    rows still have a step to finalize on — they write zeros)."""
-    return jnp.maximum((seq_len + page_size - 1) // page_size - 1, 0)
-
-
 def pool_lane_dim(head_dim):
     """Width of a row of the serving engine's payload pools: the head size,
     on the TPU rounded up to whole 128-lane rows (64 is stored as 128,
@@ -168,64 +159,188 @@ def _gather_pages(pool, table, layer):
     return pool[table] if layer is None else pool[layer, table]
 
 
-def _bounded_page_spec(pool, layer):
-    """BlockSpec of one page of layer ``layer`` of a stacked
-    ``[L, P, ps, ...]`` pool (the payload's ``[h, d]`` or the scale pools'
-    ``[h]`` behind): the layer is a block dimension of one that the kernel
-    does not see, at a block index fixed at trace time, so a layer is
-    read where it lies in the pool.  The index map clamps the sweep: steps
-    past the row's last valid page re-present that page so the revisited
-    block is not re-fetched."""
+def _decode_blocking(q, k_pages, NP):
+    """Block sizes of the decode sweep from the shapes the kernel is handed:
+    ``(pages, vmem_limit)`` -- the pages of one block of the sweep (as many
+    as make 128 keys, no more than the table holds, and halved while the
+    two buffers of K and V blocks pass 8 MiB), and the VMEM limit to ask
+    Mosaic for where wide pages need more than its 16 MiB default
+    (``None``: the default does).
+
+    ``None`` where the sweep cannot fetch a page of the pool by a DMA of
+    its own, which Mosaic takes only in whole tiles: rows that are not
+    whole lanes (a head size that is no multiple of 128), int8 pools
+    (their scale pools' rows are ``HKV`` lanes wide), and 16-bit pools
+    whose heads are not whole sublane tiles (2, 4 or a multiple of 8: not
+    an mp shard's 3, not 12).  Those keep the sweep of one page a grid
+    step (:func:`_paged_page_kernel`)."""
+    H, D = q.shape[-2:]
+    page_size, HKV = k_pages.shape[-3:-1]
+    kv_bytes = k_pages.dtype.itemsize
+    if D % _LANES or kv_bytes == 1 \
+            or (kv_bytes == 2 and HKV not in (2, 4) and HKV % 8):
+        return None
+    rows = 32 // kv_bytes                 # sublanes of one tile of the pool
+    page = page_size * -(-HKV // rows) * rows * D * kv_bytes
+    pages = max(1, min(-(-_LANES // page_size), NP))
+    while pages > 1 and 4 * pages * page > 8 << 20:
+        pages //= 2
+    # one page of K and of V widened to f32, its scores and probabilities;
+    # q, out and the carried state
+    staged = 4 * page_size * -(-HKV // 8) * 8 * D * 4
+    state = 5 * (H // HKV) * -(-HKV // 8) * 8 * D * 4
+    need = 4 * pages * page + staged + state + (4 << 20)
+    return pages, need if need > 16 << 20 else None
+
+
+def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sem, swept, *, layer, page_size,
+                         scale, pages, table_pages):
+    """Grid (slot b): a slot's whole sweep is one grid step.  ``k_hbm`` /
+    ``v_hbm`` are the pools as they lie in HBM, ``[L, P, ps, HKV, D]``;
+    the scratch: two buffers of ``pages`` pages for each, the DMA
+    semaphores ``[buffer, pool]``, and the count of blocks swept so far.
+
+    The sweep walks the slot's table a block of ``pages`` pages at a time,
+    as many blocks as the slot's length needs: each LIVE page is fetched
+    through the page table by a DMA of its own into one of the two buffers
+    while the block before it is attended, and the first block of the NEXT
+    slot is fetched during this slot's last, so the buffers alternate
+    across slots.  The batch dimension is sequential for that: on a chip
+    with two cores the slots no longer partition between them (a v5e has
+    one).  Entries past the slot's last page are not fetched at all: their
+    place in the buffer keeps what an earlier block left there (zeros at
+    first) and every key of it is masked, so dead table entries are never
+    read.
+
+    One query row a head, so the products are multiply-and-reduce on the
+    VPU over the page AS IT LIES, ``[ps, HKV, D]`` with the heads on the
+    sublanes: every kv head at once, no per-head tile, no transposition.
+    q comes in as ``[g, HKV, D]`` (query head ``kv * g + r`` is row
+    ``[r, kv]``), so a page streams once for all g grouped query heads.
+    K/V are read as stored and widened to f32 in VMEM; scores,
+    probabilities, statistics and the accumulator are f32, and so are both
+    products' operands.  The online-softmax update runs a page at a time,
+    so that a page's tensors stay near the register file; the carried
+    state is a few tiles."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    page_size = pool.shape[2]
+    b = pl.program_id(0)
+    g, HKV, D = q_ref.shape[1:]
 
-    def idx(b, i, pt, ln):
-        return (layer, pt[b, jnp.minimum(i, _last_page(ln[b], page_size))]) \
-            + (0,) * (pool.ndim - 2)
-    return pl.BlockSpec((None, 1) + pool.shape[2:], idx)
+    def sweep(slot):
+        """``(length, pages)`` of a slot's sweep: a length that overruns
+        the table sees the table's keys (callers mask with seq_lens), and
+        an empty slot sweeps one page with every key masked, so that each
+        grid step fetches and waits alike."""
+        seq_len = jnp.minimum(lens_ref[slot], table_pages * page_size)
+        return seq_len, jnp.maximum(
+            (seq_len + page_size - 1) // page_size, 1)
+
+    def fetch(slot, blk, buf, live, start):
+        """Start (or wait for) the DMAs of block ``blk`` of ``slot`` into
+        buffer ``buf``: one a pool for each of the block's pages below
+        ``live``, the slot's page count."""
+        first = blk * pages
+
+        def page(r, carry):
+            # to wait for a copy only its shape counts
+            at = pt_ref[slot, first + r] if start else 0
+            for a, (hbm, buffers) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf))):
+                copy = pltpu.make_async_copy(
+                    hbm.at[layer, at], buffers.at[buf, r], sem.at[buf, a])
+                copy.start() if start else copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(pages, live - first), page, 0)
+
+    @pl.when(b == 0)
+    def _first():
+        swept[0] = 0
+        # what no DMA overwrites is multiplied by a probability of zero:
+        # it has to be finite
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        fetch(0, 0, 0, sweep(0)[1], True)
+
+    seq_len, live = sweep(b)
+    blocks = (live + pages - 1) // pages
+    base = swept[0]
+    q = q_ref[0].astype(jnp.float32) * jnp.float32(scale)  # [g, HKV, D]
+
+    def block(blk, state):
+        buf = (base + blk) % 2
+
+        @pl.when(blk + 1 < blocks)
+        def _next_block():
+            fetch(b, blk + 1, 1 - buf, live, True)
+
+        @pl.when((blk + 1 == blocks) & (b + 1 < pl.num_programs(0)))
+        def _next_slot():
+            fetch(b + 1, 0, 1 - buf, sweep(b + 1)[1], True)
+
+        fetch(b, blk, buf, live, False)
+        m, l, acc = (list(x) for x in state)
+        for r in range(pages):
+            k = k_buf[buf, r].astype(jnp.float32)          # [ps, HKV, D]
+            v = v_buf[buf, r].astype(jnp.float32)
+            pos = (blk * pages + r) * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, (page_size, 1, 1), 0)
+            valid = pos < seq_len                          # [ps, 1, 1]
+            for j in range(g):
+                s = jnp.sum(k * q[j][None], axis=2, keepdims=True)
+                s = jnp.where(valid, s, jnp.float32(NEG_INF))  # [ps, HKV, 1]
+                m_new = jnp.maximum(m[j], s.max(axis=0))   # [HKV, 1]
+                # an empty slot has no valid key and m_new == NEG_INF,
+                # s - m_new == 0: its p must still be 0, so that it ends
+                # with l == 0 and writes zeros
+                p = jnp.where(valid, jnp.exp(s - m_new[None]),
+                              jnp.float32(0.0))
+                alpha = jnp.exp(m[j] - m_new)
+                l[j] = l[j] * alpha + p.sum(axis=0)
+                acc[j] = acc[j] * alpha + (p * v).sum(axis=0)  # [HKV, D]
+                m[j] = m_new
+        return tuple(m), tuple(l), tuple(acc)
+
+    m, l, acc = jax.lax.fori_loop(0, blocks, block, (
+        (jnp.full((HKV, 1), NEG_INF, jnp.float32),) * g,
+        (jnp.zeros((HKV, 1), jnp.float32),) * g,
+        (jnp.zeros((HKV, D), jnp.float32),) * g))
+    swept[0] = base + blocks
+    for j in range(g):
+        # output stays f32; the wrapper downcasts outside the kernel
+        o_ref[0, j] = acc[j] / jnp.maximum(l[j], jnp.float32(1e-30))
 
 
-def _accum_page(q_ref, valid, load_k, load_v, scale, num_kv_heads,
-                m_scr, l_scr, acc_scr):
-    """One page's online-softmax update, shared by the flash kernels.
+def _last_page(seq_len, page_size):
+    """Index of the last page a row's sweep must visit (>= 0, so empty
+    rows still have a step to finalize on — they write zeros)."""
+    return jnp.maximum((seq_len + page_size - 1) // page_size - 1, 0)
+
+
+def _paged_page_kernel(pt_ref, lens_ref, q_ref, *refs, page_size, scale,
+                       num_kv_heads, quantized):
+    """Grid (slot b, table entry i): the sweep of ONE page a grid step, for
+    the pools of which the decode kernel's DMAs take no page
+    (:func:`_decode_blocking`).  ``refs``: the K and V page, (int8 pools:
+    their ``[ps, HKV]`` scale tiles, the dequantization fused into the
+    loads), the output block, m / l / acc.
 
     Mosaic discipline (mirrors ops/flash_attention.py): strictly 2-D tiles,
     keepdims reductions, f32 constants, plain-contracting dot_generals
-    only.  KV heads run as a STATIC
-    unrolled loop; ``load_k(j)``/``load_v(j)`` return the page's f32
-    [page, D] tile for kv head j (the int8 kernel fuses dequant there),
-    streamed ONCE and serving all g grouped query heads."""
-    num_q = q_ref.shape[1]
-    g = num_q // num_kv_heads
-    for j in range(num_kv_heads):
-        r = slice(j * g, (j + 1) * g)
-        q = q_ref[0, r, :].astype(jnp.float32)             # [g, D]
-        k = load_k(j)                                      # [page, D]
-        v = load_v(j)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * jnp.float32(scale)
-        s = jnp.where(valid, s, jnp.float32(NEG_INF))      # [g, page]
-        m_prev = m_scr[r, :]                               # [g, 1]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                             # [g, page]
-        alpha = jnp.exp(m_prev - m_new)                    # [g, 1]
-        l_scr[r, :] = l_scr[r, :] * alpha + p.sum(axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [g, D]
-        acc_scr[r, :] = acc_scr[r, :] * alpha + pv
-        m_scr[r, :] = m_new
-
-
-def _paged_flash_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                        m_scr, l_scr, acc_scr, *, page_size, scale,
-                        num_kv_heads):
+    only.  KV heads run as a STATIC unrolled loop over the page's f32
+    ``[page, D]`` tiles, each streamed ONCE and serving all g grouped query
+    heads."""
     from jax.experimental import pallas as pl
 
+    k_ref, v_ref = refs[:2]
+    ks_ref, vs_ref = refs[2:4] if quantized else (None, None)
+    o_ref, m_scr, l_scr, acc_scr = refs[-4:]
     b = pl.program_id(0)
     i = pl.program_id(1)
+    g = q_ref.shape[1] // num_kv_heads
 
     @pl.when(i == 0)
     def _init():
@@ -241,56 +356,125 @@ def _paged_flash_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     last = jnp.minimum(_last_page(seq_len, page_size),
                        pl.num_programs(1) - 1)
 
+    def load(ref, scale_ref, j):
+        x = ref[0, :, j, :].astype(jnp.float32)            # [page, D]
+        return x * scale_ref[0, :, j:j + 1] if quantized else x
+
     @pl.when(i * page_size < seq_len)
     def _compute():
         pos = i * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
         valid = pos < seq_len                              # [1, page]
-        _accum_page(q_ref, valid,
-                    lambda j: k_ref[0, :, j, :].astype(jnp.float32),
-                    lambda j: v_ref[0, :, j, :].astype(jnp.float32),
-                    scale, num_kv_heads, m_scr, l_scr, acc_scr)
+        for j in range(num_kv_heads):
+            r = slice(j * g, (j + 1) * g)
+            q = q_ref[0, r, :].astype(jnp.float32)         # [g, D]
+            k = load(k_ref, ks_ref, j)
+            v = load(v_ref, vs_ref, j)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * jnp.float32(scale)
+            s = jnp.where(valid, s, jnp.float32(NEG_INF))  # [g, page]
+            m_prev = m_scr[r, :]                           # [g, 1]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)                         # [g, page]
+            alpha = jnp.exp(m_prev - m_new)                # [g, 1]
+            l_scr[r, :] = l_scr[r, :] * alpha + p.sum(axis=1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)        # [g, D]
+            acc_scr[r, :] = acc_scr[r, :] * alpha + pv
+            m_scr[r, :] = m_new
 
     @pl.when(i == last)
     def _fin():
         # empty rows (seq_len == 0) run _init then _fin at step 0 (when
         # blocks execute in definition order) and write zeros.  Output
-        # stays f32; the public entry downcasts outside the kernel.
+        # stays f32; the wrapper downcasts outside the kernel.
         o_ref[0] = acc_scr[...] / jnp.maximum(l_scr[...], jnp.float32(1e-30))
 
 
-def _paged_flash_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
-                        interpret, layer):
-    """q [B, H, D] against layer ``layer`` of the stacked pools
-    [L, P, ps, HKV, D]."""
+def _paged_decode_pallas(q, pools, scales, page_table, seq_lens, scale,
+                         interpret, layer, name=None):
+    """q [B, H, D] against layer ``layer`` of the stacked ``pools`` (K, V:
+    [L, P, ps, HKV, D]) and, for int8 pools, ``scales`` (K, V:
+    [L, P, ps, HKV]) -> [B, H, D].  The layer is an index fixed at trace
+    time, so a layer is read where it lies in the pool: the decode kernel
+    fetches its pages from HBM itself; pools whose pages it cannot fetch
+    (:func:`_decode_blocking`) are swept a page a grid step, the page a
+    block whose leading dimension of one, the layer, the kernel does not
+    see."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
-    page_size, HKV = k_pages.shape[2:4]
+    page_size, HKV = pools[0].shape[2:4]
     NP = page_table.shape[1]
+    blocking = _decode_blocking(q, pools[0], NP)
+    if blocking is None:
+        def page_spec(pool):
+            # the index map clamps the sweep: steps past the row's last
+            # valid page re-present it, and a revisited block is not
+            # fetched again
+            def idx(b, i, pt, ln):
+                return (layer, pt[b, jnp.minimum(
+                    i, _last_page(ln[b], page_size))]) + (0,) * (pool.ndim - 2)
+            return pl.BlockSpec((None, 1) + pool.shape[2:], idx)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, NP),
-        in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0)),
-            _bounded_page_spec(k_pages, layer),
-            _bounded_page_spec(v_pages, layer),
-        ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, D), jnp.float32),
-        ],
-    )
+        paged = (*pools, *(a.astype(jnp.float32) for a in scales))
+        operand, grid = q, (B, NP)
+        q_spec = pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0))
+        in_specs = [q_spec] + [page_spec(a) for a in paged]
+        scratch = [pltpu.VMEM((H, 1), jnp.float32),
+                   pltpu.VMEM((H, 1), jnp.float32),
+                   pltpu.VMEM((H, D), jnp.float32)]
+        kernel = functools.partial(
+            _paged_page_kernel, page_size=page_size, scale=scale,
+            num_kv_heads=HKV, quantized=bool(scales))
+        # batch rows are independent; the page sweep carries the
+        # online-softmax state and stays sequential
+        semantics, vmem_limit = ("parallel", "arbitrary"), None
+    else:
+        pages, vmem_limit = blocking
+        g = H // HKV
+        # a few KB around the kernel: query head kv * g + r to row [r, kv]
+        operand = jnp.swapaxes(q.reshape(B, HKV, g, D), 1, 2)
+        paged, grid = pools, (B,)
+        q_spec = pl.BlockSpec((1, g, HKV, D), lambda b, pt, ln: (b, 0, 0, 0))
+        in_specs = [q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        scratch = [pltpu.VMEM((2, pages) + a.shape[2:], a.dtype)
+                   for a in pools] \
+            + [pltpu.SemaphoreType.DMA((2, 2)), pltpu.SMEM((1,), jnp.int32)]
+        kernel = functools.partial(
+            _paged_decode_kernel, layer=layer, page_size=page_size,
+            scale=scale, pages=pages, table_pages=NP)
+        # sequential: a slot's last block fetches the next slot's first
+        semantics = ("arbitrary",)
     # x64 OFF around the call: the framework enables jax_enable_x64 globally
     # (paddle int64 tensor parity), and under it the literal 0s of the
     # BlockSpec index maps trace as i64 constants, which Mosaic fails to
     # legalize (checked against libtpu 0.0.34: "failed to legalize operation
     # 'func.func'" on the index-map transform).  Every dtype in the kernel is
     # pinned, so x32 promotion rules change nothing numerically.
+    with jax.enable_x64(False):
+        out = pl.pallas_call(
+            kernel,
+            name=name,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+                out_specs=q_spec, scratch_shapes=scratch),
+            out_shape=jax.ShapeDtypeStruct(operand.shape, jnp.float32),
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=semantics, vmem_limit_bytes=vmem_limit),
+        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), operand,
+          *paged)
+    if blocking is not None:
+        out = jnp.swapaxes(out, 1, 2).reshape(B, H, D)
+    return out.astype(q.dtype)
+
+
+def _paged_flash_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
+                        interpret, layer):
     # This call alone carries no ``name="paged_decode"``: a name is the
     # innermost scope of the kernel's name stack and so becomes its HLO
     # instruction name, and the benchmark's ``paged_decode_roofline`` finds
@@ -298,20 +482,15 @@ def _paged_flash_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
     # says what has to be repointed first).  ``paged_chunk_attend`` does
     # not come through here: it has a kernel of its own
     # (``_paged_chunk_pallas``) under the scope ``chunk_attention``.
-    with jax.enable_x64(False):
-        out = pl.pallas_call(
-            functools.partial(_paged_flash_kernel, page_size=page_size,
-                              scale=scale, num_kv_heads=HKV),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-            interpret=interpret,
-            # batch rows are independent (megacore-partitionable); the page
-            # sweep carries the online-softmax state and stays sequential
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-          q, k_pages, v_pages)
-    return out.astype(q.dtype)
+    return _paged_decode_pallas(q, (k_pages, v_pages), (), page_table,
+                                seq_lens, scale, interpret, layer)
+
+
+def _paged_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
+                          page_table, seq_lens, scale, interpret, layer):
+    return _paged_decode_pallas(q, (k_pages, v_pages), (k_scales, v_scales),
+                                page_table, seq_lens, scale, interpret,
+                                layer, name="paged_decode_q")
 
 
 def _gathered_attend(q, k, v, seq_lens, scale):
@@ -464,11 +643,9 @@ def _pad_to_pages(kv, page_size):
 
 # ---------------------------------------------------------- chunk attention
 # C query positions per slot (a prefill chunk, a verify chunk) against the
-# slot's pages.  The decode kernels above take ONE query row per batch row,
-# so serving a chunk through them means a [B*C]-row batch that walks the
-# same page table C times: at C=256 over a 64-page table that was 16,384
-# grid steps, each fetching a 16-token page for a one-row product.  The
-# chunk kernel keeps the slot's whole query block resident instead — its
+# slot's pages.  The decode kernel above takes ONE query row per batch row,
+# so serving a chunk through it means a [B*C]-row batch that walks the
+# same page table C times.  The chunk kernel keeps the slot's whole query block resident instead — its
 # block index is constant over the page sweep, so it is fetched once — and
 # every K/V page comes into VMEM once per (slot, query tile) and meets all
 # of the tile's positions there.
@@ -481,7 +658,8 @@ def _pad_to_pages(kv, page_size):
 # table), so the two products of a step have a full contraction tile.
 # Position t of slot b sees keys 0 .. lens[b]+t: one mask per step replaces
 # the per-row seq_len, and the sweep stops at the page of the tile's LAST
-# position (same re-present-the-last-page clamp as _bounded_page_spec).
+# position: grid steps past it re-present that page's block index, and
+# Pallas does not fetch a block whose index repeats.
 
 
 def _chunk_blocking(q, k_pages, NP):
@@ -527,10 +705,11 @@ def _paged_chunk_kernel(pt_ref, lens_ref, q_ref, *refs, page_size, scale,
     """Grid (slot b, query tile j, page step i).  ``refs``: ``pages`` K
     page refs, as many V, (int8 pools: as many K-scale and V-scale refs),
     the output block, then the scratch: the step's K and V staged
-    head-major in f32, and m / l / acc.  Same arithmetic as
-    :func:`_accum_page`: K/V read as stored and widened to f32 in VMEM
-    (dequant fused there), both products and the probabilities in f32,
-    2-D tiles inside the head loop, keepdims reductions, f32 constants."""
+    head-major in f32, and m / l / acc.  K/V are read as stored and
+    widened to f32 in VMEM (dequant fused there); both products take f32
+    operands on the MXU, which rounds them to bf16 (exact for the stored
+    K, V and a bf16 q; the probabilities lose their low bits); 2-D tiles
+    inside the head loop, keepdims reductions, f32 constants."""
     from jax.experimental import pallas as pl
 
     k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
@@ -618,8 +797,9 @@ def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
                         layer, name=None):
     """q [B, C, H, D] against layer ``layer`` of the stacked ``pools`` (K,
     V: [L, P, ps, HKV, D]) and, for int8 pools, ``scales`` (K, V:
-    [L, P, ps, HKV]) -> [B, C, H, D].  As in :func:`_bounded_page_spec`
-    the layer is a squeezed block dimension at a fixed index."""
+    [L, P, ps, HKV]) -> [B, C, H, D].  The layer is a block dimension of
+    one that the kernel does not see, at a block index fixed at trace
+    time, so a layer is read where it lies in the pool."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -656,7 +836,7 @@ def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
             pltpu.VMEM((H, D, tile), jnp.float32),
         ],
     )
-    # x64 OFF for the same Mosaic i64-index reason as _paged_flash_pallas
+    # x64 OFF for the same Mosaic i64-index reason as _paged_decode_pallas
     with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(
@@ -770,92 +950,6 @@ def quantize_kv(kv, bits=8):
 
     q, scale = quantize_absmax(kv, axis=-1, bits=bits)
     return q, jnp.squeeze(scale, -1)
-
-
-def _paged_q_flash_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref,
-                          vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                          page_size, scale, num_kv_heads):
-    """The dequant-fused twin of :func:`_paged_flash_kernel`: int8 page
-    tiles stream HBM->VMEM at half the bf16 bytes, and the per-(slot, head)
-    scale column multiplies them back to f32 IN VMEM right after the
-    convert — the full-precision page never exists outside the register
-    file.  Same sweep clamp, same dead-page elision."""
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, jnp.float32(NEG_INF))
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    seq_len = lens_ref[b]
-    last = jnp.minimum(_last_page(seq_len, page_size),
-                       pl.num_programs(1) - 1)
-
-    @pl.when(i * page_size < seq_len)
-    def _compute():
-        pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        valid = pos < seq_len                              # [1, page]
-        _accum_page(
-            q_ref, valid,
-            lambda j: (k_ref[0, :, j, :].astype(jnp.float32)
-                       * ks_ref[0, :, j:j + 1]),
-            lambda j: (v_ref[0, :, j, :].astype(jnp.float32)
-                       * vs_ref[0, :, j:j + 1]),
-            scale, num_kv_heads, m_scr, l_scr, acc_scr)
-
-    @pl.when(i == last)
-    def _fin():
-        o_ref[0] = acc_scr[...] / jnp.maximum(l_scr[...], jnp.float32(1e-30))
-
-
-def _paged_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
-                          page_table, seq_lens, scale, interpret, layer):
-    """q [B, H, D] against layer ``layer`` of the stacked int8 pools
-    [L, P, ps, HKV, D] and their scale pools [L, P, ps, HKV]."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, H, D = q.shape
-    HKV = k_pages.shape[3]
-    NP = page_table.shape[1]
-    k_scales = k_scales.astype(jnp.float32)
-    v_scales = v_scales.astype(jnp.float32)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, NP),
-        in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0)),
-            *(_bounded_page_spec(a, layer)
-              for a in (k_pages, v_pages, k_scales, v_scales)),
-        ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, D), jnp.float32),
-        ],
-    )
-    # x64 OFF for the same Mosaic i64-index reason as _paged_flash_pallas
-    with jax.enable_x64(False):
-        out = pl.pallas_call(
-            functools.partial(_paged_q_flash_kernel,
-                              page_size=k_pages.shape[2], scale=scale,
-                              num_kv_heads=HKV),
-            name="paged_decode_q",
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-            interpret=interpret,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-          q, k_pages, v_pages, k_scales, v_scales)
-    return out.astype(q.dtype)
 
 
 def _gather_dequant(pages, scales, table, layer, D):
@@ -993,7 +1087,7 @@ def _paged_write_pallas(pools, rows, table, lens, interpret, layer):
         + [page_spec(pool) for pool in pools],
         out_specs=[page_spec(pool) for pool in pools],
     )
-    # x64 OFF for the same Mosaic i64-index reason as _paged_flash_pallas.  The
+    # x64 OFF for the same Mosaic i64-index reason as _paged_decode_pallas.  The
     # name keeps this call out of ``paged_decode_roofline``, which sums the
     # decode program's kernels that carry none.
     with jax.enable_x64(False):

@@ -33,16 +33,17 @@ MAXLEN = 64
 
 
 # ============================================================ kernel side
-def _mk_paged(B=3, H=4, HKV=2, D=16, ps=8, NP=5, lens=(5, 17, 31), seed=0):
+def _mk_paged(B=3, H=4, HKV=2, D=16, ps=8, NP=5, lens=(5, 17, 31), seed=0,
+              dtype="float32"):
     """Random q + pools + a SHUFFLED page table (the bounded index map
     must chase real indirection, not an identity layout)."""
     import jax.numpy as jnp
 
     rs = np.random.RandomState(seed)
     P = B * NP + 1                       # +1 unreferenced page
-    q = jnp.asarray(rs.randn(B, H, D), jnp.float32)
-    k = jnp.asarray(rs.randn(P, ps, HKV, D), jnp.float32)
-    v = jnp.asarray(rs.randn(P, ps, HKV, D), jnp.float32)
+    q = jnp.asarray(rs.randn(B, H, D), dtype)
+    k = jnp.asarray(rs.randn(P, ps, HKV, D), dtype)
+    v = jnp.asarray(rs.randn(P, ps, HKV, D), dtype)
     perm = rs.permutation(B * NP).reshape(B, NP).astype(np.int32)
     table = jnp.asarray(perm)
     seq_lens = jnp.asarray(np.asarray(lens, np.int32))
@@ -57,52 +58,107 @@ def _quantize_pools(k, v):
     return kq, vq, ks, vs
 
 
-@pytest.mark.parametrize("lens", [(5, 17, 31), (8, 16, 39), (1, 1, 1),
-                                  (3, 40, 25), (40, 40, 40)])
-def test_flash_parity_ragged_lens(lens):
+#: The decode kernel takes pools whose rows are whole lanes (heads of 128:
+#: the served pools') a block of pages at a time (128 keys: 16 pages of 8,
+#: 8 of 16, 4 of 32); every other pool is swept a page a grid step.  One
+#: choice from the shapes, the same interpreted and compiled, so each case
+#: runs the kernel the chip would.
+KERNELS = pytest.mark.parametrize(
+    "D", [pytest.param(16, id="page_kernel"),
+          pytest.param(128, id="decode_kernel")])
+
+#: the decode kernel's edges: tables narrower than a block, not a multiple
+#: of one, lengths on a block's edge and one key past it, a length that
+#: overruns the table, three heads (f32: the decode kernel; bf16: three
+#: heads are no whole tile, so the page kernel attends)
+BLOCK_EDGES = {
+    "page8_two_blocks": dict(ps=8, NP=20, lens=(128, 129, 160)),
+    "page8_overrun": dict(ps=8, NP=20, lens=(127, 161, 400)),
+    "page16_two_blocks": dict(ps=16, NP=9, lens=(128, 129, 144)),
+    "page16_three_blocks": dict(ps=16, NP=17, lens=(256, 257, 1)),
+    "page32_two_blocks": dict(ps=32, NP=5, lens=(128, 129, 160)),
+    "page32_overrun": dict(ps=32, NP=5, lens=(33, 160, 999)),
+    "narrow_table": dict(ps=16, NP=5, lens=(5, 17, 80)),
+    "one_page_table": dict(ps=8, NP=1, lens=(1, 8, 5)),
+    "three_heads": dict(ps=16, NP=9, lens=(130, 5, 144), H=3, HKV=3),
+    "three_heads_bf16": dict(ps=16, NP=9, lens=(130, 5, 144), H=3, HKV=3,
+                             dtype="bfloat16"),
+    "gqa_bf16": dict(ps=16, NP=9, lens=(130, 5, 144), H=8, HKV=4,
+                     dtype="bfloat16"),
+}
+BLOCK_EDGES = {name: dict(kw, D=128, H=kw.get("H", 2), HKV=kw.get("HKV", 2))
+               for name, kw in BLOCK_EDGES.items()}
+
+
+def _takes_decode_kernel(q, k, table):
+    from paddle_tpu.ops.paged_attention import _decode_blocking
+
+    return _decode_blocking(q, k, table.shape[1]) is not None
+
+
+@pytest.mark.parametrize("shape", [
+    *(dict(lens=lens, D=D) for D in (16, 128)
+      for lens in ((5, 17, 31), (8, 16, 39), (1, 1, 1), (3, 40, 25),
+                   (40, 40, 40))),
+    *(pytest.param(kw, id=name) for name, kw in BLOCK_EDGES.items())])
+def test_flash_parity_ragged_lens(shape):
     """Interpret-mode flash kernel vs the dense reference on ragged
-    lengths (page-aligned, single-token, and full-table rows)."""
+    lengths (page-aligned, single-token, and full-table rows) and at the
+    edges of the sweep's blocks."""
     from paddle_tpu.ops.paged_attention import (_paged_flash_pallas,
                                                 paged_attention_ref)
 
-    q, k, v, table, seq_lens = _mk_paged(lens=lens)
-    ref = paged_attention_ref(q, k, v, table, seq_lens, scale=0.25)
+    q, k, v, table, seq_lens = _mk_paged(**shape)
+    assert _takes_decode_kernel(q, k, table) == (
+        shape["D"] == 128 and not (q.dtype == "bfloat16"
+                                   and k.shape[-2] == 3))
+    f32 = [x.astype("float32") for x in (q, k, v)]
+    ref = paged_attention_ref(*f32, table, seq_lens, scale=0.25)
     out = _paged_flash_pallas(q, k[None], v[None], table, seq_lens, 0.25, True,
-                            0)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+                              0)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref),
+        atol=2e-5 if q.dtype == "float32" else 2e-2)
 
 
-def test_flash_parity_uses_default_scale():
+@KERNELS
+def test_flash_parity_uses_default_scale(D):
     from paddle_tpu.ops.paged_attention import (paged_attention,
                                                 paged_attention_ref)
 
-    q, k, v, table, seq_lens = _mk_paged(lens=(7, 23, 33), seed=3)
+    q, k, v, table, seq_lens = _mk_paged(lens=(7, 23, 33), seed=3, D=D)
     ref = paged_attention_ref(q, k, v, table, seq_lens)
     out = paged_attention(q, k, v, table, seq_lens, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+@KERNELS
 @pytest.mark.parametrize("hkv", [1, 2, 4, 8])
-def test_flash_gqa_group_sizes(hkv):
+def test_flash_gqa_group_sizes(hkv, D):
     """GQA grouping inside the bounded kernel: H=8 query heads over
     HKV in {1, 2, 4, 8} (g = 8, 4, 2, 1) match the grouped reference."""
     from paddle_tpu.ops.paged_attention import (paged_attention,
                                                 paged_attention_ref)
 
     q, k, v, table, seq_lens = _mk_paged(H=8, HKV=hkv, lens=(6, 19, 38),
-                                         seed=hkv)
+                                         seed=hkv, D=D)
     ref = paged_attention_ref(q, k, v, table, seq_lens)
     out = paged_attention(q, k, v, table, seq_lens, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-def test_flash_int8_parity():
-    """The dequant-fused int8 flash kernel matches the quantized dense
+@pytest.mark.parametrize("shape", [
+    dict(lens=(5, 17, 31)),
+    *(pytest.param(BLOCK_EDGES[name], id=name) for name in (
+        "page8_overrun", "page16_two_blocks", "page32_two_blocks",
+        "narrow_table", "three_heads"))])
+def test_flash_int8_parity(shape):
+    """The dequant-fused int8 decode matches the quantized dense
     reference (same pools, same scales, same masking)."""
     from paddle_tpu.ops.paged_attention import (
         paged_attention_quantized, paged_attention_quantized_ref)
 
-    q, k, v, table, seq_lens = _mk_paged(lens=(5, 17, 31), seed=7)
+    q, k, v, table, seq_lens = _mk_paged(seed=7, **shape)
     kq, vq, ks, vs = _quantize_pools(k, v)
     ref = paged_attention_quantized_ref(q, kq, vq, ks, vs, table, seq_lens)
     out = paged_attention_quantized(q, kq, vq, ks, vs, table, seq_lens,
@@ -110,37 +166,53 @@ def test_flash_int8_parity():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-def test_flash_empty_rows_match_legacy_kernel():
+@pytest.mark.parametrize("shape", [
+    dict(lens=(0, 7, 40)),
+    pytest.param(dict(lens=(0, 7, 40), D=128), id="decode_kernel"),
+    pytest.param(dict(ps=16, NP=9, lens=(0, 129, 0), D=128),
+                 id="between_rows"),
+    pytest.param(dict(ps=16, NP=9, lens=(0, 129, 7), H=3, HKV=3, D=128,
+                      dtype="bfloat16"), id="three_heads_bf16")])
+def test_flash_empty_rows_match_legacy_kernel(shape):
     """lens=0 rows: the dense reference's all-masked softmax is UNIFORM
     (mean of V — a pre-existing ref semantic), while the kernel emits the
-    zeros an empty row must produce (it initialises, accumulates nothing
-    and finalises at step 0), bit-for-bit; the reference everywhere
-    else."""
+    zeros an empty row must produce (it sweeps one page with every key
+    masked), bit-for-bit; the reference everywhere else."""
     from paddle_tpu.ops.paged_attention import (_paged_flash_pallas,
                                                 paged_attention_ref)
 
-    q, k, v, table, seq_lens = _mk_paged(lens=(0, 7, 40), seed=11)
+    q, k, v, table, seq_lens = _mk_paged(seed=11, **shape)
+    tol = 2e-5 if q.dtype == "float32" else 2e-2
     flash = np.asarray(
         _paged_flash_pallas(q, k[None], v[None], table, seq_lens, 0.25, True,
                             0))
+    flash = flash.astype(np.float32)
     np.testing.assert_array_equal(flash[0], np.zeros_like(flash[0]))
-    ref = np.asarray(paged_attention_ref(q, k, v, table, seq_lens,
-                                         scale=0.25))
-    np.testing.assert_allclose(flash[1:], ref[1:], atol=2e-5)
+    ref = np.asarray(paged_attention_ref(
+        *(x.astype("float32") for x in (q, k, v)), table, seq_lens,
+        scale=0.25))
+    live = np.asarray(seq_lens) > 0
+    np.testing.assert_allclose(flash[live], ref[live], atol=tol)
+    np.testing.assert_array_equal(flash[~live], 0.0)
 
 
-def test_flash_dead_pages_never_read():
+@pytest.mark.parametrize("shape", [
+    dict(lens=(5, 17, 31)),
+    pytest.param(dict(lens=(5, 17, 31), D=128), id="decode_kernel"),
+    pytest.param(dict(ps=16, NP=17, lens=(129, 17, 256), D=128),
+                 id="three_blocks")])
+def test_flash_dead_pages_never_read(shape):
     """THE flash guarantee: poison every page slot past each row's valid
     length with +/-1e6 garbage — output must not move by one bit (the
-    bounded sweep remaps out-of-range steps to the row's last valid page
-    and masks them; a kernel that still read dead pages would overflow
-    the online softmax)."""
+    bounded sweep never fetches entries past the row's last valid page
+    -- a page a step, it re-presents that page -- and masks them; a kernel
+    that still read dead pages would overflow the online softmax)."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops.paged_attention import _paged_flash_pallas
 
-    lens = (5, 17, 31)
-    q, k, v, table, seq_lens = _mk_paged(lens=lens, seed=13)
+    lens = shape["lens"]
+    q, k, v, table, seq_lens = _mk_paged(seed=13, **shape)
     clean = np.asarray(
         _paged_flash_pallas(q, k[None], v[None], table, seq_lens, 0.25, True,
                             0))
@@ -191,6 +263,43 @@ def test_flash_parity_sweep():
                                          seq_lens, interpret=True)
         np.testing.assert_allclose(np.asarray(qout), np.asarray(qref),
                                    atol=3e-5)
+
+
+@pytest.mark.parametrize("H,HKV,D,pool,ps,np_,want", [
+    # the batch_closed cell: 8 pages of 16 keys, 2 MiB of buffers
+    (16, 16, 128, "bfloat16", 16, 64, (8, None)),
+    # an mp shard's 4 heads; pages of 8 and of 32 keys
+    (4, 4, 128, "bfloat16", 16, 64, (8, None)),
+    (16, 16, 128, "bfloat16", 8, 64, (16, None)),
+    (16, 16, 128, "bfloat16", 32, 64, (4, None)),
+    # a table narrower than a block
+    (8, 4, 128, "float32", 16, 3, (3, None)),
+    # wide pages: the block is halved until two buffers a pool fit 8 MiB,
+    # and a page widened to f32 beside them wants more VMEM than the default
+    (64, 64, 256, "float32", 32, 64, (1, 20 << 20)),
+    # no DMA takes these a page at a time: rows that are not whole lanes
+    # (interpreted too: one choice in both modes), int8 pools (16-lane
+    # scale rows), 16-bit heads that are no whole tile
+    (16, 16, 64, "bfloat16", 16, 64, None),
+    (8, 4, 16, "float32", 8, 5, None),
+    (16, 16, 128, "int8", 16, 64, None),
+    (3, 3, 128, "bfloat16", 16, 64, None),
+    (12, 12, 128, "bfloat16", 16, 64, None),
+    (12, 12, 128, "float32", 16, 64, (8, None)),
+])
+def test_decode_blocking_follows_the_shapes(H, HKV, D, pool, ps, np_, want):
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.paged_attention import _decode_blocking
+
+    got = _decode_blocking(
+        jax.ShapeDtypeStruct((16, H, D), jnp.bfloat16),
+        jax.ShapeDtypeStruct((2, 9, ps, HKV, D), jnp.dtype(pool)), np_)
+    if want is None or want[1] is None:
+        assert got == want
+    else:
+        assert got[0] == want[0] and want[1] <= got[1] <= 2 * want[1]
 
 
 def test_gathered_chunk_attend_matches_rowwise():

@@ -50,8 +50,9 @@ def test_ref_matches_dense_oracle():
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
-def test_pallas_kernel_matches_ref_interpret():
-    q, kp, vp, table, lens = _setup()
+@pytest.mark.parametrize("page,np_pages", [(8, 4), (16, 9), (32, 5)])
+def test_pallas_kernel_matches_ref_interpret(page, np_pages):
+    q, kp, vp, table, lens = _setup(page=page, np_pages=np_pages)
     # the length-bounded kernel takes stacked pools and a layer: layer 1
     # of two, the other poisoned
     kp2, vp2 = (jnp.stack([jnp.full_like(p, 9.0), p]) for p in (kp, vp))

@@ -57,6 +57,37 @@ def test_chunk_kernel_compiles_for_v5e(one_chip, name, B, C, H, HKV, D,
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("name,B,H,HKV,D,pool,calls", [
+    # the cell: 16 heads of 64 in 128 lanes, one grid step a slot
+    ("batch_closed_decode", 16, 16, 16, 128, jnp.bfloat16, "decode"),
+    ("mp_shard_4_heads", 16, 4, 4, 128, jnp.bfloat16, "decode"),
+    ("generate_f32_gqa", 2, 8, 4, 128, jnp.float32, "decode"),
+    # what no DMA takes a page at a time is swept a page a grid step
+    ("mp_shard_3_heads", 16, 3, 3, 128, jnp.bfloat16, "page"),
+    ("gpt_base_12_heads", 16, 12, 12, 128, jnp.bfloat16, "page"),
+    ("rows_of_64_lanes", 16, 16, 16, 64, jnp.bfloat16, "page"),
+    ("gqa_head128_int8", 8, 16, 4, 128, jnp.int8, "page"),
+])
+def test_decode_kernel_compiles_for_v5e(one_chip, name, B, H, HKV, D, pool,
+                                        calls):
+    ps, NP, P = 16, 64, 1025
+    quantized = pool == jnp.int8
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pools = sds((2, P, ps, HKV, D), pool)
+    scales = (sds((2, P, ps, HKV), jnp.float32),) * 2 if quantized else ()
+    fn = pa._paged_q_flash_pallas if quantized else pa._paged_flash_pallas
+    q = sds((B, H, D), jnp.float32 if pool == jnp.float32 else jnp.bfloat16)
+    assert (pa._decode_blocking(q, pools, NP) is None) == (calls == "page")
+    compiled = jax.jit(
+        lambda *a: fn(*a, 0.125, False, 1)).lower(
+        q, pools, pools, *scales, sds((B, NP), jnp.int32),
+        sds((B,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
 # ------------------------------------------------ the pool, served in place
 def _served_program(monkeypatch, one_chip, closure, layers, pages, kv_dtype):
     """``GPTAdapter.<closure>`` of a ``layers``-deep model at gpt2-medium's

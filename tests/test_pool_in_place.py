@@ -276,8 +276,8 @@ def test_one_page_under_two_table_entries_keeps_both_writes():
 
 
 # --------------------------------------------- kernels read layer l in place
-def _stack(rs, quantized):
-    shape = (L, PAGES, PS, HEADS, HD)
+def _stack(rs, quantized, hd=HD):
+    shape = (L, PAGES, PS, HEADS, hd)
     if quantized:
         pools = tuple(jnp.asarray(rs.randint(-127, 128, shape), jnp.int8)
                       for _ in range(2))
@@ -290,16 +290,21 @@ def _stack(rs, quantized):
     return pools, scales
 
 
+# rows of whole lanes take the decode kernel's own DMAs (f32 pools), every
+# other pool the sweep of a page a grid step
+@pytest.mark.parametrize("hd", [HD, 128])
 @pytest.mark.parametrize("quantized", [False, True])
 @pytest.mark.parametrize("layer", [0, 2])
 def test_decode_kernel_reads_layer_l_as_the_reference_reads_pool_l(
-        layer, quantized):
+        layer, quantized, hd):
     rs = np.random.RandomState(6)
-    pools, scales = _stack(rs, quantized)
-    q = jnp.asarray(rs.randn(3, HEADS, HD), jnp.float32)
+    pools, scales = _stack(rs, quantized, hd)
+    q = jnp.asarray(rs.randn(3, HEADS, hd), jnp.float32)
     table = _table(rs, 3)
     lens = jnp.asarray([1, 17, 32], jnp.int32)
-    scale = 1.0 / math.sqrt(HD)
+    scale = 1.0 / math.sqrt(hd)
+    assert (pa._decode_blocking(q, pools[0], NP) is not None) == (
+        hd == 128 and not quantized)
     if quantized:
         got = pa._paged_q_flash_pallas(q, *pools, *scales, table, lens, scale,
                                        True, layer)
