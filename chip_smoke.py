@@ -18,6 +18,12 @@ One process, the public entry points, full width, random weights from a seed:
   requests through ``submit()/result()`` and ``stream()``, with the lowered
   decode, prefill-chunk and prefill programs checked for their Mosaic calls
   per layer and their compiled text for any instruction over a whole pool;
+- serve_lfm2: a small hybrid of the LFM2-MoE family (hidden 2048, 32 query
+  heads in groups of 4 over 8 KV heads of 64, 10 layers, 16 experts top-4)
+  through the same engine with per-slot convolution state beside its pages,
+  its tokens held to the model's own dense forward, and the decode, chunk and
+  write kernels at those heads over pools 128 lanes wide and tables 256
+  pages wide (4,096 positions);
 - multichip: with >= 4 chips, data-parallel ResNet-50, the all-reduce probe,
   ring attention and tensor-parallel serving, each with its arrays checked
   to sit on four distinct devices.  On fewer chips: ``skipped: N device``.
@@ -82,6 +88,24 @@ FULL = {
               "stream": (12, 16)},
     "multichip": {"chips": 4, "ring": (1, 2048, 4, 64),
                   "allreduce_mb": 64},
+    # a small hybrid at LFM2-24B-A2B's hidden size and heads (32 query
+    # heads in groups of 4 over 8 KV heads of 64, stored 128 lanes wide),
+    # served at 4,096 positions: tables 256 pages wide
+    "lfm2": {"model": {"hidden_size": 2048, "num_attention_heads": 32,
+                       "num_key_value_heads": 8, "intermediate_size": 4096,
+                       "moe_intermediate_size": 512, "num_experts": 16,
+                       "num_experts_per_tok": 4, "num_hidden_layers": 10,
+                       "vocab_size": 8192, "dtype": "bfloat16"},
+             # 8 slots: pools of 134 MB, past what the compiler stages whole
+             # in VMEM around the monolithic prefill's writer (a 34 MB pool
+             # was: call 2 of PR 30; the cell's 1.07 GB pools are not:
+             # tests/test_paged_chunk_compiles.py compiles that program)
+             "num_slots": 8, "page_size": 16, "chunk": 64,
+             "max_model_len": 4096,
+             "requests": [(9, 24), (40, 12), (150, 16), (14, 40), (45, 8),
+                          (300, 20), (16, 32), (90, 10)],
+             "stream": (12, 16),
+             "kernels": {"heads": (32, 8, 64), "rows": 8, "chunk": 8}},
 }
 
 
@@ -497,7 +521,8 @@ def _engine_programs(engine, chunk):
         return np.zeros((1, width), dtype) if width else np.zeros((1,), dtype)
 
     one = (np.full((1, engine.table_width), engine._scratch, np.int32),
-           row(0, np.int32), row(0, np.float32), engine._base_key, *tail(1))
+           row(0, np.int32), row(0, np.float32), engine._base_key,
+           *engine._prefill_extra(None), *tail(1))
     s_pad = engine._prefill_bucket(1)
     return {
         "decode": (engine._step_program()[0], (
@@ -602,7 +627,10 @@ def _expect_pools_in_place(engine, chunk):
     that do not fill the device's sublane tiles (a shard of an mp engine),
     the int8 engine's 16-lane scale pools — XLA converts a pool on the way
     in and out, and the print says how often."""
-    pools = engine._pools
+    # a per-slot state that rides in the tuple (``state.slots``) is no
+    # page pool: a step scatters its slots' rows, a few KB a slot
+    pools = [engine._pools[i] for owner, idx in engine._adapter.pool_owners()
+             if owner != "state.slots" for i in idx]
     shard_bytes = pools[0].addressable_shards[0].data.nbytes
     row_major = all(
         p.format.layout.major_to_minor == tuple(range(p.ndim)) for p in pools)
@@ -628,22 +656,29 @@ def _counter(name, **labels):
     return 0 if m is None else (m.get(**labels) or 0)
 
 
-def phase_serve(cfg, kv_dtype, bf16=True, mesh=None, replica=None):
+def phase_serve(cfg, kv_dtype, bf16=True, mesh=None, replica=None,
+                build=None):
     """One engine: a cold pass (compiles inside), then the same requests
-    again (steady: no new trace).  Returns (facts, generated ids)."""
+    again (steady: no new trace).  Returns (facts, generated ids).
+    ``build()`` gives ``(model, layers with a paged cache, vocabulary)`` of
+    another family than GPT."""
     import paddle_tpu as paddle
     from paddle_tpu.serving import ServingEngine
     from paddle_tpu.text.models import GPTForCausalLM
 
     paddle.seed(SEED)
-    m = GPTForCausalLM(**cfg["model"]).eval()
-    if bf16:
-        m = m.bfloat16()
-    layers = len(m.gpt.layers)
-    vocab = m.gpt.word_embeddings.weight.shape[0]
+    if build is None:
+        m = GPTForCausalLM(**cfg["model"]).eval()
+        if bf16:
+            m = m.bfloat16()
+        layers = len(m.gpt.layers)
+        vocab = m.gpt.word_embeddings.weight.shape[0]
+    else:
+        m, layers, vocab = build()
     replica = replica or f"smoke-{kv_dtype or 'native'}"
     engine = ServingEngine(m, num_slots=cfg["num_slots"],
                            page_size=cfg["page_size"], kv_dtype=kv_dtype,
+                           max_model_len=cfg.get("max_model_len"),
                            prefill_chunk_tokens=cfg["chunk"],
                            numeric_guard=True, mesh=mesh, replica=replica)
     prompts = _prompts(cfg, vocab)
@@ -681,6 +716,117 @@ def phase_serve(cfg, kv_dtype, bf16=True, mesh=None, replica=None):
              "programs_outside_kernels": in_place,
              "pool_devices": pool_devices, "param_devices": param_devices}
     return facts, ids
+
+
+def _check_grouped_lanes(cfg, page_size, table_pages, out):
+    """The decode, chunk and write kernels over 16-bit pools whose rows are
+    whole lanes behind a narrower head (``d`` 64 stored 128 wide, as the
+    engine holds them), grouped query heads, a table ``table_pages`` wide:
+    each against the dense reference over the pool's real lanes."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    pa = importlib.import_module("paddle_tpu.ops.paged_attention")
+    rs = np.random.RandomState(SEED)
+    (H, HKV, D), B, C = cfg["heads"], cfg["rows"], cfg["chunk"]
+    ps, NP = page_size, table_pages
+    P, lanes = B * NP + 1, pa.pool_lane_dim(D)
+    kf, vf = (jnp.asarray(rs.randn(P, ps, HKV, D), jnp.bfloat16)
+              for _ in range(2))
+    table = jnp.asarray(rs.permutation(P - 1)[:B * NP].reshape(B, NP),
+                        jnp.int32)
+    lens = rs.randint(1, NP * ps + 1, (B,))
+    edges = [1, ps, NP * ps, 128, 129, NP * ps + 37, NP * ps // 2]
+    lens[:len(edges)] = edges[:B]
+    lens = jnp.asarray(lens, jnp.int32)
+    tag = f"H{H}_HKV{HKV}_D{D}in{lanes}_NP{NP}"
+
+    def stacked(entry, *tail):
+        # layer 1 of two, rows padded to whole lanes as the engine's are
+        return jax.jit(lambda x, *pools: entry(
+            x, *(jnp.stack([jnp.zeros_like(p), p])
+                 for p in (pa._to_lanes(p, lanes) for p in pools)),
+            *tail, layer=1))
+
+    q = jnp.asarray(rs.randn(B, H, D), jnp.bfloat16)
+    _check(f"paged_flash/{tag}", stacked(pa.paged_attention, table, lens),
+           lambda q, kp, vp: pa.paged_attention_ref(q, kp, vp, table, lens),
+           (q, kf, vf), 1, out)
+    qc = jnp.asarray(rs.randn(B, C, H, D), jnp.bfloat16)
+    base = jnp.asarray(rs.randint(0, NP * ps - C, (B,)), jnp.int32)
+
+    def chunk_ref(qc, kp, vp, base):
+        lens2 = base[:, None] + 1 + jnp.arange(C, dtype=jnp.int32)[None]
+        table2 = jnp.broadcast_to(table[:, None], (B, C, NP))
+        return pa.paged_attention_ref(
+            qc.reshape(B * C, H, D), kp, vp, table2.reshape(B * C, NP),
+            lens2.reshape(-1)).reshape(B, C, H, D)
+
+    _check(f"paged_chunk/{tag}",
+           jax.jit(lambda qc, kp, vp, base: stacked(
+               pa.paged_chunk_attend, table, base)(qc, kp, vp)),
+           chunk_ref, (qc, kf, vf, base), 1, out)
+    kn, vn = (jnp.asarray(rs.randn(B, C, HKV, D), jnp.bfloat16)
+              for _ in range(2))
+    pools = tuple(jnp.stack([p, p]) for p in (pa._to_lanes(kf, lanes),
+                                              pa._to_lanes(vf, lanes)))
+    for width in (1, C):
+        _check(f"paged_write/C{width}_{tag}",
+               jax.jit(lambda kn, vn, *pl: pa.paged_pool_write(
+                   pl, kn[:, :width], vn[:, :width], table, base, 1)),
+               lambda kn, vn, *pl: tuple(
+                   pa.paged_table_chunk_write(p, x, table, base, 1)
+                   for p, x in zip(pl, pa._pool_rows(
+                       pl, kn[:, :width], vn[:, :width]))),
+               (kn, vn, *pools), 1, out)
+
+
+def _greedy_gap(model, prompt, tokens):
+    """How far below the model's own best logit the served tokens lie, over
+    the spread of the logits, in the model's dense forward (no cache) of
+    prompt + tokens: a lost state or a wrong page moves it to order one."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+
+    ids = np.concatenate([prompt, np.asarray(tokens, np.int64)])
+    logits = np.asarray(model(paddle.to_tensor(ids[None]))._value.astype(
+        jnp.float32))[0, len(prompt) - 1:len(ids) - 1]
+    picked = logits[np.arange(len(tokens)), np.asarray(tokens)]
+    return float(np.max((logits.max(-1) - picked)
+                        / (logits.max(-1) - logits.mean(-1))))
+
+
+def phase_serve_lfm2(cfg):
+    """A small hybrid of the LFM2-MoE family through the engine's normal
+    path (per-slot state beside the pages of its one attention layer), and
+    the paged kernels at its heads over tables as wide as its context."""
+    from paddle_tpu.text.models import Lfm2MoeForCausalLM
+
+    t0 = time.time()
+    kernels = {}
+    _check_grouped_lanes(cfg["kernels"], cfg["page_size"],
+                         -(-cfg["max_model_len"] // cfg["page_size"]),
+                         kernels)
+    built = []
+
+    def build():
+        m = Lfm2MoeForCausalLM(**cfg["model"]).eval()
+        built.append(m)
+        return m, m.model.num_attention_layers, m.config.vocab_size
+
+    facts, ids = phase_serve(cfg, None, replica="smoke-lfm2", build=build)
+    prompts = _prompts(cfg, built[0].config.vocab_size)
+    gaps = [_greedy_gap(built[0], p, out)
+            for (p, _), out in list(zip(prompts, ids))[:3]]
+    log(f"  served tokens below the dense forward's best by {gaps} of the "
+        "logits' spread")
+    if max(gaps) > 0.1:
+        raise AssertionError(f"served tokens are not the model's: {gaps}")
+    return dict(facts, kernels=kernels, greedy_gap=round(max(gaps), 5),
+                seconds=round(time.time() - t0, 1))
 
 
 # ---------------------------------------------------------------- four chips
@@ -788,6 +934,7 @@ PHASES = {
     "train_gpt": lambda c: phase_train_gpt(c["gpt"]),
     "serve_bf16": lambda c: phase_serve(c["serve"], None)[0],
     "serve_int8": lambda c: phase_serve(c["serve"], "int8")[0],
+    "serve_lfm2": lambda c: phase_serve_lfm2(c["lfm2"]),
     "multichip": lambda c: phase_multichip(c["multichip"], c["resnet"],
                                            c["serve"]),
 }

@@ -209,23 +209,26 @@ def _collect_bwd(order, g):
 _collect_rows.defvjp(_collect_fwd, _collect_bwd)
 
 
-def sigmoid_topk(x, router_w, bias, top_k, scale=1.0, normalize=True):
+def sigmoid_topk(x, router_w, bias, top_k, scale=1.0, normalize=True,
+                 eps=1e-20):
     """DeepSeek-V3's router: ``(expert ids [T, k], weights [T, k] f32)``.
     Scores are ``sigmoid(x W_r)`` in float32 whatever ``x`` is; the k
     experts are the top k of ``score + bias``, the weights come from the
-    scores without the bias, normalised over the k and scaled."""
+    scores without the bias, normalised over the k (their sum + ``eps``:
+    the model states it, 1e-20 for DeepSeek-V3, 1e-6 for LFM2) and
+    scaled."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(scores, idx, axis=-1)
     if normalize:
-        picked = picked / (picked.sum(-1, keepdims=True) + jnp.float32(1e-20))
+        picked = picked / (picked.sum(-1, keepdims=True) + jnp.float32(eps))
     return idx, picked * jnp.float32(scale)
 
 
 def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
-                   expert_offset=0, scale=1.0, normalize=True):
+                   expert_offset=0, scale=1.0, normalize=True, eps=1e-20):
     """The held experts' part of a dropless MoE over tokens ``x`` [T, H]:
     ``(y [T, H], load [router's experts] int32)``, the load being the tokens
     each expert the router scores was chosen by, held here or not.
@@ -240,7 +243,7 @@ def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
     held = w_gate.shape[0]
     with jax.named_scope("moe_route"):
         idx, weights = sigmoid_topk(x, router_w, bias, top_k, scale,
-                                    normalize)
+                                    normalize, eps)
         local = idx - expert_offset
         here = (local >= 0) & (local < held)
         # assignments held elsewhere sort behind the last held expert
@@ -295,12 +298,17 @@ class DroplessMoELayer(Layer):
     here.
     ``tokens_per_expert`` counts the assignments each held expert has got
     since the layer was built.
+    ``norm_topk_eps`` is what the normalisation adds to the picked scores'
+    sum (the model's to state).  ``param_init(name, shape)``, where given,
+    makes each weight in place of the normal initializer: a model too large
+    to be drawn in float32 and cast hands its leaves over in their own type.
     """
 
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  experts_held=None, expert_offset=0, num_shared_experts=0,
                  routed_scaling_factor=1.0, norm_topk_prob=True,
-                 initializer_range=0.02, bias_update_speed=0.0):
+                 initializer_range=0.02, bias_update_speed=0.0,
+                 norm_topk_eps=1e-20, param_init=None):
         super().__init__()
         from ....nn import initializer as I
 
@@ -315,22 +323,26 @@ class DroplessMoELayer(Layer):
         self.experts_held, self.expert_offset = held, int(expert_offset)
         self.routed_scaling_factor = float(routed_scaling_factor)
         self.norm_topk_prob = bool(norm_topk_prob)
+        self.norm_topk_eps = float(norm_topk_eps)
         self.bias_update_speed = float(bias_update_speed)
         init = I.Normal(0.0, initializer_range)
 
-        def weight(*shape):
-            return self.create_parameter(list(shape), default_initializer=init)
+        def weight(name, *shape):
+            made = init if param_init is None \
+                else lambda shape_, dtype: param_init(name, shape_)
+            return self.create_parameter(list(shape),
+                                         default_initializer=made)
 
-        self.gate_weight = weight(d_model, num_experts)
-        self.w_gate = weight(held, d_model, d_expert)
-        self.w_up = weight(held, d_model, d_expert)
-        self.w_down = weight(held, d_expert, d_model)
+        self.gate_weight = weight("gate_weight", d_model, num_experts)
+        self.w_gate = weight("w_gate", held, d_model, d_expert)
+        self.w_up = weight("w_up", held, d_model, d_expert)
+        self.w_down = weight("w_down", held, d_expert, d_model)
         self.num_shared_experts = int(num_shared_experts)
         if num_shared_experts:
             d_shared = num_shared_experts * d_expert
-            self.shared_gate = weight(d_model, d_shared)
-            self.shared_up = weight(d_model, d_shared)
-            self.shared_down = weight(d_shared, d_model)
+            self.shared_gate = weight("shared_gate", d_model, d_shared)
+            self.shared_up = weight("shared_up", d_model, d_shared)
+            self.shared_down = weight("shared_down", d_shared, d_model)
         self.register_buffer("e_score_correction_bias",
                              jnp.zeros((num_experts,), jnp.float32))
         self.register_buffer("tokens_per_expert",
@@ -343,12 +355,13 @@ class DroplessMoELayer(Layer):
         this may run under ``recompute``."""
         top_k, offset = self.top_k, self.expert_offset
         scale, norm = self.routed_scaling_factor, self.norm_topk_prob
+        eps = self.norm_topk_eps
 
         def fn(xv, rw, bias, wg, wu, wd):
             y, load = routed_experts(
                 xv.reshape(-1, xv.shape[-1]), rw, bias, wg.astype(xv.dtype),
                 wu.astype(xv.dtype), wd.astype(xv.dtype), top_k=top_k,
-                expert_offset=offset, scale=scale, normalize=norm)
+                expert_offset=offset, scale=scale, normalize=norm, eps=eps)
             return y.reshape(xv.shape), load
 
         # no op_name: autocast must not round the router's bias, and the

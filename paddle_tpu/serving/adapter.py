@@ -48,6 +48,16 @@ on the way in and out: ROADMAP, Speed).
 prefill/step return ``(logits [B, V] f32, *pools)``, verify
 ``(logits [B, C, V] f32, *pools)``, with each pool a per-layer-stacked
 ``[L, P, ...]`` array.
+
+A SECOND KIND OF CACHE may ride in the pool tuple: :class:`SlotStateAdapter`
+(a hybrid decoder: attention layers beside layers that carry a fixed-size
+recurrent state) hands the engine ``(kp, vp, state)``, the pools over the
+attention layers only and ``state [L_state, slots + 1, ...]``, one row a
+slot, that lives and dies with the slot and not with pages.  Its decode
+rows are the slots; its one-request programs (``prefill``,
+``prefill_chunk``) take the slot's index as one more int32 operand behind
+``lens``, which the engine appends (``ServingEngine._prefill_extra``) for an
+adapter that says ``slot_state``.
 """
 
 from __future__ import annotations
@@ -56,16 +66,14 @@ import jax
 import jax.numpy as jnp
 
 
-class GPTAdapter:
-    """Adapter for :class:`paddle_tpu.text.models.GPTForCausalLM` (and any
-    model exposing the same ``.gpt`` decoder structure over the paged
-    cache).  Subclasses override the pool hooks (``init_pools``,
-    ``page_bytes``, ``pool_owners``, ``pool_pspecs``) to change the KV
-    storage format without touching the closure shapes: the cache seam
-    (``ops.paged_attention.paged_cache_attend``) tells the formats apart
-    by the pool tuple it is handed."""
+class PagedAdapter:
+    """What every adapter shares: the paged-cache tags, the geometry of the
+    K/V page pools and their arithmetic, the model's arrays taken under its
+    bind lock, the signature.  A subclass states the geometry
+    (:meth:`_set_geometry`), builds its pool tuple (``init_pools``) and
+    brings the closures."""
 
-    #: paged-cache tags this adapter drives (``paged_cache_attend``): one
+    #: paged-cache tags an adapter drives (``paged_cache_attend``): one
     #: token or a whole prompt per slot, and a chunk at the slot's own
     #: position
     tag = "served"
@@ -74,31 +82,27 @@ class GPTAdapter:
     n_pools = 2
     #: storage format label ("native" = the model dtype)
     kv_dtype = "native"
-
-    def __init__(self, model, page_size=16):
-        self.model = model
-        self.gpt = model.gpt
-        blk = self.gpt.layers[0]
-        self.num_layers = len(self.gpt.layers)
-        self.head_dim = blk.head_dim
-        # local head count from the actual projection width (TP-safe); an
-        # int8-weight model (serving.quant.quantize_model_weights) stores
-        # the projection as an Int8Linear whose weight lives in the
-        # ``weight_int8`` buffer — same shape, different attribute
-        qkv_w = getattr(blk.qkv, "weight", None)
-        if qkv_w is None:
-            qkv_w = blk.qkv.weight_int8
-        self.num_kv_heads = qkv_w.shape[-1] // (3 * blk.head_dim)
-        self.dtype = self.gpt.word_embeddings.weight._value.dtype
-        self.max_model_len = self.gpt.position_embeddings.weight.shape[0]
-        self.page_size = int(page_size)
-
+    #: True where the pool tuple holds a per-slot state beside the pages:
+    #: the engine then hands the one-request programs the slot's index
+    slot_state = False
     #: set by ServingEngine(mesh=...) — the jax Mesh whose "model" axis the
     #: pools/weights are sharded over (None = single-device serving).  The
     #: TPU flash kernels consult it at trace time (mp_shard_scope) so each
     #: shard's Pallas page sweep covers only its local KV heads.
     mp_mesh = None
     mp_axis = "model"
+
+    def _set_geometry(self, model, page_size, num_layers, num_kv_heads,
+                      head_dim, dtype, max_model_len):
+        """``num_layers`` counts the layers that HOLD pages (all of a
+        ``.gpt`` decoder, the attention layers of a hybrid)."""
+        self.model = model
+        self.page_size = int(page_size)
+        self.num_layers = int(num_layers)
+        self.num_kv_heads = int(num_kv_heads)
+        self.head_dim = int(head_dim)
+        self.dtype = dtype
+        self.max_model_len = int(max_model_len)
 
     def params_and_buffers(self):
         # under the bind lock: another replica of this model may be inside
@@ -123,6 +127,66 @@ class GPTAdapter:
                 "page_size": int(self.page_size),
                 "max_model_len": int(self.max_model_len),
                 "dtype": str(self.dtype)}
+
+    # ----------------------------------------------------------- pool hooks
+    def _page_pool(self, num_pages):
+        """One zeroed payload pool [L, P, ps, h, d]: every page-holding
+        layer's pages in one array, ``d`` the head size in whole lanes."""
+        from ..ops.paged_attention import pool_lane_dim
+
+        return jnp.zeros((self.num_layers, int(num_pages), self.page_size,
+                          self.num_kv_heads, pool_lane_dim(self.head_dim)),
+                         self.dtype)
+
+    def page_bytes(self):
+        """HBM bytes ONE page costs across the layers that hold pages, K
+        and V (the unit BlockManager capacity math and the
+        serving.kv_bytes_per_token gauge are denominated in)."""
+        from ..ops.paged_attention import pool_lane_dim
+
+        return (2 * self.num_layers * self.page_size * self.num_kv_heads
+                * pool_lane_dim(self.head_dim)
+                * jnp.dtype(self.dtype).itemsize)
+
+    def pool_owners(self):
+        """Memory-ledger owner labels over the pool tuple: ``(owner,
+        pool-index tuple)`` pairs covering EVERY pool array, so the
+        engine's ledger registration attributes payload and scale pools
+        separately (observability.memory owner taxonomy)."""
+        return (("kv.pages", tuple(range(self.n_pools))),)
+
+    def state_bytes_per_slot(self):
+        """HBM bytes of per-slot state ONE resident sequence costs beside
+        its pages (the ``serving.state_bytes_per_slot`` gauge): none for a
+        decoder whose only cache is paged."""
+        return 0
+
+
+class GPTAdapter(PagedAdapter):
+    """Adapter for :class:`paddle_tpu.text.models.GPTForCausalLM` (and any
+    model exposing the same ``.gpt`` decoder structure over the paged
+    cache).  Subclasses override the pool hooks (``init_pools``,
+    ``page_bytes``, ``pool_owners``, ``pool_pspecs``) to change the KV
+    storage format without touching the closure shapes: the cache seam
+    (``ops.paged_attention.paged_cache_attend``) tells the formats apart
+    by the pool tuple it is handed."""
+
+    def __init__(self, model, page_size=16):
+        self.gpt = model.gpt
+        blk = self.gpt.layers[0]
+        # local head count from the actual projection width (TP-safe); an
+        # int8-weight model (serving.quant.quantize_model_weights) stores
+        # the projection as an Int8Linear whose weight lives in the
+        # ``weight_int8`` buffer — same shape, different attribute
+        qkv_w = getattr(blk.qkv, "weight", None)
+        if qkv_w is None:
+            qkv_w = blk.qkv.weight_int8
+        self._set_geometry(
+            model, page_size, num_layers=len(self.gpt.layers),
+            num_kv_heads=qkv_w.shape[-1] // (3 * blk.head_dim),
+            head_dim=blk.head_dim,
+            dtype=self.gpt.word_embeddings.weight._value.dtype,
+            max_model_len=self.gpt.position_embeddings.weight.shape[0])
 
     # --------------------------------------------------------- mp sharding
     def validate_mp(self, mp):
@@ -158,31 +222,9 @@ class GPTAdapter:
 
     # ----------------------------------------------------------- pool hooks
     def init_pools(self, num_pages):
-        """Zeroed K/V pools ``(kp, vp)``, each [L, P, ps, h, d]: every
-        layer's pages in one array, ``d`` the head size in whole lanes."""
-        from ..ops.paged_attention import pool_lane_dim
-
-        shape = (self.num_layers, int(num_pages), self.page_size,
-                 self.num_kv_heads, pool_lane_dim(self.head_dim))
-        kp = jnp.zeros(shape, self.dtype)
+        """Zeroed K/V pools ``(kp, vp)``, each [L, P, ps, h, d]."""
+        kp = self._page_pool(num_pages)
         return kp, jnp.zeros_like(kp)
-
-    def page_bytes(self):
-        """HBM bytes ONE page costs across all layers, K and V (the unit
-        BlockManager capacity math and the serving.kv_bytes_per_token
-        gauge are denominated in)."""
-        from ..ops.paged_attention import pool_lane_dim
-
-        return (2 * self.num_layers * self.page_size * self.num_kv_heads
-                * pool_lane_dim(self.head_dim)
-                * jnp.dtype(self.dtype).itemsize)
-
-    def pool_owners(self):
-        """Memory-ledger owner labels over the pool tuple: ``(owner,
-        pool-index tuple)`` pairs covering EVERY pool array, so the
-        engine's ledger registration attributes payload and scale pools
-        separately (observability.memory owner taxonomy)."""
-        return (("kv.pages", tuple(range(self.n_pools))),)
 
     # ------------------------------------------------------------- closures
     def _run(self, params, bufs, ids, pools, table, lens, pos_ids, tag,
@@ -335,3 +377,154 @@ class GPTAdapter:
         h = jnp.take_along_axis(x, idx, axis=1)[:, 0]
         logits = h.astype(jnp.float32) @ w.T.astype(jnp.float32)
         return (logits,) + pools
+
+
+class SlotStateAdapter(PagedAdapter):
+    """Adapter for a hybrid decoder that is not ``.gpt``: rotary positions
+    (no position table: the model states the cap on ``max_model_len``),
+    grouped KV heads in SOME layers and a fixed-size recurrent state in the
+    others (:class:`paddle_tpu.text.models.Lfm2MoeForCausalLM`: gated short
+    convolutions).  The engine builds it for a model that states its
+    caches (``model.serving_caches``) and takes no flag.
+
+    What it asks of the model, and all it reads of it:
+
+    - ``model.serving_caches()``: ``{"attention_layers", "kv_heads",
+      "head_dim"}`` (the pages), ``"state_shape"`` (``(layers, rows,
+      width)`` of the state ONE sequence carries), ``"max_positions"`` and
+      ``"dtype"``;
+    - ``model.model`` is the decoder, called as ``decoder(ids, position_ids,
+      cache=(tag, (kp, vp), table, lens), conv_state=[L_state, B, R, W],
+      valid=[B])`` and returning ``(hidden, (kp, vp), state after the
+      call)``;
+    - ``model.head_weight()`` is the head as ``[V, H]``.
+
+    The pool tuple is ``(kp, vp, state)``: ``kp`` / ``vp`` ``[L_attn, P, ps,
+    hkv, d]`` indexed by an attention layer's rank among the attention
+    layers, ``state [L_state, slots + 1, R, W]`` by a state layer's rank and
+    the slot; row ``slots`` is the scratch row of idle lanes.  The state's
+    rules (each pinned in ``tests/test_lfm2.py``):
+
+    - a chunk that starts at position 0 (``lens[b] == 0``) and a monolithic
+      prefill enter with ZERO state, whatever the slot's last tenant left;
+    - a chunk (and a right-padded prompt) leaves the state at its row's
+      last REAL lane (``nvalid`` / ``lens``), not at the pad;
+    - a decode step shifts it by one; a lane with ``lens[b] == 0`` is idle
+      (retired, or mid-prefill: its state is the chunk program's) and reads
+      and writes only the scratch row;
+    - nothing resets it on the host: preemption re-prefills.
+
+    Its closures have :class:`GPTAdapter`'s shape and a text of their own
+    (the state enters and leaves beside the pools): ROADMAP, Design 2.
+    """
+
+    n_pools = 3
+    slot_state = True
+
+    def __init__(self, model, page_size, num_slots):
+        need = model.serving_caches()
+        self.decoder = model.model
+        self._set_geometry(
+            model, page_size, num_layers=need["attention_layers"],
+            num_kv_heads=need["kv_heads"], head_dim=need["head_dim"],
+            dtype=need["dtype"], max_model_len=need["max_positions"])
+        #: (layers, rows, width) of the state one sequence carries
+        self.state_shape = tuple(int(n) for n in need["state_shape"])
+        self.num_slots = int(num_slots)
+
+    def signature(self):
+        return dict(super().signature(),
+                    state_shape=list(self.state_shape),
+                    num_slots=int(self.num_slots))
+
+    # ----------------------------------------------------------- pool hooks
+    def init_pools(self, num_pages):
+        """``(kp, vp, state)``: pages over the attention layers only, and
+        the zeroed per-slot state with its scratch row."""
+        kp = self._page_pool(num_pages)
+        layers, rows, width = self.state_shape
+        state = jnp.zeros((layers, self.num_slots + 1, rows, width),
+                          self.dtype)
+        return kp, jnp.zeros_like(kp), state
+
+    def pool_owners(self):
+        return (("kv.pages", (0, 1)), ("state.slots", (2,)))
+
+    def state_bytes_per_slot(self):
+        layers, rows, width = self.state_shape
+        return layers * rows * width * jnp.dtype(self.dtype).itemsize
+
+    # ------------------------------------------------------------- closures
+    def _split_extra(self, args):
+        """``(*pools, table, lens[, slot])`` -> ``((kp, vp), state, table,
+        lens, slot)``.  The decode program's rows are the slots already; a
+        lane that holds nothing (``lens == 0``) goes to the scratch row."""
+        n = self.n_pools
+        if len(args) not in (n + 2, n + 3):
+            raise TypeError(
+                f"{type(self).__name__} closures take {n} pool arrays + "
+                f"table + lens (+ the slot's index); got {len(args)} "
+                f"trailing args")
+        table, lens = args[n], args[n + 1]
+        if len(args) == n + 3:
+            slot = args[n + 2].astype(jnp.int32)
+        else:
+            slot = jnp.where(lens > 0,
+                             jnp.arange(lens.shape[0], dtype=jnp.int32),
+                             jnp.int32(self.num_slots))
+        return tuple(args[:2]), args[2], table, lens, slot
+
+    def _run(self, params, bufs, ids, kv, entering, valid, table, lens,
+             pos_ids, tag):
+        from ..framework import random as _rng
+        from ..framework.state import no_grad_ctx
+        from ..tensor.tensor import Tensor
+
+        with no_grad_ctx(), _rng.rng_scope(jax.random.key(0)), \
+                self.model.bind(params, bufs):
+            x, kv, after = self.decoder(
+                Tensor(ids), position_ids=Tensor(pos_ids),
+                cache=(tag, tuple(Tensor(p) for p in kv), Tensor(table),
+                       Tensor(lens)),
+                conv_state=Tensor(entering),
+                valid=None if valid is None else Tensor(valid))
+            w = self.model.head_weight()._value
+            return x._value, w, tuple(p._value for p in kv), after._value
+
+    @staticmethod
+    def _logits(h, w):
+        return h.astype(jnp.float32) @ w.T.astype(jnp.float32)
+
+    def prefill(self, params, bufs, ids, *args):
+        kv, state, table, lens, slot = self._split_extra(args)
+        pos_ids = jnp.arange(ids.shape[1], dtype=jnp.int64)[None, :]
+        fresh = jnp.zeros_like(state[:, slot])
+        x, w, kv, after = self._run(params, bufs, ids, kv, fresh, lens,
+                                    table, lens, pos_ids, self.tag)
+        idx = (lens.astype(jnp.int32) - 1)[:, None, None]
+        h = jnp.take_along_axis(x, idx, axis=1)[:, 0]
+        return (self._logits(h, w),) + kv + (state.at[:, slot].set(after),)
+
+    def step(self, params, bufs, last, *args):
+        kv, state, table, lens, slot = self._split_extra(args)
+        pos_ids = lens[:, None].astype(jnp.int64)
+        x, w, kv, after = self._run(params, bufs, last, kv, state[:, slot],
+                                    None, table, lens, pos_ids, self.tag)
+        return (self._logits(x[:, -1], w),) + kv \
+            + (state.at[:, slot].set(after),)
+
+    def prefill_chunk(self, params, bufs, ids, nvalid, *args):
+        kv, state, table, lens, slot = self._split_extra(args)
+        C = ids.shape[1]
+        pos_ids = lens[:, None].astype(jnp.int64) \
+            + jnp.arange(C, dtype=jnp.int64)[None, :]
+        pos_ids = jnp.minimum(pos_ids, self.max_model_len - 1)
+        # a slot's first chunk starts a sequence: zero state, whatever the
+        # last tenant left in the row
+        entering = jnp.where((lens > 0)[None, :, None, None],
+                             state[:, slot], jnp.zeros((), state.dtype))
+        x, w, kv, after = self._run(params, bufs, ids, kv, entering, nvalid,
+                                    table, lens, pos_ids, self.chunk_tag)
+        idx = jnp.maximum(nvalid.astype(jnp.int32) - 1, 0)[:, None, None]
+        h = jnp.take_along_axis(x, idx, axis=1)[:, 0]
+        return (self._logits(h, w),) + kv + (state.at[:, slot].set(after),)

@@ -71,7 +71,7 @@ class PageAllocation:
 class BlockManager:
     def __init__(self, num_pages, page_size, prefix_sharing=False,
                  replica="0", bytes_per_page=None, pool_dtype=None,
-                 shards=1, radix=False, spill=None):
+                 shards=1, radix=False, spill=None, state_bytes_per_seq=0):
         if num_pages < 1:
             raise ValueError(f"num_pages must be >= 1, got {num_pages}")
         if page_size < 1:
@@ -93,6 +93,9 @@ class BlockManager:
         self.bytes_per_page = int(bytes_per_page) \
             if bytes_per_page is not None else None
         self.pool_dtype = str(pool_dtype) if pool_dtype is not None else None
+        # what ONE resident sequence holds beside its pages: a fixed-size
+        # recurrent state a slot (hybrid decoders), whatever its length
+        self.state_bytes_per_seq = int(state_bytes_per_seq)
         self.shards = int(shards)
         self._free = collections.deque(range(self.num_pages))
         self._active = {}                       # prefix key -> [page, refs]
@@ -185,6 +188,8 @@ class BlockManager:
             st["pool_bytes"] = self.num_pages * self.bytes_per_page
             st["used_bytes"] = self.used_pages * self.bytes_per_page
             st["kv_bytes_per_token"] = self.bytes_per_page / self.page_size
+        if self.state_bytes_per_seq:
+            st["state_bytes_per_seq"] = self.state_bytes_per_seq
         if self.prefix_sharing:
             # hit TOKENS, not just hit counts: saved_tokens is hit pages x
             # page_size, so a 100-page shared-run hit reads as 100x the
@@ -263,6 +268,10 @@ class BlockManager:
         if budget_bytes is not None:
             if self.bytes_per_page is None:
                 raise ValueError("budget_bytes needs bytes_per_page")
+            if self.state_bytes_per_seq:
+                # a sequence costs its pages AND its per-slot state
+                return int(budget_bytes) // (
+                    per_seq * self.bytes_per_page + self.state_bytes_per_seq)
             pages = int(budget_bytes) // self.bytes_per_page
         return pages // per_seq
 

@@ -100,7 +100,7 @@ from ..observability import programs as _programs
 from ..observability import tracing as _tracing
 from ..resilience.retry import (EngineStoppedError, NumericFault,  # noqa: F401 — re-exported
                                 classify_failure)
-from .adapter import GPTAdapter
+from .adapter import GPTAdapter, SlotStateAdapter
 from .block_manager import BlockManager
 
 _logger = logging.getLogger("paddle_tpu.serving")
@@ -468,14 +468,29 @@ class ServingEngine:
         # (decode@mp2, prefill/<b>@mp2, ...) and program-store keys, so an
         # mp=1 engine's programs stay byte-identical to pre-mesh builds
         self._mp_suffix = f"@mp{self._mp}" if self._mp > 1 else ""
+        # the one rule by which the engine picks an adapter from the model:
+        # a decoder that is not ``.gpt`` states the caches it needs
+        # (``model.serving_caches()``) and is served through them
         if adapter is not None:
             self._adapter = adapter
+        elif hasattr(model, "serving_caches"):
+            self._adapter = SlotStateAdapter(model, int(page_size),
+                                             int(num_slots))
         elif kv_dtype == "int8":
             from .quant.adapter import QuantizedGPTAdapter
 
             self._adapter = QuantizedGPTAdapter(model, page_size)
         else:
             self._adapter = GPTAdapter(model, page_size)
+        # per-slot state beside the pages (adapter.SlotStateAdapter): the
+        # one-request programs get the slot's index, and the mechanisms
+        # that move or share pages without the state are refused by name
+        self._slot_state = bool(getattr(self._adapter, "slot_state", False))
+        if self._slot_state:
+            self._refuse_with_slot_state(
+                prefix_sharing=prefix_sharing, prefix_cache=prefix_cache,
+                kv_spill=kv_spill, speculative_k=speculative_k,
+                kv_dtype=kv_dtype, mesh=mesh)
         if self._mp > 1:
             self._adapter.validate_mp(self._mp)
             # the adapter carries the mesh so the TPU flash kernels trace
@@ -534,6 +549,8 @@ class ServingEngine:
         self._bytes_per_page = int(self._adapter.page_bytes()) // self._mp
         self._pool_dtype = "int8" if self.kv_dtype == "int8" \
             else str(self._adapter.dtype)
+        state_bytes = getattr(self._adapter, "state_bytes_per_slot", None)
+        self._state_bytes_per_slot = int(state_bytes()) if state_bytes else 0
         self._bm = self._new_block_manager()
         # pool row num_pages is the SCRATCH page: inactive decode slots and
         # padded table tails point at it (every table entry must be a valid
@@ -825,6 +842,10 @@ class ServingEngine:
         self._m_pool_bytes = _g(
             "serving.pool_bytes",
             "allocated KV page-pool HBM bytes (scratch page included)")
+        self._m_state_bytes_slot = _g(
+            "serving.state_bytes_per_slot",
+            "HBM bytes of per-slot state one resident sequence holds beside "
+            "its KV pages (0 for a decoder whose only cache is paged)")
         self._set_pool_gauges()
         # memory observability (observability/memory.py): every long-lived
         # device allocation this engine owns registers with the process
@@ -833,11 +854,41 @@ class ServingEngine:
         # pages already committed to admitted-but-unfinished requests
         self._fixed_bytes = int(
             sum(int(v.nbytes) for v in self._params.values())
-            + sum(int(v.nbytes) for v in self._bufs.values()))
+            + sum(int(v.nbytes) for v in self._bufs.values())
+            + self._state_bytes_per_slot * (self.num_slots + 1))
         self._committed_pages = 0
         self._commit_lock = threading.Lock()
         self._mem_regs = []
         self._register_memory()
+
+    @staticmethod
+    def _refuse_with_slot_state(prefix_sharing, prefix_cache, kv_spill,
+                                speculative_k, kv_dtype, mesh):
+        """A decoder with per-slot state (adapter.SlotStateAdapter) is
+        served on the plain path only: every mechanism that shares, moves
+        or re-reads pages WITHOUT the state that belongs to them is
+        refused here by name, at construction."""
+        why = None
+        if prefix_sharing or prefix_cache is not None:
+            why = ("prefix_sharing / prefix_cache (and the cached-prefill "
+                   "path behind them): a shared page run carries no "
+                   "per-slot state at its edge, so a prefill that starts "
+                   "past it would enter with the wrong state")
+        elif kv_spill:
+            why = ("kv_spill: the host spill tier snapshots pages, not the "
+                   "state a sequence had at their edge")
+        elif speculative_k:
+            why = ("speculative_k: a rejected draft would need the "
+                   "per-slot state rolled back, and no snapshot is kept")
+        elif kv_dtype == "int8":
+            why = ("kv_dtype='int8': the quantized pool tuple has no place "
+                   "for the per-slot state")
+        elif mesh is not None:
+            why = ("mesh=: the per-slot state pool has no sharding rule")
+        if why is not None:
+            raise ValueError(
+                f"this model is served with per-slot state beside its "
+                f"paged KV; not supported with it: {why}")
 
     def _register_memory(self):
         """Register this engine's device allocations with the process
@@ -953,7 +1004,8 @@ class ServingEngine:
                             bytes_per_page=self._bytes_per_page,
                             pool_dtype=self._pool_dtype,
                             shards=self._mp,
-                            radix=self._radix, spill=self._spill)
+                            radix=self._radix, spill=self._spill,
+                            state_bytes_per_seq=self._state_bytes_per_slot)
 
     # ------------------------------------------------- hierarchical KV cache
     def _spill_snapshot(self, page):
@@ -982,6 +1034,7 @@ class ServingEngine:
 
     def _set_pool_gauges(self):
         self._m_kv_bytes_tok.set(self._bytes_per_page / self.page_size)
+        self._m_state_bytes_slot.set(float(self._state_bytes_per_slot))
         # one series PER POOL DTYPE: the quantized engine's f32 scale
         # pools are real device residency — folding them into the int8
         # series used to make serving.pool_bytes disagree with what the
@@ -1698,6 +1751,13 @@ class ServingEngine:
         (and trace counters) stay byte-for-byte identical."""
         return ("mp", self._mp) if self._mp > 1 else ()
 
+    def _state_key(self):
+        """Program-store key component for a pool tuple with per-slot
+        state: its shape follows ``num_slots``, which the one-request
+        programs' keys do not hold.  Empty without state — those keys stay
+        byte-for-byte what they were."""
+        return ("state", self._pools[-1].shape) if self._slot_state else ()
+
     def _store(self):
         from ..text.models._decode import program_store
 
@@ -1708,22 +1768,26 @@ class ServingEngine:
     def _step_store_key(self):
         return ("serve_step", self.num_slots, self.table_width,
                 self._pools[0].shape, str(self._pools[0].dtype),
-                self._top) + self._guard_key() + self._mp_key()
+                self._top) + self._guard_key() + self._mp_key() \
+            + self._state_key()
 
     def _verify_store_key(self, k_pad):
         return ("verify", k_pad, self.num_slots, self.table_width,
                 self._pools[0].shape, str(self._pools[0].dtype),
-                self._top) + self._guard_key() + self._mp_key()
+                self._top) + self._guard_key() + self._mp_key() \
+            + self._state_key()
 
     def _prefill_store_key(self, s_pad):
         return ("serve_prefill", s_pad, self.table_width,
                 self._pools[0].shape, str(self._pools[0].dtype),
-                self._top) + self._guard_key() + self._mp_key()
+                self._top) + self._guard_key() + self._mp_key() \
+            + self._state_key()
 
     def _prefill_chunk_store_key(self, c_pad):
         return ("serve_prefill_chunk", c_pad, self.table_width,
                 self._pools[0].shape, str(self._pools[0].dtype),
-                self._top) + self._guard_key() + self._mp_key()
+                self._top) + self._guard_key() + self._mp_key() \
+            + self._state_key()
 
     def _step_program(self):
         key = self._step_store_key()
@@ -1829,16 +1893,18 @@ class ServingEngine:
             def prefill(params, bufs, ids, *rest):
                 traces[0] += 1
                 if guard:
-                    pools, (table, lens, temps, rkey, inj) = \
+                    pools, (table, lens, temps, rkey, *extra, inj) = \
                         rest[:n], rest[n:]
                     out = adapter.prefill(params, bufs, ids, *pools, table,
-                                          lens)
+                                          lens, *extra)
                     logits = out[0] + inj[:, None]
                     tok, bad = gsampler(logits, temps, rkey)
                     stats = _numerics.stats_row(logits, low)[None]
                     return (tok, bad, stats) + tuple(out[1:])
-                pools, (table, lens, temps, rkey) = rest[:n], rest[n:]
-                out = adapter.prefill(params, bufs, ids, *pools, table, lens)
+                pools, (table, lens, temps, rkey, *extra) = \
+                    rest[:n], rest[n:]
+                out = adapter.prefill(params, bufs, ids, *pools, table, lens,
+                                      *extra)
                 return (sampler(out[0], temps, rkey),) + tuple(out[1:])
 
             return prefill, traces
@@ -1866,17 +1932,18 @@ class ServingEngine:
             def chunk(params, bufs, ids, nvalid, *rest):
                 traces[0] += 1
                 if guard:
-                    pools, (table, lens, temps, rkey, inj) = \
+                    pools, (table, lens, temps, rkey, *extra, inj) = \
                         rest[:n], rest[n:]
                     out = adapter.prefill_chunk(params, bufs, ids, nvalid,
-                                                *pools, table, lens)
+                                                *pools, table, lens, *extra)
                     logits = out[0] + inj[:, None]
                     tok, bad = gsampler(logits, temps, rkey)
                     stats = _numerics.stats_row(logits, low)[None]
                     return (tok, bad, stats) + tuple(out[1:])
-                pools, (table, lens, temps, rkey) = rest[:n], rest[n:]
+                pools, (table, lens, temps, rkey, *extra) = \
+                    rest[:n], rest[n:]
                 out = adapter.prefill_chunk(params, bufs, ids, nvalid,
-                                            *pools, table, lens)
+                                            *pools, table, lens, *extra)
                 return (sampler(out[0], temps, rkey),) + tuple(out[1:])
 
             return chunk, traces
@@ -2331,7 +2398,7 @@ class ServingEngine:
         prog, traces = self._prefill_program(s_pad)
         n0 = traces[0]
         rkey = self._next_key()
-        extra = self._prefill_extra(req)
+        extra = self._prefill_extra(req, slot_idx)
         guard = self._numeric_guard
         tail = (self._numeric_inject(1),) if guard else ()
         fam = self._prefill_family(s_pad)
@@ -2451,7 +2518,7 @@ class ServingEngine:
         prog, traces = self._prefill_chunk_program(C)
         n0 = traces[0]
         rkey = self._next_key()
-        extra = self._prefill_extra(req)
+        extra = self._prefill_extra(req, slot_idx)
         guard = self._numeric_guard
         gtail = (self._numeric_inject(1),) if guard else ()
         fam = self._prefill_cached_family(C, alloc.cached_pages)
@@ -2624,7 +2691,7 @@ class ServingEngine:
         prog, traces = self._prefill_chunk_program(C)
         n0 = traces[0]
         rkey = self._next_key()
-        extra = self._prefill_extra(req)
+        extra = self._prefill_extra(req, i)
         guard = self._numeric_guard
         tail = (self._numeric_inject(1),) if guard else ()
         fam = self._prefill_chunk_family(C)
@@ -2747,10 +2814,15 @@ class ServingEngine:
     def _verify_family(self):
         return f"verify/k{self._spec_k}{self._fam_suffix}{self._mp_suffix}"
 
-    def _prefill_extra(self, req):
+    def _prefill_extra(self, req, slot_idx=None):
         """Host arrays appended to the prefill dispatch (adapter ids,
-        grammar mask, adapter pools)."""
-        return ()
+        grammar mask, adapter pools).  Where the adapter keeps a per-slot
+        state, the slot's index: a one-request program's row is no slot
+        (no request, as in a warm-up replay: the scratch row)."""
+        if not self._slot_state:
+            return ()
+        return (np.asarray([self.num_slots if slot_idx is None
+                            else slot_idx], np.int32),)
 
     def _step_extra(self):
         """Host arrays appended to the decode dispatch."""

@@ -233,7 +233,7 @@ class MultiTenantEngine(ServingEngine):
             handle.cancel()
             return np.ones((self._vsize,), np.bool_)
 
-    def _prefill_extra(self, req):
+    def _prefill_extra(self, req, slot_idx=None):
         allowed = np.ones((1, self._vsize), np.bool_)
         if req.grammar is not None:
             allowed[0] = self._mask_or_fail(req.handle, req.grammar,

@@ -12,3 +12,6 @@ from .llama import (  # noqa: F401
 from .deepseek_v3 import (  # noqa: F401
     DeepseekV3Config, DeepseekV3ForCausalLM, DeepseekV3Model,
 )
+from .lfm2 import (  # noqa: F401
+    Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel,
+)

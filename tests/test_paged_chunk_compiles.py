@@ -223,3 +223,78 @@ def test_grouped_products_compile_at_published_widths(one_chip):
     text = compiled.as_text()
     assert text.count('op_name="ragged-dot') + text.count("%ragged-dot") >= 2
     assert compiled.cost_analysis()["flops"] < 4 * 2 * M * K * N
+
+
+@pytest.mark.parametrize("closure", ["step", "prefill_chunk", "prefill"])
+def test_hybrid_programs_fit_the_chip_at_the_cells_sizes(monkeypatch,
+                                                         one_chip, closure):
+    """``SlotStateAdapter``'s decode, chunk and monolithic prefill programs
+    for the ten-layer cut of LFM2-24B-A2B as
+    ``lfm2_24b_a2b_l10.batch_closed_4k`` serves it (32 slots, 8,193 pages,
+    tables 256 wide, bfloat16): 10.5 GB of weights that are never built
+    here (every leaf a shape), the paged pools touched by the kernels alone,
+    a few tens of MB of temporaries.  The cell's prompts are all longer
+    than a chunk, so the monolithic prefill (a prompt of 256 tokens or
+    fewer) runs at this size nowhere else: at 513 pages the compiler
+    staged both layers of a 34 MB pool in VMEM around the writer
+    (``slice-start`` into ``S(1)``: chip_smoke, PR 30); at 1.07 GB a pool
+    it cannot, and no instruction is the size of a pool or of a layer of
+    one."""
+    import json
+    import os
+
+    import chip_smoke
+    from paddle_tpu.serving import SlotStateAdapter
+    from paddle_tpu.text.models.lfm2 import Lfm2MoeForCausalLM
+
+    spec = importlib.import_module("chipbench.spec")
+    ref = spec.load_module("reference", "lfm2")
+    family = spec.load_module("models", "lfm2")
+    with open(os.path.join(spec.HERE, "configs",
+                           "lfm2_24b_a2b_l10.json")) as f:
+        cfg = json.load(f)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    # a scalar stands in for every leaf; its shape comes from the reference
+    model = Lfm2MoeForCausalLM(
+        family.program_config(cfg, "bfloat16"),
+        param_init=lambda name, shape: jnp.zeros(
+            (), jnp.float32 if name.endswith("bias") else jnp.bfloat16)).eval()
+    shapes = ref.param_shapes(cfg)
+    for name, leaf in list(model.named_parameters()) \
+            + list(model.named_buffers()):
+        if name in shapes:
+            leaf._value = jax.ShapeDtypeStruct(shapes[name],
+                                               leaf._value.dtype)
+    slots = 32
+    adapter = SlotStateAdapter(model, 16, slots)
+    params, bufs = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), adapter.params_and_buffers())
+    assert sum(math.prod(v.shape) for v in params.values()) == 5_267_089_664
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pools = tuple(sds(p.shape, p.dtype) for p in jax.eval_shape(
+        lambda: adapter.init_pools(slots * 256 + 1)))
+    assert [p.shape for p in pools] == [(2, 8193, 16, 8, 128)] * 2 \
+        + [(8, slots + 1, 2, 2048)]
+    B, width = (slots, 1) if closure == "step" else (1, 256)
+    lead = (sds((B, width), jnp.int64),) + (
+        (sds((B,), jnp.int32),) if closure == "prefill_chunk" else ())
+    slot = () if closure == "step" else (sds((B,), jnp.int32),)
+    first = 2 + len(lead)
+    compiled = jax.jit(
+        getattr(adapter, closure),
+        donate_argnums=tuple(range(first, first + 3))).lower(
+        params, bufs, *lead, *pools, sds((B, 256), jnp.int32),
+        sds((B,), jnp.int32), *slot).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert chip_smoke.pool_sized_instructions(
+        text, [p.shape for p in pools[:2]]) == []
+    # two attention layers: a writer and an attend kernel each; the rest
+    # are XLA's own Mosaic calls for the 8 x 3 grouped products
+    assert text.count("gqa_attention") >= 4
+    assert text.count('op_name="ragged-dot-none"') == 24
+    assert mem.temp_size_in_bytes < 100e6
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) < 12.5e9

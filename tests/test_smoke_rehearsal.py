@@ -29,6 +29,15 @@ TOY = {
               "num_slots": 2, "page_size": 8, "chunk": 16,
               "requests": [(5, 6), (20, 4), (40, 5)], "stream": (7, 4)},
     "multichip": {"chips": 4, "ring": (1, 1024, 2, 64), "allreduce_mb": 1},
+    "lfm2": {"model": dict(hidden_size=64, num_attention_heads=4,
+                           num_key_value_heads=2, intermediate_size=96,
+                           moe_intermediate_size=16, num_experts=4,
+                           num_experts_per_tok=2, num_hidden_layers=4,
+                           vocab_size=128),
+             "num_slots": 2, "page_size": 8, "chunk": 16,
+             "max_model_len": 64,
+             "requests": [(5, 6), (20, 4), (40, 5)], "stream": (7, 4),
+             "kernels": {"heads": (4, 2, 64), "rows": 4, "chunk": 3}},
 }
 
 
@@ -76,7 +85,7 @@ def as_tpu(monkeypatch):
 
 
 @pytest.mark.parametrize("phase", ["kernels", "train_resnet", "train_gpt",
-                                   "serve_bf16", "serve_int8"])
+                                   "serve_bf16", "serve_int8", "serve_lfm2"])
 def test_phase_rehearsal(as_tpu, phase):
     facts = chip_smoke.run_phase(phase, TOY)
     assert isinstance(facts, dict) and facts
