@@ -23,7 +23,8 @@ One process, the public entry points, full width, random weights from a seed:
   through the same engine with per-slot convolution state beside its pages,
   its tokens held to the model's own dense forward, and the decode, chunk and
   write kernels at those heads over pools 128 lanes wide and tables 256
-  pages wide (4,096 positions);
+  pages wide (4,096 positions), and the grouped-product kernel at a chunk's
+  1,024 assignments over 64 experts of 2,048 x 1,536;
 - multichip: with >= 4 chips, data-parallel ResNet-50, the all-reduce probe,
   ring attention and tensor-parallel serving, each with its arrays checked
   to sit on four distinct devices.  On fewer chips: ``skipped: N device``.
@@ -105,7 +106,12 @@ FULL = {
              "requests": [(9, 24), (40, 12), (150, 16), (14, 40), (45, 8),
                           (300, 20), (16, 32), (90, 10)],
              "stream": (12, 16),
-             "kernels": {"heads": (32, 8, 64), "rows": 8, "chunk": 8}},
+             # a chunk's 64 x top-4 = 256 assignments over 16 experts take
+             # the repo's grouped kernel: 3 products in 8 expert layers
+             "chunk_grouped_kernels": 24,
+             # the cell's chunk: (rows, K, N, groups) of a gate product
+             "kernels": {"heads": (32, 8, 64), "rows": 8, "chunk": 8,
+                         "grouped": (1024, 2048, 1536, 64)}},
 }
 
 
@@ -559,13 +565,17 @@ def _decode_sweep(engine):
             "grid_steps_a_layer": slots}
 
 
-def _expect_engine_mosaic(engine, chunk, layers):
+def _expect_engine_mosaic(engine, chunk, layers, chunk_grouped=0):
     """Mosaic calls in the lowered serving programs: the pool writer
     (``paged_write``) ONCE, a function of its shapes that every layer
     calls, and in every layer the decode kernel in the decode program and
     the chunk kernel in the prefill-chunk program; a whole-prompt prefill
-    attends densely and only writes."""
-    want = {"decode": layers + 1, "prefill_chunk": layers + 1, "prefill": 1}
+    attends densely and only writes.  ``chunk_grouped``: the expert
+    layers' grouped products in the prefill-chunk program, where its rows
+    take the repo's kernel (``ops/grouped_matmul.py``; XLA's own for
+    ``ragged_dot`` come after this lowering)."""
+    want = {"decode": layers + 1,
+            "prefill_chunk": layers + 1 + chunk_grouped, "prefill": 1}
     for name, (prog, args) in _engine_programs(engine, chunk).items():
         expect_mosaic(f"serving {name} program", prog, args, want[name])
     return want
@@ -700,7 +710,9 @@ def phase_serve(cfg, kv_dtype, bf16=True, mesh=None, replica=None,
         if restarts or faults:
             raise AssertionError(f"{restarts} engine restarts, {faults} "
                                  "numeric faults")
-        mosaic = _expect_engine_mosaic(engine, cfg["chunk"], layers)
+        mosaic = _expect_engine_mosaic(
+            engine, cfg["chunk"], layers,
+            cfg.get("chunk_grouped_kernels", 0))
         sweep = _decode_sweep(engine)
         log(f"  decode sweep: {sweep}")
         in_place = _expect_pools_in_place(engine, cfg["chunk"])
@@ -783,6 +795,36 @@ def _check_grouped_lanes(cfg, page_size, table_pages, out):
                (kn, vn, *pools), 1, out)
 
 
+def _check_grouped_product(sizes, out):
+    """The grouped product through its seam at a chunk's rows (the shapes
+    choose the repo's kernel there) against a per-group loop in float32:
+    ragged groups, empty ones among them, and rows past the last group,
+    which the kernel zeroes."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    gm = importlib.import_module("paddle_tpu.ops.grouped_matmul")
+    rs = np.random.RandomState(SEED)
+    M, K, N, G = sizes
+    counts = rs.multinomial(M - M // 8, rs.dirichlet(np.full(G, 0.5)))
+    x = jnp.asarray(rs.randn(M, K), jnp.bfloat16)
+    w = jnp.asarray(rs.randn(G, K, N) * 0.02, jnp.bfloat16)
+    ends = np.cumsum(counts)
+
+    def loop(x, w, counts):
+        x, rows = x.astype(jnp.float32), jnp.arange(M)[:, None]
+        y = jnp.zeros((M, N), jnp.float32)
+        for g in range(G):
+            mine = (rows >= ends[g] - counts[g]) & (rows < ends[g])
+            y = y + jnp.where(mine, x, 0.0) @ w[g].astype(jnp.float32)
+        return y
+
+    _check(f"grouped_matmul/M{M}_K{K}_N{N}_G{G}", jax.jit(gm.grouped_matmul),
+           loop, (x, w, jnp.asarray(counts, jnp.int32)), 1, out)
+
+
 def _greedy_gap(model, prompt, tokens):
     """How far below the model's own best logit the served tokens lie, over
     the spread of the logits, in the model's dense forward (no cache) of
@@ -810,6 +852,7 @@ def phase_serve_lfm2(cfg):
     _check_grouped_lanes(cfg["kernels"], cfg["page_size"],
                          -(-cfg["max_model_len"] // cfg["page_size"]),
                          kernels)
+    _check_grouped_product(cfg["kernels"]["grouped"], kernels)
     built = []
 
     def build():

@@ -14,8 +14,9 @@ MXU wants.
 ``DroplessMoELayer`` is the other formulation (DeepSeek-V3, arXiv:2412.19437
 section 2.1.2): sigmoid scores, top-k with a selection bias, no capacity and
 no dropped token.  Tokens are sorted into expert order and the experts are
-grouped products (``jax.lax.ragged_dot``) over the rows each one really got;
-no ``[G, E, C]`` tensor exists.  The layer is told which experts it holds
+grouped products (``ops.grouped_matmul``: XLA's ``ragged_dot`` or the repo's
+kernel, by the shapes) over the rows each one really got; no ``[G, E, C]``
+tensor exists.  The layer is told which experts it holds
 (``experts_held``, ``expert_offset``): the router scores all of them, the
 layer computes the part of the sum its own experts give.
 """
@@ -30,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ....nn import functional as F  # noqa: F401 (activation lookup)
 from ....nn.layer import Layer
+from ....ops.grouped_matmul import grouped_matmul
 from ....profiler import metrics as _metrics
 from ....tensor.dispatch import apply as _apply, unwrap
 from ....tensor.tensor import Tensor
@@ -264,9 +266,9 @@ def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
             return jnp.where(exists, rows_, jnp.zeros((), rows_.dtype))
 
         xs = real(xs)
-        h = real(jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, counts))
-                 * jax.lax.ragged_dot(xs, w_up, counts))
-        ys = real(jax.lax.ragged_dot(h, w_down, counts))
+        h = real(jax.nn.silu(grouped_matmul(xs, w_gate, counts))
+                 * grouped_matmul(xs, w_up, counts))
+        ys = real(grouped_matmul(h, w_down, counts))
     with jax.named_scope("moe_route"):
         per_choice = _collect_rows(ys, order, inv).reshape(T, top_k, H)
         y = jnp.einsum("tkh,tk->th", per_choice.astype(jnp.float32),

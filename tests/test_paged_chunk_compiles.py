@@ -9,6 +9,7 @@ TPU's library at a time, and pytest-xdist hands a file to one worker."""
 
 import importlib
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 pa = importlib.import_module("paddle_tpu.ops.paged_attention")
+gm = importlib.import_module("paddle_tpu.ops.grouped_matmul")
 
 
 @pytest.fixture(scope="module")
@@ -204,25 +206,58 @@ def test_flash_kernels_compile_at_latent_attentions_head_sizes(one_chip):
     assert backward.as_text().count("tpu_custom_call") == 2
 
 
-def test_grouped_products_compile_at_published_widths(one_chip):
-    """``jax.lax.ragged_dot`` over 16 held experts of 2,048 x 768 and a
-    buffer as long as all 8,192 x 6 assignments, forward and both
-    gradients: the TPU's compiler gives each a grouped kernel of its own
-    (no dense product per group)."""
+def test_grouped_products_compile_at_published_widths(monkeypatch, one_chip):
+    """The seam over 16 held experts of 2,048 x 768 and a buffer as long as
+    all 8,192 x 6 assignments (3,072 rows a group by shape: the shapes keep
+    ``jax.lax.ragged_dot``), forward and both gradients: the TPU's compiler
+    gives each a grouped kernel of its own (no dense product per group)."""
     M, K, N, G = 8192 * 6, 2048, 768, 16
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def loss(x, w, sizes):
-        return jnp.sum(jax.lax.ragged_dot(x, w, sizes).astype(jnp.float32))
+        return jnp.sum(gm.grouped_matmul(x, w, sizes).astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         sds((M, K), jnp.bfloat16), sds((G, K, N), jnp.bfloat16),
         sds((G,), jnp.int32)).compile()
     text = compiled.as_text()
     assert text.count('op_name="ragged-dot') + text.count("%ragged-dot") >= 2
+    assert "grouped_matmul/pallas_call" not in text
     assert compiled.cost_analysis()["flops"] < 4 * 2 * M * K * N
+
+
+@pytest.mark.parametrize("rows", [1024, 832])
+@pytest.mark.parametrize("K,N", [(2048, 1536), (1536, 2048)])
+def test_grouped_kernel_compiles_at_the_hybrid_cells_widths(
+        monkeypatch, one_chip, rows, K, N):
+    """The seam at a chunk's 1,024 and a 13-page prompt's 832 assignments
+    over 64 experts of 2,048 x 1,536, gate / up and down: ONE Mosaic call,
+    the repo's, all the rows a tile and all of a group's columns a block;
+    under differentiation the same forward and ``ragged_dot``'s kernels
+    behind it."""
+    G = 64
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds((rows, K), jnp.bfloat16), sds((G, K, N), jnp.bfloat16),
+            sds((G,), jnp.int32))
+    assert gm._blocking(*args[:2]) == (rows, N)
+    text = jax.jit(gm.grouped_matmul).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "grouped_matmul/pallas_call" in text and "ragged-dot" not in text
+
+    def loss(x, w, sizes):
+        return jnp.sum(gm.grouped_matmul(x, w, sizes).astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        *args).compile().as_text()
+    assert "jvp(grouped_matmul)/pallas_call" in text
+    assert text.count('op_name="ragged-dot') + text.count("%ragged-dot") >= 2
 
 
 @pytest.mark.parametrize("closure", ["step", "prefill_chunk", "prefill"])
@@ -292,9 +327,23 @@ def test_hybrid_programs_fit_the_chip_at_the_cells_sizes(monkeypatch,
     assert chip_smoke.pool_sized_instructions(
         text, [p.shape for p in pools[:2]]) == []
     # two attention layers: a writer and an attend kernel each; the rest
-    # are XLA's own Mosaic calls for the 8 x 3 grouped products
+    # are the 8 x 3 grouped products: the decode step's 128 rows stay XLA's
+    # own Mosaic calls (with their 8 offsets kernels the
+    # ``costs/lfm2.grouped_kernels`` that chipbench/decode_scope.py counts
+    # by that name), a chunk's 1,024 and a prompt's 832-1,024 rows go
+    # through the repo's kernel, which keeps its name stack
     assert text.count("gqa_attention") >= 4
-    assert text.count('op_name="ragged-dot-none"') == 24
+    ragged = text.count('op_name="ragged-dot-none"')
+    tiled = len(re.findall(
+        r'custom-call\(.*op_name="[^"]*/moe_experts/grouped_matmul/', text))
+    costs = spec.load_module("costs", "lfm2")
+    if closure == "step":
+        assert (ragged, tiled) == (24, 0)
+        assert ragged + text.count('op_name="ragged-dot-metadata"') \
+            == costs.grouped_kernels(cfg)
+    else:
+        assert (ragged, tiled) == (0, 24)
+        assert "ragged-dot" not in text
     assert mem.temp_size_in_bytes < 100e6
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes) < 12.5e9

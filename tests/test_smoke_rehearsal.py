@@ -37,7 +37,8 @@ TOY = {
              "num_slots": 2, "page_size": 8, "chunk": 16,
              "max_model_len": 64,
              "requests": [(5, 6), (20, 4), (40, 5)], "stream": (7, 4),
-             "kernels": {"heads": (4, 2, 64), "rows": 4, "chunk": 3}},
+             "kernels": {"heads": (4, 2, 64), "rows": 4, "chunk": 3,
+                         "grouped": (256, 64, 128, 8)}},
 }
 
 
@@ -129,3 +130,34 @@ def test_served_programs_lower_to_mosaic(monkeypatch):
         chip_smoke._expect_engine_mosaic(engine("lower-ref"), 16, 2)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     chip_smoke._expect_engine_mosaic(engine("lower-tpu"), 16, 2)
+
+
+def test_hybrid_chunk_program_lowers_to_the_grouped_kernel(monkeypatch):
+    """A served hybrid's programs, lowered for the TPU from here: a chunk of
+    128 tokens x top-2 = 256 assignments over 4 experts takes the repo's
+    grouped kernel (3 products an expert layer, each a Mosaic call in the
+    lowering), the decode step's and a 8-token prompt's few rows stay
+    ``ragged_dot`` (kernels only in the TPU's compiler, after this
+    lowering); ``moe.grouped_products_traced`` says so by label."""
+    from paddle_tpu.profiler import metrics
+    from paddle_tpu.serving import ServingEngine
+    from paddle_tpu.text.models import Lfm2MoeForCausalLM
+
+    def traced():
+        counter = metrics.counter("moe.grouped_products_traced")
+        return [counter.get(kernel=k) or 0 for k in ("tiled", "ragged_dot")]
+
+    model = Lfm2MoeForCausalLM(**dict(TOY["lfm2"]["model"],
+                                      max_position_embeddings=256)).eval()
+    expert_layers = sum(layer.sparse for layer in model.model.layers)
+    assert expert_layers == 2
+    engine = ServingEngine(model, num_slots=2, page_size=8,
+                           prefill_chunk_tokens=128, max_model_len=256,
+                           numeric_guard=True, replica="lower-lfm2")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = traced()
+    chip_smoke._expect_engine_mosaic(
+        engine, 128, model.model.num_attention_layers,
+        chunk_grouped=3 * expert_layers)
+    assert [b - a for a, b in zip(before, traced())] \
+        == [3 * expert_layers, 2 * 3 * expert_layers]
