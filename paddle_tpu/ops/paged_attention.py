@@ -17,7 +17,10 @@ identity page table and every length equal.
 Layout:
     pools      (k, v) each [L, P, page_size, HKV, Dp]: every layer's pages
                stacked in one array, shared by all sequences; a layer is an
-               index into it.  ``Dp`` is the head size in whole lanes on
+               index into it: a Python int, or a traced int32 scalar (a
+               decoder that runs its layers several times reaches row
+               ``step * layers + layer`` from inside a traced loop).  Either
+               way it rides the kernels as one more prefetched scalar.  ``Dp`` is the head size in whole lanes on
                the TPU (:func:`pool_lane_dim`).  The int8 cache adds the
                scale pools (ks, vs) [L, P, page_size, HKV] f32 to the tuple.
                (The entries also take ONE layer's [P, page_size, ...] with
@@ -104,7 +107,7 @@ def _flash_sharded(pallas_fn, q, pools, scales, page_table, seq_lens,
     [B, h, d] or the chunk [B, C, h, d]; ``pools`` are the stacked
     [L, P, ps, h, d] payload arrays, ``scales`` the optional [L, P, ps, h]
     scale pools (quantized path); every shard reads layer ``layer`` of its
-    own heads."""
+    own heads (the layer replicates with the table and the lengths)."""
     from jax.sharding import PartitionSpec as P
 
     mesh, ax = _MP_SCOPE[0]
@@ -112,16 +115,16 @@ def _flash_sharded(pallas_fn, q, pools, scales, page_table, seq_lens,
     pool_spec = P(None, None, None, ax, None)
     scale_spec = P(None, None, None, ax)
     in_specs = (q_spec,) + (pool_spec,) * len(pools) \
-        + (scale_spec,) * len(scales) + (P(), P())
+        + (scale_spec,) * len(scales) + (P(), P(), P())
 
     def local(q_, *rest):
         kv = rest[:len(pools) + len(scales)]
-        table_, lens_ = rest[-2:]
-        return pallas_fn(q_, *kv, table_, lens_, scale, interpret, layer)
+        table_, lens_, layer_ = rest[-3:]
+        return pallas_fn(q_, *kv, table_, lens_, scale, interpret, layer_)
 
     f = jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=q_spec,
                       check_vma=False)
-    return f(q, *pools, *scales, page_table, seq_lens)
+    return f(q, *pools, *scales, page_table, seq_lens, _layer_scalar(layer))
 
 
 def pool_lane_dim(head_dim):
@@ -146,11 +149,20 @@ def _to_lanes(x, width):
 
 def _as_stack(pools, layer):
     """``(stacked pools, layer)`` for the kernels, which take the serving
-    engine's stacked ``[L, P, ps, ...]`` pools and a layer: with ``layer``
-    None the pools are ONE layer's ``[P, ps, ...]``, a stack of one."""
+    engine's stacked ``[L, P, ps, ...]`` pools and a layer (an int or a
+    traced scalar, handed on as it is): with ``layer`` None the pools are
+    ONE layer's ``[P, ps, ...]``, a stack of one."""
     if layer is None:
         return tuple(p[None] for p in pools), 0
-    return tuple(pools), int(layer)
+    return tuple(pools), layer
+
+
+def _layer_scalar(layer):
+    """The layer as the ``[1]`` int32 the kernels prefetch beside the page
+    table and the lengths: a Python int becomes a constant, a traced scalar
+    stays one.  ONE form for both, so a program's kernels do not depend on
+    which kind of index its model hands the seam."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def _gather_pages(pool, table, layer):
@@ -193,11 +205,12 @@ def _decode_blocking(q, k_pages, NP):
     return pages, need if need > 16 << 20 else None
 
 
-def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sem, swept, *, layer, page_size,
+def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, k_buf, v_buf, sem, swept, *, page_size,
                          scale, pages, table_pages):
     """Grid (slot b): a slot's whole sweep is one grid step.  ``k_hbm`` /
-    ``v_hbm`` are the pools as they lie in HBM, ``[L, P, ps, HKV, D]``;
+    ``v_hbm`` are the pools as they lie in HBM, ``[L, P, ps, HKV, D]``, of
+    which layer ``layer_ref[0]`` (the third prefetched scalar) is read;
     the scratch: two buffers of ``pages`` pages for each, the DMA
     semaphores ``[buffer, pool]``, and the count of blocks swept so far.
 
@@ -228,6 +241,7 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     b = pl.program_id(0)
     g, HKV, D = q_ref.shape[1:]
+    layer = layer_ref[0]
 
     def sweep(slot):
         """``(length, pages)`` of a slot's sweep: a length that overruns
@@ -320,11 +334,12 @@ def _last_page(seq_len, page_size):
     return jnp.maximum((seq_len + page_size - 1) // page_size - 1, 0)
 
 
-def _paged_page_kernel(pt_ref, lens_ref, q_ref, *refs, page_size, scale,
-                       num_kv_heads, quantized):
+def _paged_page_kernel(pt_ref, lens_ref, layer_ref, q_ref, *refs, page_size,
+                       scale, num_kv_heads, quantized):
     """Grid (slot b, table entry i): the sweep of ONE page a grid step, for
     the pools of which the decode kernel's DMAs take no page
-    (:func:`_decode_blocking`).  ``refs``: the K and V page, (int8 pools:
+    (:func:`_decode_blocking`); the layer is the index maps' business.
+    ``refs``: the K and V page, (int8 pools:
     their ``[ps, HKV]`` scale tiles, the dequantization fused into the
     loads), the output block, m / l / acc.
 
@@ -397,12 +412,12 @@ def _paged_decode_pallas(q, pools, scales, page_table, seq_lens, scale,
                          interpret, layer, name=None):
     """q [B, H, D] against layer ``layer`` of the stacked ``pools`` (K, V:
     [L, P, ps, HKV, D]) and, for int8 pools, ``scales`` (K, V:
-    [L, P, ps, HKV]) -> [B, H, D].  The layer is an index fixed at trace
-    time, so a layer is read where it lies in the pool: the decode kernel
-    fetches its pages from HBM itself; pools whose pages it cannot fetch
-    (:func:`_decode_blocking`) are swept a page a grid step, the page a
-    block whose leading dimension of one, the layer, the kernel does not
-    see."""
+    [L, P, ps, HKV]) -> [B, H, D].  The layer (an int or a traced int32
+    scalar) is the third prefetched scalar, so a layer is read where it
+    lies in the pool: the decode kernel fetches its pages from HBM itself;
+    pools whose pages it cannot fetch (:func:`_decode_blocking`) are swept
+    a page a grid step, the page a block whose leading dimension of one,
+    the layer, the kernel does not see."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -415,14 +430,14 @@ def _paged_decode_pallas(q, pools, scales, page_table, seq_lens, scale,
             # the index map clamps the sweep: steps past the row's last
             # valid page re-present it, and a revisited block is not
             # fetched again
-            def idx(b, i, pt, ln):
-                return (layer, pt[b, jnp.minimum(
+            def idx(b, i, pt, ln, ly):
+                return (ly[0], pt[b, jnp.minimum(
                     i, _last_page(ln[b], page_size))]) + (0,) * (pool.ndim - 2)
             return pl.BlockSpec((None, 1) + pool.shape[2:], idx)
 
         paged = (*pools, *(a.astype(jnp.float32) for a in scales))
         operand, grid = q, (B, NP)
-        q_spec = pl.BlockSpec((1, H, D), lambda b, i, pt, ln: (b, 0, 0))
+        q_spec = pl.BlockSpec((1, H, D), lambda b, i, pt, ln, ly: (b, 0, 0))
         in_specs = [q_spec] + [page_spec(a) for a in paged]
         scratch = [pltpu.VMEM((H, 1), jnp.float32),
                    pltpu.VMEM((H, 1), jnp.float32),
@@ -439,14 +454,15 @@ def _paged_decode_pallas(q, pools, scales, page_table, seq_lens, scale,
         # a few KB around the kernel: query head kv * g + r to row [r, kv]
         operand = jnp.swapaxes(q.reshape(B, HKV, g, D), 1, 2)
         paged, grid = pools, (B,)
-        q_spec = pl.BlockSpec((1, g, HKV, D), lambda b, pt, ln: (b, 0, 0, 0))
+        q_spec = pl.BlockSpec((1, g, HKV, D),
+                              lambda b, pt, ln, ly: (b, 0, 0, 0))
         in_specs = [q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
         scratch = [pltpu.VMEM((2, pages) + a.shape[2:], a.dtype)
                    for a in pools] \
             + [pltpu.SemaphoreType.DMA((2, 2)), pltpu.SMEM((1,), jnp.int32)]
         kernel = functools.partial(
-            _paged_decode_kernel, layer=layer, page_size=page_size,
-            scale=scale, pages=pages, table_pages=NP)
+            _paged_decode_kernel, page_size=page_size, scale=scale,
+            pages=pages, table_pages=NP)
         # sequential: a slot's last block fetches the next slot's first
         semantics = ("arbitrary",)
     # x64 OFF around the call: the framework enables jax_enable_x64 globally
@@ -460,14 +476,14 @@ def _paged_decode_pallas(q, pools, scales, page_table, seq_lens, scale,
             kernel,
             name=name,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+                num_scalar_prefetch=3, grid=grid, in_specs=in_specs,
                 out_specs=q_spec, scratch_shapes=scratch),
             out_shape=jax.ShapeDtypeStruct(operand.shape, jnp.float32),
             interpret=interpret,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=semantics, vmem_limit_bytes=vmem_limit),
-        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), operand,
-          *paged)
+        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+          _layer_scalar(layer), operand, *paged)
     if blocking is not None:
         out = jnp.swapaxes(out, 1, 2).reshape(B, H, D)
     return out.astype(q.dtype)
@@ -593,7 +609,8 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
 # ``layer`` and reads or writes THAT layer of the stacked pool where it lies
 # (``layer=None``: the pool is one layer's [P, ps, h, d]).  Nothing slices a
 # layer out or stacks layers back: the kernels get the layer as a leading
-# block dimension of one at a fixed block index, the dense fall-backs
+# block dimension of one at the block index a prefetched scalar gives (so a
+# layer may be a Python int or a traced scalar alike), the dense fall-backs
 # gather ``pool[layer, table]``, and a write touches the rows it writes.
 # One write mechanism: a chunk of C tokens per slot at the slot's own
 # position (a decode token is a chunk of one, a whole prompt a chunk at
@@ -700,9 +717,11 @@ def _chunk_last_key(seq_len, j, tile, chunk, capacity):
                     0, capacity - 1)
 
 
-def _paged_chunk_kernel(pt_ref, lens_ref, q_ref, *refs, page_size, scale,
-                        num_kv_heads, pages, chunk, table_pages, quantized):
-    """Grid (slot b, query tile j, page step i).  ``refs``: ``pages`` K
+def _paged_chunk_kernel(pt_ref, lens_ref, layer_ref, q_ref, *refs, page_size,
+                        scale, num_kv_heads, pages, chunk, table_pages,
+                        quantized):
+    """Grid (slot b, query tile j, page step i); the layer is the index
+    maps' business.  ``refs``: ``pages`` K
     page refs, as many V, (int8 pools: as many K-scale and V-scale refs),
     the output block, then the scratch: the step's K and V staged
     head-major in f32, and m / l / acc.  K/V are read as stored and
@@ -798,8 +817,9 @@ def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
     """q [B, C, H, D] against layer ``layer`` of the stacked ``pools`` (K,
     V: [L, P, ps, HKV, D]) and, for int8 pools, ``scales`` (K, V:
     [L, P, ps, HKV]) -> [B, C, H, D].  The layer is a block dimension of
-    one that the kernel does not see, at a block index fixed at trace
-    time, so a layer is read where it lies in the pool."""
+    one that the kernel does not see, at the block index the third
+    prefetched scalar gives (an int or a traced int32 scalar), so a layer
+    is read where it lies in the pool."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -811,18 +831,18 @@ def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
                        (0, 2, 3, 1))                       # [B, H, D, Cp]
 
     def page_map(r, rank):
-        def idx(b, j, i, pt, ln):
+        def idx(b, j, i, pt, ln, ly):
             last = _chunk_last_key(ln[b], j, tile, C, NP * page_size)
-            return (layer,
+            return (ly[0],
                     pt[b, jnp.minimum(i * pages + r, last // page_size)]) \
                 + (0,) * (rank - 2)
         return idx
 
     paged = (*pools, *(a.astype(jnp.float32) for a in scales))
     q_spec = pl.BlockSpec((1, H, D, tile),
-                          lambda b, j, i, pt, ln: (b, 0, 0, j))
+                          lambda b, j, i, pt, ln, ly: (b, 0, 0, j))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, Cp // tile, -(-NP // pages)),
         in_specs=[q_spec] + [
             pl.BlockSpec((None, 1) + a.shape[2:], page_map(r, a.ndim))
@@ -852,7 +872,8 @@ def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
                 vmem_limit_bytes=vmem_limit),
-        )(table.astype(jnp.int32), lens.astype(jnp.int32), qt,
+        )(table.astype(jnp.int32), lens.astype(jnp.int32),
+          _layer_scalar(layer), qt,
           *(a for a in paged for _ in range(pages)))
     return jnp.transpose(out, (0, 3, 1, 2))[:, :C].astype(q.dtype)
 
@@ -1104,7 +1125,7 @@ def _paged_write_pallas(pools, rows, table, lens, interpret, layer):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
         )(table.astype(jnp.int32), lens.astype(jnp.int32),
-          jnp.asarray(layer, jnp.int32).reshape(1), *padded, *pools))
+          _layer_scalar(layer), *padded, *pools))
 
 
 def _write_sharded(pools, rows, table, lens, layer):
@@ -1122,14 +1143,14 @@ def _write_sharded(pools, rows, table, lens, layer):
     pool_specs = tuple(heads_at(p, 3) for p in pools)
 
     def local(*a):
-        return _paged_write_pallas(a[:n], a[n:2 * n], a[-2], a[-1], False,
-                                   layer)
+        return _paged_write_pallas(a[:n], a[n:2 * n], a[-3], a[-2], False,
+                                   a[-1])
 
     f = jax.shard_map(
         local, mesh=mesh, out_specs=pool_specs, check_vma=False,
         in_specs=pool_specs + tuple(heads_at(x, 2) for x in rows)
-        + (P(), P()))
-    return f(*pools, *rows, table, lens)
+        + (P(), P(), P()))
+    return f(*pools, *rows, table, lens, _layer_scalar(layer))
 
 
 def _pool_write(pools, rows, table, lens, layer):
@@ -1248,7 +1269,9 @@ def paged_cache_attend(q, k, v, cache, prefill_attend):
     are to be stored, e.g. already rotated); ``cache`` is ``(tag, layer,
     pools, table, lens)``: the stacked pool tuple (``(kp, vp)``, or the
     int8 cache's ``(kp, vp, ks, vs)``: K/V are quantized on the way in and
-    dequantized in the attention), this layer's index into it, the page
+    dequantized in the attention), this layer's index into it (a Python
+    int, or a traced int32 scalar: ``step * layers + layer`` inside the
+    traced loop of a decoder that runs its layers several times), the page
     table ``[B, NP]`` and every slot's length BEFORE this chunk ``[B]``.
     The chunk lands at positions ``lens[b] .. lens[b]+C-1`` and position t
     attends keys ``0 .. lens[b]+t``, its own included.  ``tag``:

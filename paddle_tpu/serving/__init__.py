@@ -52,7 +52,7 @@ speculative proposal/acceptance, prefix-cache hit/miss/eviction/saved
 tokens, KV-spill pages/resurrections/drops/bytes.
 """
 
-from .adapter import GPTAdapter, SlotStateAdapter  # noqa: F401
+from .adapter import GPTAdapter, StatedCacheAdapter  # noqa: F401
 from .api import ContinuousBatchingPredictor  # noqa: F401
 from .block_manager import BlockManager, PageAllocation  # noqa: F401
 from .prefix_index import RadixPrefixIndex, prefix_digest  # noqa: F401
@@ -82,7 +82,7 @@ __all__ = [
     "ServingEngine", "Request", "RequestHandle", "RequestRejectedError",
     "EngineStoppedError", "SamplingParams", "BlockManager", "PageAllocation",
     "RadixPrefixIndex", "KVSpillTier", "prefix_digest",
-    "GPTAdapter", "SlotStateAdapter", "ContinuousBatchingPredictor", "NgramDrafter",
+    "GPTAdapter", "StatedCacheAdapter", "ContinuousBatchingPredictor", "NgramDrafter",
     "make_verifier", "ServingCluster", "ClusterHandle", "ReplicaPool",
     "PrefixAffinityRouter", "RouteDecision", "SLOPolicy",
     "QuantizedGPTAdapter", "quantize_model_weights", "calibrate",

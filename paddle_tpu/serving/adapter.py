@@ -14,10 +14,13 @@ one scheduler serves every pool layout.
 The pools are served IN PLACE.  A closure hands the model ONE cache,
 ``(tag, pools, table, lens)``, and every decoder layer reads and writes
 the stacked pools at its own index (``ops.paged_attention``: the kernels'
-block specs take the layer as a leading block dimension of one, the writer
-merges a chunk's rows into the pages it touches through aliased operands)
-and hands the tuple to the next: no program slices a layer out of a pool
-or stacks layers back.  On the TPU a payload pool's rows are as wide as
+block specs take the layer as a leading block dimension of one whose block
+index is a prefetched scalar, so the index may be a Python int or a traced
+one: a decoder that runs its layers several times reaches row ``step *
+layers + layer`` from inside its traced loop; the writer merges a chunk's
+rows into the pages it touches through aliased operands) and hands the
+tuple to the next: no program slices a layer out of a pool or stacks layers
+back.  On the TPU a payload pool's rows are as wide as
 the chip's lanes (``ops.paged_attention.pool_lane_dim``: a ``d`` of 64 is
 stored 128 wide, zeros behind it), which is how the device holds a
 ``[ps, h, d]`` page for the kernels anyway; said in the SHAPE, the
@@ -49,15 +52,18 @@ prefill/step return ``(logits [B, V] f32, *pools)``, verify
 ``(logits [B, C, V] f32, *pools)``, with each pool a per-layer-stacked
 ``[L, P, ...]`` array.
 
-A SECOND KIND OF CACHE may ride in the pool tuple: :class:`SlotStateAdapter`
-(a hybrid decoder: attention layers beside layers that carry a fixed-size
-recurrent state) hands the engine ``(kp, vp, state)``, the pools over the
-attention layers only and ``state [L_state, slots + 1, ...]``, one row a
-slot, that lives and dies with the slot and not with pages.  Its decode
-rows are the slots; its one-request programs (``prefill``,
+A decoder that is not ``.gpt`` STATES ITS CACHES (``model.serving_caches()``)
+and is served by :class:`StatedCacheAdapter`: pages for as many cache rows
+as it names (the attention layers of a hybrid; ``steps x layers`` of a
+decoder that runs its layers several times over one set of weights), and,
+where it names a ``state_shape``, A SECOND KIND OF CACHE in the pool tuple:
+``(kp, vp, state)`` with ``state [L_state, slots + 1, ...]``, one row a
+slot, that lives and dies with the slot and not with pages.  With a state
+the decode rows are the slots and the one-request programs (``prefill``,
 ``prefill_chunk``) take the slot's index as one more int32 operand behind
 ``lens``, which the engine appends (``ServingEngine._prefill_extra``) for an
-adapter that says ``slot_state``.
+adapter that says ``slot_state``; without one the tuple is ``(kp, vp)`` and
+the programs take what :class:`GPTAdapter`'s take.
 """
 
 from __future__ import annotations
@@ -94,8 +100,9 @@ class PagedAdapter:
 
     def _set_geometry(self, model, page_size, num_layers, num_kv_heads,
                       head_dim, dtype, max_model_len):
-        """``num_layers`` counts the layers that HOLD pages (all of a
-        ``.gpt`` decoder, the attention layers of a hybrid)."""
+        """``num_layers`` counts the cache rows, the layers that HOLD pages
+        (all of a ``.gpt`` decoder, the attention layers of a hybrid, steps
+        x layers of a looped decoder): not the layers that hold weights."""
         self.model = model
         self.page_size = int(page_size)
         self.num_layers = int(num_layers)
@@ -379,31 +386,36 @@ class GPTAdapter(PagedAdapter):
         return (logits,) + pools
 
 
-class SlotStateAdapter(PagedAdapter):
-    """Adapter for a hybrid decoder that is not ``.gpt``: rotary positions
-    (no position table: the model states the cap on ``max_model_len``),
-    grouped KV heads in SOME layers and a fixed-size recurrent state in the
-    others (:class:`paddle_tpu.text.models.Lfm2MoeForCausalLM`: gated short
-    convolutions).  The engine builds it for a model that states its
-    caches (``model.serving_caches``) and takes no flag.
+class StatedCacheAdapter(PagedAdapter):
+    """Adapter for a decoder that is not ``.gpt`` and states its caches:
+    rotary positions (no position table: the model states the cap on
+    ``max_model_len``), pages for the cache rows it names, and, for a
+    hybrid, a fixed-size recurrent state a slot beside them
+    (:class:`paddle_tpu.text.models.Lfm2MoeForCausalLM`: grouped KV heads
+    in SOME layers, gated short convolutions in the others;
+    :class:`paddle_tpu.text.models.OuroForCausalLM`: pages only, one row a
+    (step, layer) of a stack that runs several times).  The engine builds
+    it for a model that has ``serving_caches`` and takes no flag.
 
     What it asks of the model, and all it reads of it:
 
     - ``model.serving_caches()``: ``{"attention_layers", "kv_heads",
-      "head_dim"}`` (the pages), ``"state_shape"`` (``(layers, rows,
-      width)`` of the state ONE sequence carries), ``"max_positions"`` and
-      ``"dtype"``;
+      "head_dim"}`` (the pages: ``attention_layers`` counts CACHE ROWS),
+      ``"max_positions"`` and ``"dtype"``; optionally ``"state_shape"``
+      (``(layers, rows, width)`` of the state ONE sequence carries) and
+      ``"loop_steps"`` (how often the stack runs: a fact for the
+      signature, the rows are counted already);
     - ``model.model`` is the decoder, called as ``decoder(ids, position_ids,
-      cache=(tag, (kp, vp), table, lens), conv_state=[L_state, B, R, W],
-      valid=[B])`` and returning ``(hidden, (kp, vp), state after the
-      call)``;
+      cache=(tag, (kp, vp), table, lens))`` and returning ``(hidden, (kp,
+      vp))``; with a state also ``conv_state=[L_state, B, R, W],
+      valid=[B]``, returning the state after the call third;
     - ``model.head_weight()`` is the head as ``[V, H]``.
 
-    The pool tuple is ``(kp, vp, state)``: ``kp`` / ``vp`` ``[L_attn, P, ps,
-    hkv, d]`` indexed by an attention layer's rank among the attention
-    layers, ``state [L_state, slots + 1, R, W]`` by a state layer's rank and
-    the slot; row ``slots`` is the scratch row of idle lanes.  The state's
-    rules (each pinned in ``tests/test_lfm2.py``):
+    The pool tuple is ``(kp, vp)`` or ``(kp, vp, state)``: ``kp`` / ``vp``
+    ``[rows, P, ps, hkv, d]`` indexed by a cache row, ``state [L_state,
+    slots + 1, R, W]`` by a state layer's rank and the slot; row ``slots``
+    is the scratch row of idle lanes.  The state's rules (each pinned in
+    ``tests/test_lfm2.py``):
 
     - a chunk that starts at position 0 (``lens[b] == 0``) and a monolithic
       prefill enter with ZERO state, whatever the slot's last tenant left;
@@ -415,11 +427,10 @@ class SlotStateAdapter(PagedAdapter):
     - nothing resets it on the host: preemption re-prefills.
 
     Its closures have :class:`GPTAdapter`'s shape and a text of their own
-    (the state enters and leaves beside the pools): ROADMAP, Design 2.
+    (positions are rotary, the head is the model's, a state may enter and
+    leave beside the pools): ROADMAP, Design 2.  ``verify`` and ``encode``
+    exist for :class:`GPTAdapter` only (ROADMAP R7).
     """
-
-    n_pools = 3
-    slot_state = True
 
     def __init__(self, model, page_size, num_slots):
         need = model.serving_caches()
@@ -428,44 +439,64 @@ class SlotStateAdapter(PagedAdapter):
             model, page_size, num_layers=need["attention_layers"],
             num_kv_heads=need["kv_heads"], head_dim=need["head_dim"],
             dtype=need["dtype"], max_model_len=need["max_positions"])
-        #: (layers, rows, width) of the state one sequence carries
-        self.state_shape = tuple(int(n) for n in need["state_shape"])
+        #: (layers, rows, width) of the state one sequence carries, or None
+        shape = need.get("state_shape")
+        self.state_shape = None if shape is None \
+            else tuple(int(n) for n in shape)
+        self.slot_state = self.state_shape is not None
+        self.n_pools = 3 if self.slot_state else 2
+        #: how often the decoder runs its layers (1: once)
+        self.loop_steps = int(need.get("loop_steps", 1))
         self.num_slots = int(num_slots)
 
     def signature(self):
-        return dict(super().signature(),
-                    state_shape=list(self.state_shape),
-                    num_slots=int(self.num_slots))
+        sig = dict(super().signature(), cache_layers=int(self.num_layers),
+                   loop_steps=int(self.loop_steps))
+        if self.slot_state:
+            sig.update(state_shape=list(self.state_shape),
+                       num_slots=int(self.num_slots))
+        return sig
 
     # ----------------------------------------------------------- pool hooks
     def init_pools(self, num_pages):
-        """``(kp, vp, state)``: pages over the attention layers only, and
-        the zeroed per-slot state with its scratch row."""
+        """``(kp, vp)`` over the cache rows, and with a state ``(kp, vp,
+        state)``: the zeroed per-slot state with its scratch row."""
         kp = self._page_pool(num_pages)
+        if not self.slot_state:
+            return kp, jnp.zeros_like(kp)
         layers, rows, width = self.state_shape
         state = jnp.zeros((layers, self.num_slots + 1, rows, width),
                           self.dtype)
         return kp, jnp.zeros_like(kp), state
 
     def pool_owners(self):
+        if not self.slot_state:
+            return super().pool_owners()
         return (("kv.pages", (0, 1)), ("state.slots", (2,)))
 
     def state_bytes_per_slot(self):
+        if not self.slot_state:
+            return 0
         layers, rows, width = self.state_shape
         return layers * rows * width * jnp.dtype(self.dtype).itemsize
 
     # ------------------------------------------------------------- closures
     def _split_extra(self, args):
         """``(*pools, table, lens[, slot])`` -> ``((kp, vp), state, table,
-        lens, slot)``.  The decode program's rows are the slots already; a
-        lane that holds nothing (``lens == 0``) goes to the scratch row."""
+        lens, slot)``; ``state`` and ``slot`` are None without a state.
+        With one, the decode program's rows are the slots already; a lane
+        that holds nothing (``lens == 0``) goes to the scratch row."""
         n = self.n_pools
-        if len(args) not in (n + 2, n + 3):
+        takes = (n + 2, n + 3) if self.slot_state else (n + 2,)
+        if len(args) not in takes:
             raise TypeError(
                 f"{type(self).__name__} closures take {n} pool arrays + "
-                f"table + lens (+ the slot's index); got {len(args)} "
-                f"trailing args")
+                f"table + lens" + (" (+ the slot's index)"
+                                   if self.slot_state else "")
+                + f"; got {len(args)} trailing args")
         table, lens = args[n], args[n + 1]
+        if not self.slot_state:
+            return tuple(args[:2]), None, table, lens, None
         if len(args) == n + 3:
             slot = args[n + 2].astype(jnp.int32)
         else:
@@ -476,42 +507,53 @@ class SlotStateAdapter(PagedAdapter):
 
     def _run(self, params, bufs, ids, kv, entering, valid, table, lens,
              pos_ids, tag):
+        """``(hidden, head [V, H], (kp, vp), the state after the call or
+        None)``."""
         from ..framework import random as _rng
         from ..framework.state import no_grad_ctx
         from ..tensor.tensor import Tensor
 
+        beside = {} if entering is None else {
+            "conv_state": Tensor(entering),
+            "valid": None if valid is None else Tensor(valid)}
         with no_grad_ctx(), _rng.rng_scope(jax.random.key(0)), \
                 self.model.bind(params, bufs):
-            x, kv, after = self.decoder(
+            x, kv, *after = self.decoder(
                 Tensor(ids), position_ids=Tensor(pos_ids),
                 cache=(tag, tuple(Tensor(p) for p in kv), Tensor(table),
-                       Tensor(lens)),
-                conv_state=Tensor(entering),
-                valid=None if valid is None else Tensor(valid))
+                       Tensor(lens)), **beside)
             w = self.model.head_weight()._value
-            return x._value, w, tuple(p._value for p in kv), after._value
+            return x._value, w, tuple(p._value for p in kv), \
+                (after[0]._value if after else None)
 
     @staticmethod
     def _logits(h, w):
         return h.astype(jnp.float32) @ w.T.astype(jnp.float32)
 
+    @staticmethod
+    def _leaving(kv, state, slot, after):
+        """The pool tuple a closure hands back: the pages, and the state
+        with the rows of ``slot`` as the call left them."""
+        return kv if state is None else kv + (state.at[:, slot].set(after),)
+
     def prefill(self, params, bufs, ids, *args):
         kv, state, table, lens, slot = self._split_extra(args)
         pos_ids = jnp.arange(ids.shape[1], dtype=jnp.int64)[None, :]
-        fresh = jnp.zeros_like(state[:, slot])
+        fresh = None if state is None else jnp.zeros_like(state[:, slot])
         x, w, kv, after = self._run(params, bufs, ids, kv, fresh, lens,
                                     table, lens, pos_ids, self.tag)
         idx = (lens.astype(jnp.int32) - 1)[:, None, None]
         h = jnp.take_along_axis(x, idx, axis=1)[:, 0]
-        return (self._logits(h, w),) + kv + (state.at[:, slot].set(after),)
+        return (self._logits(h, w),) + self._leaving(kv, state, slot, after)
 
     def step(self, params, bufs, last, *args):
         kv, state, table, lens, slot = self._split_extra(args)
         pos_ids = lens[:, None].astype(jnp.int64)
-        x, w, kv, after = self._run(params, bufs, last, kv, state[:, slot],
-                                    None, table, lens, pos_ids, self.tag)
-        return (self._logits(x[:, -1], w),) + kv \
-            + (state.at[:, slot].set(after),)
+        entering = None if state is None else state[:, slot]
+        x, w, kv, after = self._run(params, bufs, last, kv, entering, None,
+                                    table, lens, pos_ids, self.tag)
+        return (self._logits(x[:, -1], w),) \
+            + self._leaving(kv, state, slot, after)
 
     def prefill_chunk(self, params, bufs, ids, nvalid, *args):
         kv, state, table, lens, slot = self._split_extra(args)
@@ -521,10 +563,11 @@ class SlotStateAdapter(PagedAdapter):
         pos_ids = jnp.minimum(pos_ids, self.max_model_len - 1)
         # a slot's first chunk starts a sequence: zero state, whatever the
         # last tenant left in the row
-        entering = jnp.where((lens > 0)[None, :, None, None],
-                             state[:, slot], jnp.zeros((), state.dtype))
+        entering = None if state is None else jnp.where(
+            (lens > 0)[None, :, None, None], state[:, slot],
+            jnp.zeros((), state.dtype))
         x, w, kv, after = self._run(params, bufs, ids, kv, entering, nvalid,
                                     table, lens, pos_ids, self.chunk_tag)
         idx = jnp.maximum(nvalid.astype(jnp.int32) - 1, 0)[:, None, None]
         h = jnp.take_along_axis(x, idx, axis=1)[:, 0]
-        return (self._logits(h, w),) + kv + (state.at[:, slot].set(after),)
+        return (self._logits(h, w),) + self._leaving(kv, state, slot, after)

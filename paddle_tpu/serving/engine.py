@@ -100,7 +100,7 @@ from ..observability import programs as _programs
 from ..observability import tracing as _tracing
 from ..resilience.retry import (EngineStoppedError, NumericFault,  # noqa: F401 — re-exported
                                 classify_failure)
-from .adapter import GPTAdapter, SlotStateAdapter
+from .adapter import GPTAdapter, StatedCacheAdapter
 from .block_manager import BlockManager
 
 _logger = logging.getLogger("paddle_tpu.serving")
@@ -474,23 +474,29 @@ class ServingEngine:
         if adapter is not None:
             self._adapter = adapter
         elif hasattr(model, "serving_caches"):
-            self._adapter = SlotStateAdapter(model, int(page_size),
-                                             int(num_slots))
+            self._adapter = StatedCacheAdapter(model, int(page_size),
+                                               int(num_slots))
         elif kv_dtype == "int8":
             from .quant.adapter import QuantizedGPTAdapter
 
             self._adapter = QuantizedGPTAdapter(model, page_size)
         else:
             self._adapter = GPTAdapter(model, page_size)
-        # per-slot state beside the pages (adapter.SlotStateAdapter): the
+        # per-slot state beside the pages (adapter.StatedCacheAdapter): the
         # one-request programs get the slot's index, and the mechanisms
-        # that move or share pages without the state are refused by name
+        # that move or share pages without the state are refused by name.
+        # A decoder that states pages alone shares, spills and re-reads
+        # them like any other; what it is refused is what its adapter
+        # lacks, by name
         self._slot_state = bool(getattr(self._adapter, "slot_state", False))
         if self._slot_state:
             self._refuse_with_slot_state(
                 prefix_sharing=prefix_sharing, prefix_cache=prefix_cache,
                 kv_spill=kv_spill, speculative_k=speculative_k,
                 kv_dtype=kv_dtype, mesh=mesh)
+        self._refuse_what_the_adapter_lacks(
+            self._adapter, speculative_k=speculative_k, kv_dtype=kv_dtype,
+            mesh=mesh)
         if self._mp > 1:
             self._adapter.validate_mp(self._mp)
             # the adapter carries the mesh so the TPU flash kernels trace
@@ -846,6 +852,14 @@ class ServingEngine:
             "serving.state_bytes_per_slot",
             "HBM bytes of per-slot state one resident sequence holds beside "
             "its KV pages (0 for a decoder whose only cache is paged)")
+        self._m_cache_layers = _g(
+            "serving.cache_layers",
+            "rows of the KV page pools: the layers that hold pages (steps x "
+            "layers for a decoder that runs its layers several times)")
+        self._m_loop_steps = _g(
+            "serving.loop_steps",
+            "times the decoder runs its layers over one set of weights in "
+            "one forward pass (1 for a plain decoder)")
         self._set_pool_gauges()
         # memory observability (observability/memory.py): every long-lived
         # device allocation this engine owns registers with the process
@@ -864,7 +878,7 @@ class ServingEngine:
     @staticmethod
     def _refuse_with_slot_state(prefix_sharing, prefix_cache, kv_spill,
                                 speculative_k, kv_dtype, mesh):
-        """A decoder with per-slot state (adapter.SlotStateAdapter) is
+        """A decoder with per-slot state (adapter.StatedCacheAdapter) is
         served on the plain path only: every mechanism that shares, moves
         or re-reads pages WITHOUT the state that belongs to them is
         refused here by name, at construction."""
@@ -889,6 +903,30 @@ class ServingEngine:
             raise ValueError(
                 f"this model is served with per-slot state beside its "
                 f"paged KV; not supported with it: {why}")
+
+    @staticmethod
+    def _refuse_what_the_adapter_lacks(adapter, speculative_k, kv_dtype,
+                                       mesh):
+        """The served paths that exist for :class:`GPTAdapter` only
+        (ROADMAP R7) are refused at construction, each by its name, for an
+        adapter that does not bring them: not for what the model is."""
+        why = None
+        if speculative_k and not hasattr(adapter, "verify"):
+            why = ("speculative_k: verification runs the adapter's `verify` "
+                   "program (logits at every drafted position), which "
+                   f"{type(adapter).__name__} does not have")
+        elif kv_dtype == "int8" \
+                and getattr(adapter, "kv_dtype", None) != "int8":
+            why = ("kv_dtype='int8': the quantized pool tuple is "
+                   "serving.quant.QuantizedGPTAdapter's, written over a "
+                   f"`.gpt` decoder; {type(adapter).__name__} keeps its "
+                   "pages in the model's type")
+        elif mesh is not None and not hasattr(adapter, "param_pspec"):
+            why = ("mesh=: the sharding rules (`validate_mp`, "
+                   "`pool_pspecs`, `param_pspec`) are GPTAdapter's; "
+                   f"{type(adapter).__name__} has none")
+        if why is not None:
+            raise ValueError(f"not supported for this model: {why}")
 
     def _register_memory(self):
         """Register this engine's device allocations with the process
@@ -1035,6 +1073,9 @@ class ServingEngine:
     def _set_pool_gauges(self):
         self._m_kv_bytes_tok.set(self._bytes_per_page / self.page_size)
         self._m_state_bytes_slot.set(float(self._state_bytes_per_slot))
+        self._m_cache_layers.set(float(self._adapter.num_layers))
+        self._m_loop_steps.set(
+            float(getattr(self._adapter, "loop_steps", 1)))
         # one series PER POOL DTYPE: the quantized engine's f32 scale
         # pools are real device residency — folding them into the int8
         # series used to make serving.pool_bytes disagree with what the

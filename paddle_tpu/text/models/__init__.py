@@ -15,3 +15,6 @@ from .deepseek_v3 import (  # noqa: F401
 from .lfm2 import (  # noqa: F401
     Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel,
 )
+from .ouro import (  # noqa: F401
+    OuroConfig, OuroForCausalLM, OuroModel,
+)

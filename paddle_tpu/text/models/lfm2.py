@@ -35,7 +35,7 @@ a time: a 5B-parameter cut is never held in float32, and a caller with its
 own weights hands them over leaf by leaf without a second copy.
 
 Serving: ``forward(..., cache=, conv_state=, valid=)`` is what
-``serving.adapter.SlotStateAdapter`` drives; the model states the caches
+``serving.adapter.StatedCacheAdapter`` drives; the model states the caches
 a server has to hold for it (``serving_caches``), which is how
 ``ServingEngine(model)`` picks that adapter: nothing of ``serving`` is
 imported here.
@@ -129,15 +129,19 @@ def seeded_init(config):
     K ** -0.5)`` (the operator keeps its input's scale), norm gains ones, the experts'
     selection bias zeros (a checkpoint holds a trained one)."""
     dtype = _dt.to_jax(config.dtype)
-    std, taps = float(config.initializer_range), int(config.conv_L_cache)
+    std = float(config.initializer_range)
     root = jax.random.key(int(config.seed) & 0x7FFFFFFF)
 
     def make(name, shape):
-        if name.endswith(("norm.weight", "layernorm.weight")):
+        if name.endswith(("norm.weight", "layernorm.weight",
+                          "layernorm_2.weight")):
             return jnp.ones(shape, dtype)
         if name.endswith("e_score_correction_bias"):
             return jnp.zeros(shape, jnp.float32)
-        scale = taps ** -0.5 if name.endswith("conv_weight") else std
+        if name.endswith(".bias"):
+            return jnp.zeros(shape, dtype)
+        scale = int(config.conv_L_cache) ** -0.5 \
+            if name.endswith("conv_weight") else std
         key = jax.random.fold_in(root, zlib.crc32(name.encode()) & 0x7FFFFFFF)
         return (jax.random.normal(key, shape, jnp.float32)
                 * scale).astype(dtype)
@@ -420,7 +424,7 @@ class Lfm2MoeForCausalLM(Layer):
 
     def serving_caches(self):
         """What a server has to hold for ONE sequence of this decoder, all
-        ``serving.adapter.SlotStateAdapter`` reads of its sizes: pages for
+        ``serving.adapter.StatedCacheAdapter`` reads of its sizes: pages for
         the attention layers' grouped heads, and ``state_shape`` ``(layers,
         rows, width)``, the rows of gate products each convolution layer
         carries.  ``ServingEngine(model)`` picks its adapter by this

@@ -88,37 +88,47 @@ def _reference_init(layer):
             p._value = new
 
 
+def _projections(linear):
+    """``(column, row)``: how a block makes its bias-free projections, each
+    called ``(leaf, d_in, d_out)``.  By default the Megatron column / row
+    classes with their own initializer; a decoder that hands its leaves
+    over one at a time in its served type (``ouro.py``) gives ``linear``,
+    one factory for both."""
+    if linear is not None:
+        return linear, linear
+    # llama uses no biases (bias=False reaches the TP classes too)
+    return (lambda leaf, i, o: _col_linear(i, o, bias=False),
+            lambda leaf, i, o: _row_linear(i, o, bias=False))
+
+
 class LlamaMLP(Layer):
     """SwiGLU: down(silu(gate(x)) * up(x)) — two column-parallel inputs,
     one row-parallel output (Megatron layout)."""
 
-    def __init__(self, hidden_size, intermediate_size):
+    def __init__(self, hidden_size, intermediate_size, linear=None):
         super().__init__()
-        # llama uses no biases (bias=False reaches the TP classes too)
-        self.gate_proj = _col_linear(hidden_size, intermediate_size, bias=False)
-        self.up_proj = _col_linear(hidden_size, intermediate_size, bias=False)
-        self.down_proj = _row_linear(intermediate_size, hidden_size, bias=False)
+        col, row = _projections(linear)
+        self.gate_proj = col("gate_proj", hidden_size, intermediate_size)
+        self.up_proj = col("up_proj", hidden_size, intermediate_size)
+        self.down_proj = row("down_proj", intermediate_size, hidden_size)
 
     def forward(self, x):
         return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
 
 
 class LlamaAttention(Layer):
-    def __init__(self, config):
+    def __init__(self, config, linear=None):
         super().__init__()
         h = config.hidden_size
         self.num_heads = config.num_attention_heads
         self.num_kv_heads = config.num_key_value_heads
         self.head_dim = h // self.num_heads
         self.rope_theta = config.rope_theta
-        self.q_proj = _col_linear(h, self.num_heads * self.head_dim,
-                                  bias=False)
-        self.k_proj = _col_linear(h, self.num_kv_heads * self.head_dim,
-                                  bias=False)
-        self.v_proj = _col_linear(h, self.num_kv_heads * self.head_dim,
-                                  bias=False)
-        self.o_proj = _row_linear(self.num_heads * self.head_dim, h,
-                                  bias=False)
+        col, row = _projections(linear)
+        self.q_proj = col("q_proj", h, self.num_heads * self.head_dim)
+        self.k_proj = col("k_proj", h, self.num_kv_heads * self.head_dim)
+        self.v_proj = col("v_proj", h, self.num_kv_heads * self.head_dim)
+        self.o_proj = row("o_proj", self.num_heads * self.head_dim, h)
 
     def forward(self, x, rope, attn_bias=None, cache=None):
         B, S = x.shape[0], x.shape[1]
@@ -135,8 +145,11 @@ class LlamaAttention(Layer):
             qh = qv.reshape(B, S, hq, hd)
             kh = kv.reshape(B, S, hkv, hd)
             vh = vv.reshape(B, S, hkv, hd)
-            qh, kh = _apply_rope(qh, kh, cos, sin)
-            return qh, kh, vh
+            # the rotary tables are float32: rotate there, hand q and k on
+            # in the projections' own type (a bfloat16 decoder's hidden
+            # state stays bfloat16 behind the attention)
+            qr, kr = _apply_rope(qh, kh, cos, sin)
+            return qr.astype(qh.dtype), kr.astype(kh.dtype), vh
 
         qh, kh, vh = _apply(attend, q, k, v, rope[0], rope[1],
                             op_name="llama_rope", n_outs=3)

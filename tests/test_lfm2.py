@@ -1,6 +1,6 @@
 """The LFM2-MoE family (``text/models/lfm2.py``) and its serving through
 ``ServingEngine`` with per-slot state beside the paged KV
-(``serving.adapter.SlotStateAdapter``), at small sizes in float32 with the
+(``serving.adapter.StatedCacheAdapter``), at small sizes in float32 with the
 published pattern (2 dense layers, then ``attn conv conv conv``): the
 operators against plain loops, the state through tokens and chunks, the
 router, the model against ``chipbench/reference/lfm2.py``, and through the
@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.serving import GPTAdapter, ServingEngine, SlotStateAdapter
+from paddle_tpu.serving import GPTAdapter, ServingEngine, StatedCacheAdapter
 from paddle_tpu.tensor.tensor import Tensor
 from paddle_tpu.text.models import GPTForCausalLM, Lfm2MoeConfig
 from paddle_tpu.text.models.lfm2 import (Lfm2Attention, Lfm2ShortConv,
@@ -240,7 +240,7 @@ def test_an_evaluating_model_touches_no_buffer(family):
 
 # ----------------------------------------------------- through the adapter
 def _adapter_state(model, slots=3, pages=24):
-    adapter = SlotStateAdapter(model, PS, slots)
+    adapter = StatedCacheAdapter(model, PS, slots)
     params, bufs = adapter.params_and_buffers()
     return adapter, params, bufs, adapter.init_pools(pages + 1)
 
@@ -363,7 +363,7 @@ def test_engine_serves_mixed_lengths_as_the_reference_decodes(family, chunk):
     assert model.serving_caches()["state_shape"] == (5, 2, 32)
     prompts = [_ids(10 + i, n) for i, n in enumerate((21, 9, 33, 16, 5))]
     outs, eng = _served(model, prompts, prefill_chunk_tokens=chunk)
-    assert isinstance(eng._adapter, SlotStateAdapter)
+    assert isinstance(eng._adapter, StatedCacheAdapter)
     for p, out in zip(prompts, outs):
         assert len(out) == 6
         _assert_greedy(params, p, np.asarray(out))

@@ -263,7 +263,7 @@ def test_grouped_kernel_compiles_at_the_hybrid_cells_widths(
 @pytest.mark.parametrize("closure", ["step", "prefill_chunk", "prefill"])
 def test_hybrid_programs_fit_the_chip_at_the_cells_sizes(monkeypatch,
                                                          one_chip, closure):
-    """``SlotStateAdapter``'s decode, chunk and monolithic prefill programs
+    """``StatedCacheAdapter``'s decode, chunk and monolithic prefill programs
     for the ten-layer cut of LFM2-24B-A2B as
     ``lfm2_24b_a2b_l10.batch_closed_4k`` serves it (32 slots, 8,193 pages,
     tables 256 wide, bfloat16): 10.5 GB of weights that are never built
@@ -279,7 +279,7 @@ def test_hybrid_programs_fit_the_chip_at_the_cells_sizes(monkeypatch,
     import os
 
     import chip_smoke
-    from paddle_tpu.serving import SlotStateAdapter
+    from paddle_tpu.serving import StatedCacheAdapter
     from paddle_tpu.text.models.lfm2 import Lfm2MoeForCausalLM
 
     spec = importlib.import_module("chipbench.spec")
@@ -304,7 +304,7 @@ def test_hybrid_programs_fit_the_chip_at_the_cells_sizes(monkeypatch,
             leaf._value = jax.ShapeDtypeStruct(shapes[name],
                                                leaf._value.dtype)
     slots = 32
-    adapter = SlotStateAdapter(model, 16, slots)
+    adapter = StatedCacheAdapter(model, 16, slots)
     params, bufs = jax.tree_util.tree_map(
         lambda a: sds(a.shape, a.dtype), adapter.params_and_buffers())
     assert sum(math.prod(v.shape) for v in params.values()) == 5_267_089_664
@@ -347,3 +347,79 @@ def test_hybrid_programs_fit_the_chip_at_the_cells_sizes(monkeypatch,
     assert mem.temp_size_in_bytes < 100e6
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes) < 12.5e9
+
+
+@pytest.mark.parametrize("closure", ["step", "prefill_chunk", "prefill"])
+def test_looped_programs_hold_48_layer_bodies_and_fit_the_chip(
+        monkeypatch, one_chip, closure):
+    """``StatedCacheAdapter``'s decode, chunk and monolithic prefill programs
+    for Ouro-2.6B whole as ``ouro_2_6b.batch_closed_1k`` serves it (16
+    slots, 321 pages over 192 cache rows, tables 64 wide, bfloat16): 5.34 GB
+    of weights that are never built here (every leaf a shape) beside 8.08 GB
+    of pools.  THE WEIGHTS ARE SHARED AND THE LOOP IS TRACED: one ``while``
+    whose body holds the 48 layer bodies, so a program holds 48 writer
+    kernels and 48 attention kernels, not 192 of each, and the cache row
+    reaches them as a traced scalar.  The pools ride in the loop's carry
+    and are touched by the kernels alone: the one pool-sized instruction is
+    the loop itself.  What the compiler adds is its own re-layout of the
+    q, k and v weights (concatenated for one product, hoisted out of the
+    loop): 1.3 GB of temporaries a program."""
+    import json
+    import os
+
+    import chip_smoke
+    from paddle_tpu.serving import StatedCacheAdapter
+    from paddle_tpu.text.models.ouro import OuroForCausalLM
+
+    spec = importlib.import_module("chipbench.spec")
+    ref = spec.load_module("reference", "ouro")
+    family = spec.load_module("models", "ouro")
+    with open(os.path.join(spec.HERE, "configs", "ouro_2_6b.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(spec.HERE, "workloads",
+                           "ouro_2_6b.batch_closed_1k.json")) as f:
+        engine = json.load(f)["engine"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    # a scalar stands in for every leaf; its shape comes from the reference
+    model = OuroForCausalLM(
+        family.program_config(cfg, "bfloat16"),
+        param_init=lambda name, shape: jnp.zeros((), jnp.bfloat16)).eval()
+    shapes = ref.param_shapes(cfg)
+    for name, leaf in model.named_parameters():
+        leaf._value = jax.ShapeDtypeStruct(shapes[name], leaf._value.dtype)
+    slots, width = engine["num_slots"], engine["max_model_len"] // 16
+    adapter = StatedCacheAdapter(model, engine["page_size"], slots)
+    params, bufs = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), adapter.params_and_buffers())
+    assert len(params) == 48 * 11 + 5
+    assert sum(math.prod(v.shape) for v in params.values()) == 2_667_974_657
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pools = tuple(sds(p.shape, p.dtype) for p in jax.eval_shape(
+        lambda: adapter.init_pools(engine["num_pages"] + 1)))
+    assert [p.shape for p in pools] == [(192, 321, 16, 16, 128)] * 2
+    assert math.prod(pools[0].shape) < 2 ** 31      # 94% of it
+    B, C = (slots, 1) if closure == "step" else (1, 256)
+    lead = (sds((B, C), jnp.int64),) + (
+        (sds((B,), jnp.int32),) if closure == "prefill_chunk" else ())
+    first = 2 + len(lead)
+    compiled = jax.jit(
+        getattr(adapter, closure),
+        donate_argnums=(first, first + 1)).lower(
+        params, bufs, *lead, *pools, sds((B, width), jnp.int32),
+        sds((B,), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    sized = chip_smoke.pool_sized_instructions(text,
+                                               [p.shape for p in pools])
+    assert len(sized) == 1 and sized[0].startswith("%while"), sized
+    assert len(re.findall(r"= \([^\n]*\) while\(", text)) == 1
+    # 48 writers and 48 attention kernels (the decode kernel, the chunk
+    # kernel, or flash attention over a whole prompt): a layer body each
+    assert text.count("tpu_custom_call") == 2 * 48
+    assert len(re.findall(r'custom-call\([^\n]*op_name="[^"]*loop_step/'
+                          r'gqa_attention/[^"]*paged_write', text)) == 48
+    assert mem.temp_size_in_bytes < 1.5e9
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) < 15.0e9
