@@ -12,7 +12,17 @@ GLOBAL page pools; a background scheduler thread executes iterations:
    first token;
 3. run ONE compiled decode step for the whole batch — every slot at its
    OWN position (per-slot lens / page table rows), inactive slots pointed
-   at a scratch page — then sync the sampled tokens to the host.
+   at a scratch page.  The loop runs one step AHEAD of the host: a turn
+   enqueues its programs (the step's ``last`` is the previous step's
+   sampled ids, still on the device) and then reads back, emits and
+   retires what the turn BEFORE left, while the device computes.  What
+   the host knows ahead it acts on ahead (a lane whose budget is
+   dispatched to the end leaves its lane and frees its pages at once);
+   an EOS hit, a cancel, a deadline or a non-finite row is found one step
+   late, and the token computed meanwhile is dropped by the slot's
+   generation.  An engine whose next step's inputs are made on the host
+   from this step's token (speculative drafts, grammar masks) reads every
+   dispatch back at once, through the same loop.
 
 No caller ever waits for the slowest sequence in the batch: a short
 request retires and its slot backfills from the queue while long ones keep
@@ -338,7 +348,7 @@ class RequestHandle:
 class _Slot:
     __slots__ = ("handle", "req", "alloc", "table_row", "length", "last",
                  "produced", "temp", "eos", "max_new", "deadline",
-                 "last_token_t", "idx", "prefilled")
+                 "last_token_t", "idx", "prefilled", "unread", "gen")
 
     def __init__(self, req, alloc, table_row):
         self.idx = None                     # batch lane (set at admission)
@@ -348,7 +358,14 @@ class _Slot:
         self.table_row = table_row          # np.int32 [<= NP] real pages
         self.length = len(req.prompt)       # tokens whose K/V are in pages
         self.last = 0                       # last sampled token id
-        self.produced = 0
+        self.produced = 0                   # tokens EMITTED to the caller
+        # tokens dispatched and not read back yet (``produced + unread`` is
+        # what the device has sampled or will: the budget counts that)
+        self.unread = 0
+        # bumped when the lane is taken from the request before its budget
+        # ends (EOS, cancel, deadline, a non-finite row, preemption): a
+        # result in flight that recorded an older value is dropped unread
+        self.gen = 0
         self.temp = float(req.sampling.temperature)
         self.eos = req.eos_token_id
         self.max_new = req.max_new_tokens
@@ -360,6 +377,40 @@ class _Slot:
         # stays inert (scratch table, length 0) so decode dispatches skip
         # it, and _advance_prefills drives the next chunk.
         self.prefilled = None
+
+
+class _Flight:
+    """One dispatch whose results the host has not read: the arrays, still
+    on the device, and whom they belong to.  ``lanes`` holds ``(row, lane,
+    slot, gen, expects)``: the result's row, the batch lane and its slot
+    at dispatch, the slot's generation then, and 1 if the row's token is
+    the request's next one (a chunk that is not a prompt's last yields
+    none)."""
+
+    __slots__ = ("kind", "fam", "turn", "lanes", "hist", "t0", "t_read",
+                 "cold", "ahead", "tok", "bad", "nstats")
+
+    def __init__(self, kind, fam, turn, lanes, hist=None):
+        self.kind = kind                    # "prefill" | "chunk" | "step"
+        self.fam = fam
+        self.turn = turn
+        self.lanes = lanes
+        self.hist = hist                    # the kind's seconds histogram
+        self.cold = self.ahead = False
+        self.tok = self.bad = self.nstats = None
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _seed_last(last, lane, tok):
+    """The decode step's ``last [B, 1]`` with row ``lane`` set to the first
+    token ``tok [1]`` a prefill sampled: a lane goes live on the device."""
+    return last.at[lane, 0].set(tok[0].astype(last.dtype))
+
+
+@jax.jit
+def _carry_last(tok):
+    """A step's sampled ids ``[B]`` as the next step's ``last [B, 1]``."""
+    return tok[:, None].astype(jnp.int64)
 
 
 class ServingEngine:
@@ -678,6 +729,26 @@ class ServingEngine:
             self._h_dlen = np.zeros((self.num_slots,), np.int32)
         self._n_temp = 0          # live slots with temperature sampling
         self._gauges_t = 0.0      # last _update_gauges stamp (throttled)
+        # the decode loop runs one step ahead of the host (depth 1): a
+        # turn enqueues its programs and THEN reads what the turn before
+        # left in ``_pending``, so emitting, retiring, admitting and
+        # building arguments happen while the device computes.  Depth 0
+        # (every dispatch read back at once) where the next step's inputs
+        # are made on the host from this step's token; the engine derives
+        # it from what it was built with, there is no option
+        self._depth = 0 if self._host_makes_step_inputs() else 1
+        self._pending = collections.deque()     # _Flight, dispatch order
+        self._turn_no = 0
+        self._t_step = 0.0        # when the last step's result was read
+        # depth 1: the step's ``last`` stays on the device (a step's tok
+        # carried over, a new lane's first token merged in)
+        self._d_last = self._h_last
+        if self._depth:
+            # both small programs compile here, not inside a window
+            z = np.zeros((self.num_slots,), np.int64)
+            if device is not None:
+                z = jax.device_put(z, device)
+            _seed_last(_carry_last(z), np.int32(0), z[:1])
         self._max_queue = max_queue
         self._stop_evt = threading.Event()
         self._thread = None
@@ -789,6 +860,19 @@ class ServingEngine:
             "serving.preemptions",
             "sequences evicted from their decode slot (reason=deadline: "
             "retired expired; reason=qos: requeued for a higher tier)")
+        self._m_ahead = _c(
+            "serving.steps_dispatched_ahead",
+            "decode dispatches made while the previous step's result was "
+            "still unread (over serving.decode_batch_size_count: the share "
+            "of steps the device did not wait for the host)")
+        self._m_drains = _c(
+            "serving.pipeline_drains",
+            "turns of a run-ahead engine that read back everything in "
+            "flight with nothing enqueued behind it, by reason")
+        self._m_discarded = _c(
+            "serving.tokens_discarded",
+            "sampled tokens dropped unread or unemitted: their lane was "
+            "retired, preempted or failed while they were in flight")
         # per-tier pressure gauges (QoS engines set them; registered
         # unconditionally so the metric families are stable)
         self._m_tier_depth = _g(
@@ -1050,7 +1134,11 @@ class ServingEngine:
         """Device->host copy of ONE page row across EVERY pool array —
         the KVSpillTier's snapshot callable.  Walking the whole tuple is
         what keeps int8 payload+scale pairs together: the quantized
-        adapter's (kp, vp, ks, vs) all slice at the same page index."""
+        adapter's (kp, vp, ks, vs) all slice at the same page index.
+        Results in flight need no read-back first: the copy waits for
+        every program enqueued before it, and the page it takes is idle
+        (a lane in flight writes pages it still holds; one that left
+        ahead wrote past its prompt's shared pages)."""
         return tuple(np.asarray(p[:, page]) for p in self._pools)
 
     def _spill_restore(self, page, payload):
@@ -1358,11 +1446,7 @@ class ServingEngine:
         while time.monotonic() < deadline:
             if self._error is not None or not self._started:
                 return True  # aborted/stopped: nothing left in flight
-            with self._lock:
-                empty = not self._queue \
-                    and all(s is None for s in self._slots) \
-                    and self._admitting is None
-            if empty:
+            if self.quiescent:
                 return True
             time.sleep(0.01)
         raise TimeoutError(f"engine did not drain within {timeout}s: "
@@ -1390,6 +1474,8 @@ class ServingEngine:
                 "serving scheduler thread did not stop within 600s "
                 "(stuck in a compile or device call); engine state left "
                 "untouched — retry stop() once the call returns")
+        for s in self._drop_pending():
+            self._fail_stopped(s.handle)
         for i, s in enumerate(self._slots):
             if s is not None:
                 self._bm.free(s.alloc)
@@ -2025,7 +2111,8 @@ class ServingEngine:
                             f"replica {self.replica} lost: host reclaimed "
                             "by the cluster scheduler (injected replica "
                             "loss)")
-                if self._queue or any(s is not None for s in self._slots):
+                if self._queue or self._pending \
+                        or any(s is not None for s in self._slots):
                     # one tree of spans per turn that does work; what its
                     # duration holds beyond its children is the turn's
                     # self time: gauges, ledgers, host-buffer writes
@@ -2068,7 +2155,9 @@ class ServingEngine:
                 return
 
     def _turn(self):
-        """One scheduler iteration with something queued or in a slot."""
+        """One scheduler iteration with something queued, in a slot or in
+        flight."""
+        self._turn_no += 1
         with _tracing.span("serving.admit"):
             self._admit()
         # chunked prefill rides the SAME scheduler iteration as the
@@ -2076,8 +2165,20 @@ class ServingEngine:
         # batch decode over the lanes that finished ingesting
         self._advance_prefills()
         self._update_gauges()
-        if any(s is not None and s.prefilled is None for s in self._slots):
+        if self._live():
             self._step_once()
+        if self._pending and self._pending[-1].turn < self._turn_no:
+            # nothing was enqueued this turn (every lane had left or was
+            # retired): no later dispatch reads these results for it
+            self._m_drains.inc(reason="idle")
+            with _tracing.span("serving.device_wait"):
+                ready = self._take(len(self._pending))
+            self._deliver(ready)
+
+    def _live(self):
+        """Does any lane decode (its prompt ingested, its slot held)?"""
+        return any(s is not None and s.prefilled is None
+                   for s in self._slots)
 
     def _recover(self, exc):
         """Transient scheduler failure (classified by
@@ -2094,7 +2195,10 @@ class ServingEngine:
             "serving engine auto-restart %d/%d after transient failure %r; "
             "re-queueing in-flight requests", self._engine_restarts,
             self._max_engine_restarts, exc)
-        inflight = []
+        # results in flight are dropped with the pools they were computed
+        # from: ``produced`` counts what was EMITTED, so a re-admission
+        # computes the dropped tokens again
+        inflight = [(s.req, s.produced) for s in self._drop_pending()]
         for i, s in enumerate(self._slots):
             if s is not None:
                 self._slots[i] = None
@@ -2240,11 +2344,8 @@ class ServingEngine:
         Tokens already emitted stay emitted."""
         s = self._slots[i]
         h = s.handle
-        produced = s.produced
-        self._bm.free(s.alloc)
-        self._release_tenant(s.req)
-        self._slots[i] = None
-        self._clear_slot_row(i, s)
+        produced = s.produced       # emitted: a token in flight is dropped
+        self._release_lane(i, s)
         if h.cancelled:
             self._finish(h, "cancelled")
             return
@@ -2307,7 +2408,7 @@ class ServingEngine:
         if self._error is not None or not self._started:
             return True
         with self._lock:
-            return not self._queue \
+            return not self._queue and not self._pending \
                 and all(s is None for s in self._slots) \
                 and self._admitting is None
 
@@ -2318,6 +2419,9 @@ class ServingEngine:
         if pending is not None and not pending.handle.done:
             pending.handle._error = exc
             self._finish(pending.handle, "error")
+        for s in self._drop_pending():
+            s.handle._error = exc
+            self._finish(s.handle, "error")
         for i, s in enumerate(self._slots):
             if s is not None:
                 self._bm.free(s.alloc)
@@ -2417,228 +2521,282 @@ class ServingEngine:
             f"mode={req.mode!r} request reached the base engine scheduler")
 
     def _prefill(self, req, alloc, slot_idx):
-        if req.handle.admitted_at is None:   # TTFT decomposition: queue_s
-            req.handle.admitted_at = time.time()
+        """Admit ``req`` into lane ``slot_idx`` with ONE dispatch over its
+        prompt; the lane decodes from this turn's step on, its first token
+        still on the device.
+
+        Hierarchical KV cache: leading pages the radix index matched (or
+        the spill tier resurrected) already hold byte-valid K/V, so the
+        chunk program runs just the divergent tail at positions
+        ``cached..S0-1`` (clamped so at least the last prompt position is
+        computed: its logits seed the first token).  Greedy output stays
+        byte-identical: K/V at a position is a pure function of the token
+        prefix and the weights.  Such a dispatch has its own
+        ``prefill/<b>@cached<p>`` perf family and span
+        (``serving.prefill_cached``)."""
+        h = req.handle
+        if h.admitted_at is None:   # TTFT decomposition: queue_s
+            h.admitted_at = time.time()
         S0 = len(req.prompt)
-        # hierarchical KV cache: leading pages the radix index matched
-        # (or the spill tier resurrected) already hold byte-valid K/V —
-        # dispatch only the divergent tail, clamped so at least the last
-        # prompt position is computed (its logits seed the first token)
-        if alloc.cached_pages:
-            cached = min(alloc.cached_pages * self.page_size, S0 - 1)
-            if cached > 0:
-                return self._prefill_cached(req, alloc, slot_idx, cached)
-        s_pad = self._prefill_bucket(S0)
-        ids = np.zeros((1, s_pad), np.int64)
-        ids[0, :S0] = req.prompt
+        cached = min(alloc.cached_pages * self.page_size, S0 - 1) \
+            if alloc.cached_pages else 0
+        width = self._prefill_bucket(S0 - cached)
         table_row = np.asarray(alloc.pages, np.int32)
-        table = np.full((1, self.table_width), self._scratch, np.int32)
-        table[0, :len(table_row)] = table_row
-        lens = np.asarray([S0], np.int32)
-        temps = np.asarray([req.sampling.temperature], np.float32)
-        prog, traces = self._prefill_program(s_pad)
-        n0 = traces[0]
-        rkey = self._next_key()
-        extra = self._prefill_extra(req, slot_idx)
-        guard = self._numeric_guard
-        tail = (self._numeric_inject(1),) if guard else ()
-        fam = self._prefill_family(s_pad)
-        if _perf.needs_cost(fam):
-            # capture arg shapes ONCE per family; the cost_analysis
-            # re-lower+compile itself runs lazily, off this thread
-            _perf.register_cost_thunk(fam, _perf.jit_cost_thunk(
-                prog, (self._params, self._bufs, ids, *self._pools,
-                       table, lens, temps, rkey, *extra, *tail)))
-        # first dispatch of this program = minutes-long XLA compile: the
-        # ledger compile window flags self._compiling for the watchdog/
-        # health paths, holds programs.compile_in_progress up, and bills
-        # the stall to this request's TTFT decomposition
-        win = _programs.ledger().compile_window(
-            self._prefill_store_key(s_pad), family=fam, replica=self.replica,
-            device=self._device_label(), store=self._store(),
-            owner=self._model, handles=(req.handle,), engine=self,
-            cold=n0 == 0)
-        win.attach(prog, (self._params, self._bufs, ids, *self._pools,
-                          table, lens, temps, rkey, *extra, *tail))
-        t0 = time.perf_counter()
-        bad = nstats = None
-        try:
-            with _tracing.span("serving.prefill",
-                               trace_id=req.handle.trace_id,
-                               request_id=req.handle.request_id,
-                               slot=slot_idx, prompt_len=S0):
-                with _tracing.span("serving.dispatch"):
-                    if guard:
-                        tok, bad, nstats, *pools = prog(
-                            self._params, self._bufs, ids, *self._pools,
-                            table, lens, temps, rkey, *extra, *tail)
-                    else:
-                        tok, *pools = prog(self._params, self._bufs, ids,
-                                           *self._pools, table, lens, temps,
-                                           rkey, *extra)
-                    self._pools = tuple(pools)
-                with _tracing.span("serving.device_wait"):
-                    tok = int(np.asarray(tok)[0])
-        finally:
-            win.close(traced=traces[0] > n0)
-            self._progress_t = time.monotonic()
-        if traces[0] > n0:
-            self._m_prefill_traces.inc(traces[0] - n0)
-        elif traces[0]:
-            # warm dispatch: attribute its device time to the program
-            # family (a trace+compile wall is not device time — skipped)
-            _perf.record(fam, time.perf_counter() - t0)
-        self._m_prefill_seconds.observe(time.perf_counter() - t0)
-        if guard:
-            _numerics.submit(f"serving/{self.replica}", ("logits",), nstats,
-                             step=self._iteration)
-            if bool(np.asarray(bad)[0]):
-                # non-finite first-token logits: fail THIS request before
-                # it ever occupies a decode lane; nothing else is touched
-                h = req.handle
-                h._error = NumericFault(
-                    "non-finite logits at prefill", site="logits",
-                    stream=f"serving/{self.replica}", step=self._iteration)
-                self._m_numeric_faults.inc()
-                self._bm.free(alloc)
-                self._release_tenant(req)
-                self._admitting = None
-                self._finish(h, "error")
-                return
+        if cached > 0:
+            fam = self._prefill_cached_family(width, alloc.cached_pages)
+            cm = _tracing.span(
+                "serving.prefill_cached", trace_id=h.trace_id,
+                request_id=h.request_id, slot=slot_idx, prompt_len=S0,
+                cached_tokens=cached)
+        else:
+            fam = self._prefill_family(width)
+            cm = _tracing.span(
+                "serving.prefill", trace_id=h.trace_id,
+                request_id=h.request_id, slot=slot_idx, prompt_len=S0)
         slot = _Slot(req, alloc, table_row)
         slot.idx = slot_idx
-        slot.last = tok
-        slot.produced = 1
-        req.handle.status = "running"
-        self._slots[slot_idx] = slot
-        self._admitting = None
-        # persistent host-buffer row for the decode dispatch (rebuilt here
-        # and on retire only, never per step)
-        i = slot_idx
+        fl = _Flight("prefill", fam, self._turn_no,
+                     [(0, slot_idx, slot, 0, 1)], self._m_prefill_seconds)
+
+        def sent():
+            # between dequeue and here the request lived in _admitting, so
+            # a crash mid-dispatch could still requeue it
+            h.status = "running"
+            self._slots[slot_idx] = slot
+            self._admitting = None
+            if slot.temp > 0:
+                self._n_temp += 1
+            self._go_live(slot, slot_idx, fl.tok)
+
+        self._dispatch(
+            fl, cm, *self._ingest_program(req, slot_idx, table_row, cached,
+                                          S0 - cached, width, cached > 0),
+            (h,), self._m_prefill_traces, sent)
+
+    def _ingest_program(self, req, slot_idx, table_row, start, nval, width,
+                        chunk):
+        """``(program, traces, store key, arguments)`` of the one-request
+        dispatch that ingests prompt tokens ``start .. start+nval-1``
+        right-padded to ``width``: the chunk program at positions
+        ``start..`` (``chunk``), else the monolithic prefill from 0.
+        Pad-lane junk K/V lands past the valid length (or drops OOB):
+        invisible to seq_lens masking, overwritten by the first decode
+        write."""
+        ids = np.zeros((1, width), np.int64)
+        ids[0, :nval] = req.prompt[start:start + nval]
+        table = np.full((1, self.table_width), self._scratch, np.int32)
+        table[0, :len(table_row)] = table_row
+        temps = np.asarray([req.sampling.temperature], np.float32)
+        if chunk:
+            prog, traces = self._prefill_chunk_program(width)
+            key = self._prefill_chunk_store_key(width)
+            head = (ids, np.asarray([nval], np.int32))
+            lens = np.asarray([start], np.int32)
+        else:
+            prog, traces = self._prefill_program(width)
+            key = self._prefill_store_key(width)
+            head = (ids,)
+            lens = np.asarray([nval], np.int32)
+        tail = (self._numeric_inject(1),) if self._numeric_guard else ()
+        return prog, traces, key, (
+            self._params, self._bufs, *head, *self._pools, table, lens,
+            temps, self._next_key(), *self._prefill_extra(req, slot_idx),
+            *tail)
+
+    def _go_live(self, slot, i, tok):
+        """Lane ``i`` decodes from the next step on: its persistent host
+        row (rebuilt here and on retire only, never per step), and its
+        first token ``tok``, which the host has not read, merged into the
+        step's ``last`` on the device."""
+        slot.prefilled = None
+        slot.unread = 1
         self._h_table[i, :] = self._scratch
-        self._h_table[i, :len(table_row)] = table_row
+        self._h_table[i, :len(slot.table_row)] = slot.table_row
         self._h_lens[i] = slot.length
         self._h_temps[i] = slot.temp
-        self._h_last[i, 0] = tok
-        self._on_admitted(slot, slot_idx)
-        if slot.temp > 0:
-            self._n_temp += 1
+        if self._depth:
+            self._d_last = _seed_last(self._d_last, np.int32(i), tok)
+        self._on_admitted(slot, i)
         if self._drafter is not None:
             # draft context = prompt + every emitted token (re-admission
             # after a restart passes prompt+tokens-so-far as the prompt,
             # so the rebuilt index sees the same stream)
-            self._drafter.register(i, req.prompt)
-            self._drafter.extend(i, [tok])
-        self._emit_token(slot, tok)
-        self._retire_if_done(slot_idx)
+            self._drafter.register(i, slot.req.prompt)
+        self._leave_if_spent(i, slot)
 
-    def _prefill_cached(self, req, alloc, slot_idx, cached):
-        """Partial-prefix prefill: the first ``cached`` prompt tokens are
-        covered by radix-matched / spill-resurrected pages whose K/V is
-        already byte-valid, so ONE chunk-variant dispatch runs just the
-        divergent tail at positions ``cached..S0-1`` (the chunk cache
-        machinery reused at a nonzero offset — a scheduler change, not a
-        program change) and its sampled token seeds decode exactly like a
-        monolithic prefill.  Greedy output stays byte-identical: K/V at a
-        position is a pure function of the token prefix and the weights,
-        so reading the cached run is the same bytes recompute would have
-        written.  Attributed to its own ``prefill/<b>@cached<p>`` perf
-        family so the roofline table separates tail-only dispatches from
-        full prefills."""
-        S0 = len(req.prompt)
-        tail = S0 - cached
-        C = self._prefill_bucket(tail)
-        ids = np.zeros((1, C), np.int64)
-        ids[0, :tail] = req.prompt[cached:]
-        table_row = np.asarray(alloc.pages, np.int32)
-        table = np.full((1, self.table_width), self._scratch, np.int32)
-        table[0, :len(table_row)] = table_row
-        lens = np.asarray([cached], np.int32)
-        nvalid = np.asarray([tail], np.int32)
-        temps = np.asarray([req.sampling.temperature], np.float32)
-        prog, traces = self._prefill_chunk_program(C)
+    # -------------------------------------------- dispatch and read-back
+    def _dispatch(self, fl, cm, prog, traces, key, args, handles, traced,
+                  sent):
+        """Enqueue one compiled program inside its dispatching span
+        ``cm``, do the host's bookkeeping that its results do not feed
+        (``sent``), then read back what EARLIER dispatches left (this
+        one's too at depth 0) and deliver that."""
+        if _perf.needs_cost(fl.fam):
+            # capture arg shapes ONCE per family; the cost_analysis
+            # re-lower+compile itself runs lazily, off this thread
+            _perf.register_cost_thunk(fl.fam,
+                                      _perf.jit_cost_thunk(prog, args))
         n0 = traces[0]
-        rkey = self._next_key()
-        extra = self._prefill_extra(req, slot_idx)
-        guard = self._numeric_guard
-        gtail = (self._numeric_inject(1),) if guard else ()
-        fam = self._prefill_cached_family(C, alloc.cached_pages)
-        if _perf.needs_cost(fam):
-            _perf.register_cost_thunk(fam, _perf.jit_cost_thunk(
-                prog, (self._params, self._bufs, ids, nvalid, *self._pools,
-                       table, lens, temps, rkey, *extra, *gtail)))
+        # first dispatch of a program = minutes-long XLA compile: the
+        # ledger compile window flags self._compiling for the watchdog/
+        # health paths, holds programs.compile_in_progress up, and bills
+        # the stall to the TTFT decomposition of every waiting request
         win = _programs.ledger().compile_window(
-            self._prefill_chunk_store_key(C), family=fam,
-            replica=self.replica, device=self._device_label(),
-            store=self._store(), owner=self._model,
-            handles=(req.handle,), engine=self, cold=n0 == 0)
-        win.attach(prog, (self._params, self._bufs, ids, nvalid,
-                          *self._pools, table, lens, temps, rkey,
-                          *extra, *gtail))
-        t0 = time.perf_counter()
-        bad = nstats = None
+            key, family=fl.fam, replica=self.replica,
+            device=self._device_label(), store=self._store(),
+            owner=self._model, handles=handles, engine=self, cold=n0 == 0)
+        win.attach(prog, args)
+        fl.t0 = time.perf_counter()
         try:
-            with _tracing.span("serving.prefill_cached",
-                               trace_id=req.handle.trace_id,
-                               request_id=req.handle.request_id,
-                               slot=slot_idx, prompt_len=S0,
-                               cached_tokens=cached):
+            with cm:
                 with _tracing.span("serving.dispatch"):
-                    if guard:
-                        tok, bad, nstats, *pools = prog(
-                            self._params, self._bufs, ids, nvalid,
-                            *self._pools, table, lens, temps, rkey,
-                            *extra, *gtail)
-                    else:
-                        tok, *pools = prog(self._params, self._bufs, ids,
-                                           nvalid, *self._pools, table, lens,
-                                           temps, rkey, *extra)
-                    self._pools = tuple(pools)
+                    out = prog(*args)
+                    k = 1
+                    fl.tok = out[0]
+                    if self._numeric_guard:
+                        k = 3
+                        fl.bad, fl.nstats = out[1], out[2]
+                    self._pools = tuple(out[k:])
+                fl.cold = traces[0] > n0
+                sent()
+                self._pending.append(fl)
                 with _tracing.span("serving.device_wait"):
-                    tok = int(np.asarray(tok)[0])
+                    ready = self._read_back(fl)
         finally:
             win.close(traced=traces[0] > n0)
             self._progress_t = time.monotonic()
-        if traces[0] > n0:
-            self._m_prefill_traces.inc(traces[0] - n0)
-        elif traces[0]:
-            _perf.record(fam, time.perf_counter() - t0)
-        self._m_prefill_seconds.observe(time.perf_counter() - t0)
-        if guard:
-            _numerics.submit(f"serving/{self.replica}", ("logits",), nstats,
-                             step=self._iteration)
-            if bool(np.asarray(bad)[0]):
-                h = req.handle
-                h._error = NumericFault(
-                    "non-finite logits at prefill", site="logits",
-                    stream=f"serving/{self.replica}", step=self._iteration)
-                self._m_numeric_faults.inc()
-                self._bm.free(alloc)
-                self._release_tenant(req)
-                self._admitting = None
-                self._finish(h, "error")
-                return
-        slot = _Slot(req, alloc, table_row)
-        slot.idx = slot_idx
-        slot.last = tok
-        slot.produced = 1
-        req.handle.status = "running"
-        self._slots[slot_idx] = slot
-        self._admitting = None
-        i = slot_idx
-        self._h_table[i, :] = self._scratch
-        self._h_table[i, :len(table_row)] = table_row
-        self._h_lens[i] = slot.length
-        self._h_temps[i] = slot.temp
-        self._h_last[i, 0] = tok
-        self._on_admitted(slot, slot_idx)
-        if slot.temp > 0:
-            self._n_temp += 1
-        if self._drafter is not None:
-            self._drafter.register(i, req.prompt)
-            self._drafter.extend(i, [tok])
-        self._emit_token(slot, tok)
-        self._retire_if_done(slot_idx)
+        if fl.cold:
+            traced.inc(traces[0] - n0)
+        self._deliver(ready)
+
+    def _read_back(self, fl):
+        """Inside ``fl``'s dispatching span, after its enqueue: read the
+        results that are due.  Depth 0: everything.  Depth 1: what the
+        turns BEFORE this one left, once this turn's last program is
+        enqueued behind it (the step; a chunk that no step follows), so
+        the device goes on while the host blocks here; everything, when
+        the dispatch left no slot held and nothing can run ahead."""
+        pend = self._pending
+        if not self._depth:
+            n = len(pend)
+        elif all(s is None for s in self._slots):
+            n = len(pend)
+            self._m_drains.inc(reason="no_lane")
+        elif fl.kind == "step" or (fl.kind == "chunk" and not self._live()):
+            n = sum(1 for f in pend if f.turn < self._turn_no)
+        else:
+            n = 0
+        return self._take(n)
+
+    def _take(self, n):
+        """Read the ``n`` oldest dispatches' results, in order.  They are
+        popped only once every read is through: a read that raises leaves
+        them all in flight for whoever recovers."""
+        ready = [self._read(f) for f in itertools.islice(self._pending, n)]
+        for _ in range(n):
+            self._pending.popleft()
+        return ready
+
+    def _read(self, fl):
+        """Block for one dispatch's results: ``(fl, tok, bad)`` on the
+        host.  A chunk that yields no token and carries no guard has
+        nothing to read."""
+        tok = bad = None
+        if any(ln[4] for ln in fl.lanes):
+            tok = np.asarray(fl.tok)
+        if fl.bad is not None:
+            bad = np.asarray(fl.bad)
+        fl.t_read = time.perf_counter()
+        return fl, tok, bad
+
+    def _deliver(self, ready):
+        """Results read back, in dispatch order: observe what each cost,
+        emit its tokens, retire.  A lane whose slot was taken from the
+        request since the dispatch has a newer generation: its result is
+        dropped, not emitted."""
+        if not ready:
+            return
+        stream = f"serving/{self.replica}"
+        with _tracing.span("serving.emit"):
+            for fl, tok, bad in ready:
+                if fl.kind == "step":
+                    # what a decode step costs the loop: between two
+                    # steps' results reaching the host, or from its own
+                    # dispatch where the last result was read before it
+                    dt = fl.t_read - (self._t_step if fl.ahead else fl.t0)
+                    self._t_step = fl.t_read
+                    self._m_step_seconds.observe(dt)
+                else:
+                    dt = fl.t_read - fl.t0      # dispatch -> host
+                    fl.hist.observe(dt)
+                if not fl.cold:
+                    # warm dispatch: attribute its time to the program
+                    # family (a trace+compile wall is not device time)
+                    _perf.record(fl.fam, dt)
+                if fl.nstats is not None:
+                    _numerics.submit(stream, ("logits",), fl.nstats,
+                                     step=self._iteration)
+                for row, i, slot, gen, expects in fl.lanes:
+                    if slot.gen != gen:
+                        if expects:
+                            self._m_discarded.inc()
+                        continue
+                    slot.unread -= expects
+                    if bad is not None and bad[row]:
+                        # this row's logits went non-finite: fail exactly
+                        # this request; finite rows emit unchanged tokens
+                        self._fail_numeric(i, slot)
+                        continue
+                    if not expects:
+                        continue
+                    slot.produced += 1
+                    slot.last = int(tok[row])
+                    if self._slots[i] is slot:
+                        self._h_last[i, 0] = slot.last
+                    self._emit_token(slot, slot.last)
+                    if not self._retire_if_done(i, slot) \
+                            and self._drafter is not None:
+                        # the drafter's context must keep growing or it
+                        # would never find a matching suffix again
+                        self._drafter.extend(i, [slot.last])
+
+    def _drop_pending(self):
+        """Forget every result in flight (the pools are rebuilt, or the
+        engine ends).  Returns the slots that had left their lane ahead of
+        their last tokens: no entry of ``_slots`` holds them any more, so
+        whoever drops the results settles their requests."""
+        left, n = [], 0
+        for fl in self._pending:
+            for _, i, slot, gen, expects in fl.lanes:
+                n += expects if slot.gen == gen else 0
+                if self._slots[i] is not slot and slot.gen == gen \
+                        and not slot.handle.done and slot not in left:
+                    left.append(slot)
+        self._pending.clear()
+        if n:
+            self._m_discarded.inc(n)
+        return left
+
+    def _leave_if_spent(self, i, slot):
+        """What the host knows ahead it acts on ahead: with its budget
+        dispatched to the end, ``slot`` leaves lane ``i`` and frees its
+        pages now; the tokens still in flight are its last and are emitted
+        when read.  (At depth 0 the read-back retires it, at once.)"""
+        if self._depth and slot.produced + slot.unread >= slot.max_new:
+            self._release_lane(i, slot, drop=False)
+
+    def _release_lane(self, i, slot, drop=True):
+        """Take lane ``i`` from ``slot``'s request: its pages return to the
+        pool and the lane backfills at the next admit.  Whatever is still
+        in flight for it is dropped unread, unless it leaves ahead
+        (``drop=False``)."""
+        if drop:
+            slot.gen += 1
+        if self._slots[i] is slot:
+            self._bm.free(slot.alloc)
+            self._release_tenant(slot.req)
+            self._slots[i] = None
+            self._clear_slot_row(i, slot)
 
     # ------------------------------------------------- chunked prefill
     def _admit_chunked(self, req, alloc, slot_idx):
@@ -2697,10 +2855,7 @@ class ServingEngine:
                 status = "cancelled" if h.cancelled else "expired"
                 if status == "expired":
                     self._count_preemption(s.req, "deadline")
-                self._bm.free(s.alloc)
-                self._release_tenant(s.req)
-                self._slots[i] = None
-                self._clear_slot_row(i, s)
+                self._release_lane(i, s)
                 self._finish(h, status)
                 continue
             budget -= self._prefill_chunk_step(i, s)
@@ -2710,100 +2865,31 @@ class ServingEngine:
         """Dispatch ONE chunk of slot ``i``'s prompt: tokens
         ``prefilled .. prefilled+C-1`` (right-padded on the last chunk)
         through the chunk cache variant at positions ``prefilled..``.
-        Pad-lane junk K/V lands past the valid length (or drops OOB) —
-        invisible to seq_lens masking, overwritten by the first decode
-        write — so the padded dispatch is byte-equivalent to an exact one.
-        The FINAL chunk's sampled token seeds decode and the lane goes
-        live.  Returns the number of real prompt tokens ingested (the
-        budget unit)."""
+        Only the FINAL chunk's sampled token is anyone's: it seeds decode
+        and the lane goes live; the others' results are never read (but
+        for the guard's flag).  Returns the number of real prompt tokens
+        ingested (the budget unit)."""
         req = slot.req
         C = self._chunk_tokens
-        S0 = len(req.prompt)
         c0 = slot.prefilled
-        nval = min(C, S0 - c0)
-        final = c0 + nval >= S0
-        ids = np.zeros((1, C), np.int64)
-        ids[0, :nval] = req.prompt[c0:c0 + nval]
-        table = np.full((1, self.table_width), self._scratch, np.int32)
-        table[0, :len(slot.table_row)] = slot.table_row
-        lens = np.asarray([c0], np.int32)
-        nvalid = np.asarray([nval], np.int32)
-        temps = np.asarray([slot.temp], np.float32)
-        prog, traces = self._prefill_chunk_program(C)
-        n0 = traces[0]
-        rkey = self._next_key()
-        extra = self._prefill_extra(req, i)
-        guard = self._numeric_guard
-        tail = (self._numeric_inject(1),) if guard else ()
-        fam = self._prefill_chunk_family(C)
-        if _perf.needs_cost(fam):
-            _perf.register_cost_thunk(fam, _perf.jit_cost_thunk(
-                prog, (self._params, self._bufs, ids, nvalid, *self._pools,
-                       table, lens, temps, rkey, *extra, *tail)))
-        win = _programs.ledger().compile_window(
-            self._prefill_chunk_store_key(C), family=fam,
-            replica=self.replica, device=self._device_label(),
-            store=self._store(), owner=self._model,
-            handles=(req.handle,), engine=self, cold=n0 == 0)
-        win.attach(prog, (self._params, self._bufs, ids, nvalid,
-                          *self._pools, table, lens, temps, rkey,
-                          *extra, *tail))
-        t0 = time.perf_counter()
-        bad = nstats = None
-        try:
-            with _tracing.span("serving.prefill_chunk",
-                               trace_id=req.handle.trace_id,
-                               request_id=req.handle.request_id,
-                               slot=i, chunk_start=c0, chunk_tokens=nval):
-                with _tracing.span("serving.dispatch"):
-                    if guard:
-                        tok, bad, nstats, *pools = prog(
-                            self._params, self._bufs, ids, nvalid,
-                            *self._pools, table, lens, temps, rkey,
-                            *extra, *tail)
-                    else:
-                        tok, *pools = prog(self._params, self._bufs, ids,
-                                           nvalid, *self._pools, table, lens,
-                                           temps, rkey, *extra)
-                    self._pools = tuple(pools)
-                with _tracing.span("serving.device_wait"):
-                    tok = int(np.asarray(tok)[0])
-        finally:
-            win.close(traced=traces[0] > n0)
-            self._progress_t = time.monotonic()
-        if traces[0] > n0:
-            self._m_prefill_chunk_traces.inc(traces[0] - n0)
-        elif traces[0]:
-            _perf.record(fam, time.perf_counter() - t0)
-        self._m_prefill_chunk_seconds.observe(time.perf_counter() - t0)
-        if guard:
-            _numerics.submit(f"serving/{self.replica}", ("logits",), nstats,
-                             step=self._iteration)
-            if bool(np.asarray(bad)[0]):
-                # non-finite chunk logits: fail exactly this request (the
-                # decode-lane helper does the full retire dance; the lane
-                # backfills at the next admit)
-                self._fail_numeric(i)
-                return nval
-        slot.prefilled = c0 + nval
-        if not final:
-            return nval
-        # last chunk: its sampled token is the monolithic prefill's first
-        # token — the lane goes live for the decode dispatch
-        slot.prefilled = None
-        slot.last = tok
-        slot.produced = 1
-        self._h_table[i, :] = self._scratch
-        self._h_table[i, :len(slot.table_row)] = slot.table_row
-        self._h_lens[i] = slot.length
-        self._h_temps[i] = slot.temp
-        self._h_last[i, 0] = tok
-        self._on_admitted(slot, i)
-        if self._drafter is not None:
-            self._drafter.register(i, req.prompt)
-            self._drafter.extend(i, [tok])
-        self._emit_token(slot, tok)
-        self._retire_if_done(i)
+        nval = min(C, len(req.prompt) - c0)
+        final = c0 + nval >= len(req.prompt)
+        h = req.handle
+        fl = _Flight("chunk", self._prefill_chunk_family(C), self._turn_no,
+                     [(0, i, slot, slot.gen, int(final))],
+                     self._m_prefill_chunk_seconds)
+
+        def sent():
+            slot.prefilled = c0 + nval
+            if final:
+                self._go_live(slot, i, fl.tok)
+
+        self._dispatch(
+            fl, _tracing.span("serving.prefill_chunk", trace_id=h.trace_id,
+                              request_id=h.request_id, slot=i,
+                              chunk_start=c0, chunk_tokens=nval),
+            *self._ingest_program(req, i, slot.table_row, c0, nval, C, True),
+            (h,), self._m_prefill_chunk_traces, sent)
         return nval
 
     def _step_key(self):
@@ -2869,6 +2955,12 @@ class ServingEngine:
         """Host arrays appended to the decode dispatch."""
         return ()
 
+    def _host_makes_step_inputs(self):
+        """Does the host make a decode step's inputs from the LAST step's
+        sampled token (a drafter's proposals; a subclass's per-token
+        masks)?  Then every dispatch is read back at once (depth 0)."""
+        return bool(self._spec_k)
+
     def _verify_extra(self, active):
         """Host arrays appended to the verify dispatch (reads the draft
         buffers _h_ids/_h_dlen the caller just filled)."""
@@ -2904,21 +2996,18 @@ class ServingEngine:
             inj[_numerics.nan_inject_row() % B] = v
         return inj
 
-    def _fail_numeric(self, i):
-        """Retire decode lane ``i`` with a numeric fault: exactly this
-        request errors (``status="error"``, ``handle._error`` a
-        :class:`NumericFault`), its pages free and the lane backfills at
-        the next admit — the batch's other rows are untouched."""
-        slot = self._slots[i]
+    def _fail_numeric(self, i, slot):
+        """Retire ``slot`` (decode lane ``i``'s, unless it left ahead) with
+        a numeric fault: exactly this request errors (``status="error"``,
+        ``handle._error`` a :class:`NumericFault`), its pages free and the
+        lane backfills at the next admit — the batch's other rows are
+        untouched."""
         h = slot.handle
         h._error = NumericFault(
             f"non-finite logits in decode lane {i}", site="logits",
             stream=f"serving/{self.replica}", step=self._iteration)
         self._m_numeric_faults.inc()
-        self._bm.free(slot.alloc)
-        self._release_tenant(slot.req)
-        self._slots[i] = None
-        self._clear_slot_row(i, slot)
+        self._release_lane(i, slot)
         self._finish(h, "error")
 
     def _quant_drift_tick(self):
@@ -2947,97 +3036,59 @@ class ServingEngine:
         self._m_quant_drift.set(drift)
 
     def _plain_step(self, active):
+        """Enqueue ONE decode step over the lanes ``active`` and advance
+        them on the host by what needs no sampled value (``length + 1``;
+        a lane whose budget is now dispatched to the end leaves ahead),
+        then read back what the previous turn left."""
         prog, traces = self._step_program()
-        n0 = traces[0]
-        rkey = self._step_key()
-        extra = self._step_extra()
-        guard = self._numeric_guard
-        tail = (self._numeric_inject(),) if guard else ()
-        fam = self._decode_family()
-        if _perf.needs_cost(fam):
-            _perf.register_cost_thunk(fam, _perf.jit_cost_thunk(
-                prog, (self._params, self._bufs, self._h_last, *self._pools,
-                       self._h_table, self._h_lens, self._h_temps, rkey,
-                       *extra, *tail)))
+        slots = [self._slots[i] for i in active]
         # one span per batched iteration, LINKING every active request's
         # trace id (a decode step serves many traces at once — the OTLP
         # links model, not one parent); the list is built only for a sink
         # that keeps it
         cm = _tracing.span(
-            "serving.decode_step", self._links(active),
+            "serving.decode_step", self._links(slots),
             iteration=self._iteration, batch=len(active))
+        fl = _Flight("step", self._decode_family(), self._turn_no,
+                     [(i, i, s, s.gen, 1) for i, s in zip(active, slots)])
+        fl.ahead = any(f.kind == "step" for f in self._pending)
+        # the host advances its rows while the program may still read them
+        # (a transfer in flight; the CPU backend takes numpy memory as it
+        # is): each dispatch gets rows of its own
+        rows = (self._h_table.copy(), self._h_lens.copy(),
+                self._h_temps.copy())
+        tail = (self._numeric_inject(),) if self._numeric_guard else ()
+        args = (self._params, self._bufs, self._d_last, *self._pools, *rows,
+                self._step_key(), *self._step_extra(), *tail)
+
+        def sent():
+            self._observe_dispatch(len(active))
+            if fl.ahead:
+                self._m_ahead.inc()
+            if self._depth:
+                self._d_last = _carry_last(fl.tok)
+            for i, s in zip(active, slots):
+                s.length += 1
+                s.unread += 1
+                self._h_lens[i] = s.length
+                self._leave_if_spent(i, s)
+
         # first decode dispatch = XLA compile; every active request waits
         # out the whole stall, so the window bills each of their TTFTs
-        win = _programs.ledger().compile_window(
-            self._step_store_key(), family=fam, replica=self.replica,
-            device=self._device_label(), store=self._store(),
-            owner=self._model,
-            handles=[self._slots[i].handle for i in active],
-            engine=self, cold=n0 == 0)
-        if n0 == 0:
-            win.attach(prog, (self._params, self._bufs, self._h_last,
-                              *self._pools, self._h_table, self._h_lens,
-                              self._h_temps, rkey, *extra, *tail))
-        t0 = time.perf_counter()
-        bad = nstats = None
-        try:
-            with cm:
-                with _tracing.span("serving.dispatch"):
-                    if guard:
-                        tok, bad, nstats, *pools = prog(
-                            self._params, self._bufs, self._h_last,
-                            *self._pools, self._h_table, self._h_lens,
-                            self._h_temps, rkey, *extra, *tail)
-                    else:
-                        tok, *pools = prog(self._params, self._bufs,
-                                           self._h_last, *self._pools,
-                                           self._h_table, self._h_lens,
-                                           self._h_temps, rkey, *extra)
-                    self._pools = tuple(pools)
-                with _tracing.span("serving.device_wait"):
-                    tok = np.asarray(tok)
-        finally:
-            win.close(traced=traces[0] > n0)
-            self._progress_t = time.monotonic()
-        if traces[0] > n0:
-            self._m_step_traces.inc(traces[0] - n0)
-        else:
-            _perf.record(fam, time.perf_counter() - t0)
-        self._observe_step(t0, len(active))
-        if guard:
-            _numerics.submit(f"serving/{self.replica}", ("logits",), nstats,
-                             step=self._iteration)
-            bad = np.asarray(bad)
-        with _tracing.span("serving.emit"):
-            for i in active:
-                if guard and bad[i]:
-                    # this lane's logits went non-finite: fail exactly this
-                    # request; finite lanes below emit byte-identical tokens
-                    self._fail_numeric(i)
-                    continue
-                s = self._slots[i]
-                s.length += 1
-                s.produced += 1
-                s.last = int(tok[i])
-                self._h_lens[i] = s.length
-                self._h_last[i, 0] = s.last
-                self._emit_token(s, s.last)
-                if not self._retire_if_done(i) and self._drafter is not None:
-                    # a speculative engine can route no-draft iterations
-                    # through this path: the drafter's context must keep
-                    # growing or it would never find a matching suffix again
-                    self._drafter.extend(i, [s.last])
+        self._dispatch(fl, cm, prog, traces, self._step_store_key(), args,
+                       [s.handle for s in slots], self._m_step_traces, sent)
 
-    def _links(self, active):
+    @staticmethod
+    def _links(slots):
         """The trace ids of the requests a batched step serves, as a
-        span's lazy attributes."""
-        return lambda: {"links": [self._slots[i].handle.trace_id
-                                  for i in active]}
+        span's lazy attributes (of the slots at dispatch: a lane may have
+        left by the time a sink asks)."""
+        return lambda: {"links": [s.handle.trace_id for s in slots]}
 
-    def _observe_step(self, t0, lanes):
-        """One decode / verify dispatch is over: its wall time, and the
-        counts at the same boundary."""
-        self._m_step_seconds.observe(time.perf_counter() - t0)
+    def _observe_dispatch(self, lanes):
+        """One decode / verify dispatch is made: the counts at that
+        boundary (the pool's use before a lane that leaves ahead frees its
+        pages)."""
         self._m_decode_batch.observe(lanes)
         self._m_step_page_util.observe(self._bm.utilization())
         self._iteration += 1
@@ -3083,7 +3134,8 @@ class ServingEngine:
                        self._h_table, self._h_lens, self._h_dlen,
                        self._h_temps, rkey, *extra, *tail)))
         cm = _tracing.span(
-            "serving.verify_step", self._links(active),
+            "serving.verify_step",
+            self._links([self._slots[i] for i in active]),
             iteration=self._iteration, batch=len(active), k=K,
             drafted=sum(len(drafts[i]) for i in active))
         win = _programs.ledger().compile_window(
@@ -3123,7 +3175,8 @@ class ServingEngine:
             self._m_verify_traces.inc(traces[0] - n0)
         else:
             _perf.record(fam, time.perf_counter() - t0)
-        self._observe_step(t0, len(active))
+        self._m_step_seconds.observe(time.perf_counter() - t0)
+        self._observe_dispatch(len(active))
         if guard:
             _numerics.submit(f"serving/{self.replica}", ("logits",), nstats,
                              step=self._iteration)
@@ -3131,10 +3184,10 @@ class ServingEngine:
         proposed = accepted = 0
         with _tracing.span("serving.emit"):
             for i in active:
-                if guard and bad[i]:
-                    self._fail_numeric(i)
-                    continue
                 s = self._slots[i]
+                if guard and bad[i]:
+                    self._fail_numeric(i, s)
+                    continue
                 d = drafts[i]
                 a = 0
                 while a < len(d) and accept[i, a]:
@@ -3155,7 +3208,7 @@ class ServingEngine:
                     self._h_last[i, 0] = tok
                     self._emit_token(s, tok)
                     emitted_n += 1
-                    if self._retire_if_done(i):
+                    if self._retire_if_done(i, s):
                         done = True
                         break
                 # accepted = drafts that became OUTPUT tokens: early retirement
@@ -3205,8 +3258,10 @@ class ServingEngine:
         h._events.put(("token", tok))
         self._m_tokens.inc()
 
-    def _retire_if_done(self, i):
-        slot = self._slots[i]
+    def _retire_if_done(self, i, slot):
+        """After ``slot`` (lane ``i``'s, unless it left ahead) emitted a
+        token: end its request if that token, its budget, a cancel or its
+        deadline says so."""
         h = slot.handle
         status = None
         if h.cancelled:
@@ -3220,10 +3275,7 @@ class ServingEngine:
             self._count_preemption(slot.req, "deadline")
         if status is None:
             return False
-        self._bm.free(slot.alloc)
-        self._release_tenant(slot.req)
-        self._slots[i] = None
-        self._clear_slot_row(i, slot)
+        self._release_lane(i, slot)
         self._finish(h, status)
         return True
 
@@ -3249,6 +3301,7 @@ class ServingEngine:
         self._h_lens[:] = 0
         self._h_temps[:] = 0.0
         self._h_last[:] = 0
+        self._d_last = self._h_last
         if self._spec_k:
             self._h_ids[:] = 0
             self._h_dlen[:] = 0
@@ -3350,7 +3403,8 @@ class ServingEngine:
         if self._max_queue and qd >= max(1, int(0.8 * self._max_queue)):
             reasons.append(f"queue_pressure:{qd}/{self._max_queue}")
         stamp = self._progress_t
-        busy = qd or any(s is not None for s in self._slots)
+        busy = qd or self._pending \
+            or any(s is not None for s in self._slots)
         if busy and stamp is not None and not self._compiling:
             age = time.monotonic() - stamp
             if age > self._degraded_stall_s:

@@ -245,6 +245,12 @@ class MultiTenantEngine(ServingEngine):
             else self._dev_allowed
         return (allowed,) + self._mt_args(self._h_aid)
 
+    def _host_makes_step_inputs(self):
+        """A constrained row's next mask is its FSM advanced through the
+        token just emitted (``_emit_token``): every step is read back
+        before the next is built."""
+        return True
+
     def _verify_extra(self, active):
         if not self._n_constrained:
             return (self._dev_allowed3,) + self._mt_args(self._h_aid)
