@@ -72,12 +72,18 @@ def main():
           f"  (cold={cold_bd['cold']})")
 
     led = programs.ledger()
-    led.resolve_analysis()  # trace vs backend-compile split, exe size
+    # the rows carry the build's own seconds as JAX reported them; the
+    # executable's size, flops and bytes resolve on demand (a second build)
+    led.resolve_analysis()
     print("\nprogram ledger (the /statusz 'programs' table):")
     for row in led.rows():
         print(f"  {row['family']:<22} {row['cold']:<5}"
-              f" compile {row['compile_s'] or 0:.3f}s"
-              f" backend {row.get('backend_compile_s', 0) or 0:.3f}s"
+              f" wall {row['compile_s'] or 0:.3f}s ="
+              f" trace {row.get('trace_s', 0):.3f}"
+              f" + lower {row.get('lower_s', 0):.3f}"
+              f" + xla {row.get('backend_compile_s', 0):.3f}"
+              f" + cache {row.get('cache_load_s', 0):.3f} + waits;"
+              f" {row.get('executable_bytes')} B"
               f" paid-by {str(row['trace_id'])[:8]}")
 
     engine.capture_manifest().save(manifest_path)

@@ -8,9 +8,13 @@ into single fused HLO modules; distribution is mesh + shardings over ICI/DCN.
 
 from __future__ import annotations
 
-import os as _os
+import time as _time
 
-import jax as _jax
+_IMPORT_T0 = _time.time()       # phase ``startup.import``: closed at the end
+
+import os as _os  # noqa: E402
+
+import jax as _jax  # noqa: E402
 
 # Importing this package initialises NO backend (tests/test_startup.py): a
 # chip belongs to one process, so a parent that only imports paddle_tpu must
@@ -205,3 +209,12 @@ def use_deterministic_algorithms(flag=True):
     (queryable via get_flags) — there is no runtime knob to flip, and the
     already-initialized backend could not read one anyway."""
     set_flags({"FLAGS_cudnn_deterministic": bool(flag)})
+
+
+# Last: what this process builds is on record from here on (the build
+# listeners register with the module; still no backend), and the import
+# itself is the record's first phase.
+from .observability import programs as _programs  # noqa: E402
+
+_programs.record().add_phase("startup.import", None, _IMPORT_T0,
+                             _time.time() - _IMPORT_T0)

@@ -173,6 +173,24 @@ class TrainStep:
                                    mesh=getattr(optimizer,
                                                 "_sharded_states_mesh", None))
 
+    def _first_call(self, fn, args):
+        """First dispatch of a variant = trace + XLA compile (+ async
+        enqueue), under a compile window: TrainStep variants are mints too
+        (keyed by their perf family — no model program store), and the
+        window puts the seconds JAX reports for the build on the row."""
+        win = _obs_programs.ledger().compile_window(
+            fn._perf_family, family=fn._perf_family, kind="train_step",
+            replica="-", trace_id=_tracing.current_trace_id())
+        try:
+            with _obs_programs.phase("train_step.first_call"), \
+                    _tracing.span("jit.train_step", step=self._step_count,
+                                  new_variant=True):
+                return fn(*args)
+        finally:
+            win.close()
+            self._m_compiles.inc()
+            self._m_compile_s.set(win.wall_s)
+
     # ------------------------------------------------------------------ call
     def __call__(self, *batch):
         lr_f = self._lr_value()
@@ -264,22 +282,14 @@ class TrainStep:
         # inherit this trace id, so a step and its collectives correlate in
         # the merged cross-rank timeline; in a jax.profiler trace it is the
         # host's part of a step, on the device events' clock
-        with _tracing.span("jit.train_step", step=self._step_count,
-                           new_variant=new_variant):
-            out = fn(*call_args, *vals, *tail)
         if new_variant:
-            # first dispatch of a variant = trace + XLA compile (+ async
-            # enqueue); record it and refresh the donation footprint
-            compile_s = perf_counter() - t_call
-            self._m_compiles.inc()
-            self._m_compile_s.set(compile_s)
+            out = self._first_call(fn, (*call_args, *vals, *tail))
+        else:
+            with _tracing.span("jit.train_step", step=self._step_count,
+                               new_variant=False):
+                out = fn(*call_args, *vals, *tail)
+        if new_variant:
             self._m_donated.set(self._donated_bytes())
-            # program-lifecycle ledger row: TrainStep variants are mints
-            # too (keyed by their perf family — no model program store)
-            _obs_programs.ledger().record_compile(
-                fn._perf_family, compile_s, family=fn._perf_family,
-                kind="train_step", replica="-",
-                trace_id=_tracing.current_trace_id())
             if (os.environ.get("PADDLE_TRAINSTEP_COST", "0").lower()
                     not in ("", "0", "false", "no")) or _prof_events._ACTIVE:
                 self.cost_analysis(_fn=fn)

@@ -769,12 +769,14 @@ def jit_cost_thunk(jitted, args):
 
 
 def jit_analysis_thunk(jitted, args):
-    """:func:`jit_cost_thunk` with a lifecycle split for the program
-    ledger: the re-lower is timed as a trace-seconds estimate and the
-    backend compile separately, alongside flops / bytes-accessed /
-    executable size / memory analysis — one dict per program, resolved
-    lazily (never on a scrape).  Same weakref discipline as
-    :func:`jit_cost_thunk`: a pending thunk must not pin a dead model."""
+    """:func:`jit_cost_thunk` for the program ledger: flops /
+    bytes-accessed / executable size / memory analysis — one dict per
+    program, resolved lazily (never on a scrape).  It lowers and compiles
+    the program a second time, so it reports no seconds: the build that
+    really happened is timed by JAX's own events, on the ledger's row
+    (``observability/programs.py`` ``BuildRecord``).  Same weakref
+    discipline as :func:`jit_cost_thunk`: a pending thunk must not pin a
+    dead model."""
     import weakref
 
     import jax
@@ -788,16 +790,10 @@ def jit_analysis_thunk(jitted, args):
             raise RuntimeError(
                 "compiled program was garbage-collected before its "
                 "analysis resolved")
-        t0 = perf_counter()
-        low = fn.lower(*shapes)
-        t1 = perf_counter()
-        comp = low.compile()
-        t2 = perf_counter()
+        comp = fn.lower(*shapes).compile()
         ca = comp.cost_analysis()
         mem = _memory_analysis_dict(comp)
-        return {"trace_s": t1 - t0,
-                "backend_compile_s": t2 - t1,
-                "flops": float(ca.get("flops", 0.0)),
+        return {"flops": float(ca.get("flops", 0.0)),
                 "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
                 "executable_bytes": (mem or {}).get("generated_code_bytes"),
                 "memory": mem}

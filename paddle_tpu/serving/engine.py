@@ -423,6 +423,7 @@ class ServingEngine:
                 ...
     """
 
+    @_programs.phase("serving.engine_init")
     def __init__(self, model, num_slots=4, page_size=16, max_model_len=None,
                  num_pages=None, top_k=0, top_p=1.0, prefix_sharing=False,
                  max_queue=None, seed=0, adapter=None, watchdog_s=None,
@@ -613,26 +614,30 @@ class ServingEngine:
         # padded table tails point at it (every table entry must be a valid
         # pool row; junk written there is never attended)
         self._scratch = int(num_pages)
-        self._pools = tuple(self._adapter.init_pools(num_pages + 1))
-        self._params, self._bufs = self._adapter.params_and_buffers()
-        if device is not None:
-            # dp-replica placement: commit this replica's params/buffers and
-            # page pools to its device — uncommitted per-step host arrays
-            # (table/lens/ids) follow the committed operands, so every
-            # dispatch of this engine runs there
-            self._params = jax.device_put(self._params, device)
-            self._bufs = jax.device_put(self._bufs, device)
-            self._pools = jax.device_put(self._pools, device)
-        elif self._mesh is not None:
-            # mp placement: commit weights with their Megatron annotations
-            # and pools with the KV-head sharding — GSPMD propagates the
-            # layouts through the unchanged adapter closures, so every
-            # program family compiles ONCE as a single SPMD program (not
-            # per shard), and the uncommitted host arrays (table/lens/
-            # ids/temps) replicate onto the mesh automatically
-            self._params = self._shard_tree(self._params)
-            self._bufs = self._shard_tree(self._bufs)
-            self._pools = self._shard_pools(self._pools)
+        # dp-replica placement (device=): commit this replica's params/
+        # buffers and page pools to its device — uncommitted per-step host
+        # arrays (table/lens/ids) follow the committed operands, so every
+        # dispatch of this engine runs there.  mp placement (mesh=): commit
+        # weights with their Megatron annotations and pools with the
+        # KV-head sharding — GSPMD propagates the layouts through the
+        # unchanged adapter closures, so every program family compiles
+        # ONCE as a single SPMD program (not per shard), and the
+        # uncommitted host arrays (table/lens/ids/temps) replicate onto
+        # the mesh automatically
+        with _programs.phase("serving.engine_init.pools"):
+            self._pools = tuple(self._adapter.init_pools(num_pages + 1))
+            if device is not None:
+                self._pools = jax.device_put(self._pools, device)
+            elif self._mesh is not None:
+                self._pools = self._shard_pools(self._pools)
+        with _programs.phase("serving.engine_init.weights"):
+            self._params, self._bufs = self._adapter.params_and_buffers()
+            if device is not None:
+                self._params = jax.device_put(self._params, device)
+                self._bufs = jax.device_put(self._bufs, device)
+            elif self._mesh is not None:
+                self._params = self._shard_tree(self._params)
+                self._bufs = self._shard_tree(self._bufs)
         if self._spill is not None:
             # transport callables close over self: every spill/resurrect
             # reads the CURRENT pool tuple, so donation rebinds and
@@ -1194,22 +1199,24 @@ class ServingEngine:
             raise RuntimeError("engine previously failed") from self._error
         if self._started:
             return self
-        self._modes = [(m, m.training)
-                       for m in self._model.sublayers(include_self=True)]
-        self._model.eval()
-        self._stop_evt.clear()
-        self._draining = False
-        self._engine_restarts = 0   # a fresh start() is a fresh budget
-        self._progress_t = time.monotonic()
-        self._thread = threading.Thread(
-            target=self._loop,
-            name=f"paddle-serving-engine[{self.replica}]", daemon=True)
-        self._started = True
-        self._thread.start()
-        self._start_observability()
+        with _programs.phase("serving.engine_start"):
+            self._modes = [(m, m.training)
+                           for m in self._model.sublayers(include_self=True)]
+            self._model.eval()
+            self._stop_evt.clear()
+            self._draining = False
+            self._engine_restarts = 0   # a fresh start() is a fresh budget
+            self._progress_t = time.monotonic()
+            self._thread = threading.Thread(
+                target=self._loop,
+                name=f"paddle-serving-engine[{self.replica}]", daemon=True)
+            self._started = True
+            self._thread.start()
+            self._start_observability()
         return self
 
     # ------------------------------------------------------------- warmup
+    @_programs.phase("serving.warmup")
     def warmup(self, manifest):
         """Replay a :class:`~paddle_tpu.observability.programs
         .WarmupManifest` ahead of admission: every engine-owned key in the

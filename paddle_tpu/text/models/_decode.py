@@ -188,16 +188,19 @@ def decode_loop(model, fwd, ids0, max_new_tokens, init_cache,
         base = jax.random.key(seed if seed is not None else 0)
         key0 = jax.random.fold_in(base, 0)
         t_loop = perf_counter()
-        nxt, cache = prefill(params, bufs, jnp.asarray(ids0), cache, key0)
-        if not warm and store is not None:
-            # the prefill dispatch above paid this key's trace+compile
-            # (the step program compiles asynchronously under the same
-            # episode); attribute the wall to the ambient trace id
-            _programs.ledger().record_compile(
-                program_key, perf_counter() - t_loop,
-                family="generate.decode", kind="generate", store=store,
-                owner=model, replica="-",
-                trace_id=_tracing.current_trace_id())
+        # a cold key's prefill dispatch pays its trace+compile (the step
+        # program builds under the same episode, at its first step): the
+        # compile window puts the wall and the build's own seconds on the
+        # row, under the ambient trace id
+        win = _programs.ledger().compile_window(
+            program_key, family="generate.decode", kind="generate",
+            store=store, owner=model, replica="-",
+            trace_id=_tracing.current_trace_id(),
+            cold=not warm and store is not None)
+        try:
+            nxt, cache = prefill(params, bufs, jnp.asarray(ids0), cache, key0)
+        finally:
+            win.close()
         if store is not None and _perf.needs_cost("generate.decode"):
             # per-token roofline attribution for the generate() path: one
             # representative step program's cost (shapes captured here,

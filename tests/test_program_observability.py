@@ -332,16 +332,324 @@ def test_scrape_bounded_under_open_compile_window(engine):
 
 
 def test_analysis_resolves_off_scrape_path(engine, model):
+    """The build's seconds are on the row since its window closed (the
+    suite's persistent cache is off: every executable was compiled);
+    what resolves on demand is size, flops and bytes, and no seconds."""
     led = programs.ledger()
     store = program_store(model)
-    led.resolve_analysis()
-    rows = led.rows(store=store)
-    resolved = [r for r in rows if "backend_compile_s" in r]
-    assert resolved, rows
-    for r in resolved:
-        assert r["backend_compile_s"] > 0
-        assert r["trace_s"] >= 0
+    measured = [r for r in led.rows(store=store) if "backend_compile_s" in r]
+    assert len(measured) == len(store), measured
+    for r in measured:
+        assert r["backend_compile_s"] > 0 and r["trace_s"] > 0
+        assert r["lower_s"] > 0 and r["cache_load_s"] == 0
+        assert r["cache_hit"] is False
+        assert r["trace_s"] + r["lower_s"] + r["backend_compile_s"] \
+            <= r["compile_s"]
+    assert led.resolve_analysis() >= 1
+    resolved = [r for r in led.rows(store=store) if "flops" in r]
+    assert resolved
+    for r, was in zip(resolved, measured):
         assert r["flops"] is None or r["flops"] >= 0
+        assert r["backend_compile_s"] == was["backend_compile_s"]
+
+
+# ======================================= the build record: what JAX reported
+def _fresh_jit(scale=2.0, name="probe_fn"):
+    """A jitted function no cache of this process has seen: a new function
+    object every call (the same HLO for the same ``scale``)."""
+    import jax
+    import jax.numpy as jnp
+
+    def fn(x):
+        return jnp.tanh(x @ x.T).sum() * scale
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
+def _x():
+    import jax.numpy as jnp
+
+    return jnp.ones((7, 5), jnp.float32)
+
+
+def test_window_owns_its_threads_builds():
+    """Events inside a window land on its row and not under ``fun_name``;
+    a jit outside any window is tallied under its function's name."""
+    led = programs.ledger()
+    x = _x()
+    t0 = time.time()
+    _fresh_jit(3.0, "outside_any_window")(x)
+    win = led.compile_window(("win_owns",), family="probe")
+    _fresh_jit(4.0, "inside_the_window")(x)
+    win.close()
+    got = led.builds(since=t0)
+    rows = got["programs"]
+    assert "inside_the_window" not in rows
+    own = rows[repr(("win_owns",))]
+    assert own["n"] == 1 and own["hits"] == 0
+    assert own["trace_s"] > 0 and own["lower_s"] > 0 and own["compile_s"] > 0
+    loose = rows["outside_any_window"]
+    assert loose["n"] == 1 and loose["trace_s"] > 0 and loose["compile_s"] > 0
+    assert got["executables"] == 2 and got["cache_hits"] == 0
+    r = led.entry(("win_owns",)).row()
+    assert r["trace_s"] == pytest.approx(own["trace_s"], abs=1e-5)
+    assert r["backend_compile_s"] == pytest.approx(own["compile_s"], abs=1e-5)
+    assert r["cache_hit"] is False and r["cache_load_s"] == 0
+    assert r["trace_s"] + r["lower_s"] + r["backend_compile_s"] \
+        <= r["compile_s"]
+    reg = prof_metrics.get_registry()
+    assert reg.get("programs.build_seconds").labels(phase="trace").value > 0
+    assert reg.get("programs.built_total").labels(
+        source="compiled").value >= 2
+
+
+def test_another_threads_builds_are_not_the_windows():
+    led = programs.ledger()
+    x = _x()
+    t0 = time.time()
+    win = led.compile_window(("win_alone",), family="probe")
+    th = threading.Thread(
+        target=lambda: _fresh_jit(5.0, "on_another_thread")(x))
+    th.start()
+    th.join(timeout=120)
+    assert not th.is_alive()
+    win.close()
+    rows = led.builds(since=t0)["programs"]
+    assert rows["on_another_thread"]["n"] == 1
+    assert repr(("win_alone",)) not in rows
+    r = led.entry(("win_alone",)).row()
+    assert r["trace_s"] == r["lower_s"] == r["backend_compile_s"] == 0
+    assert r["cache_hit"] is False and r["compile_s"] > 0
+
+
+def test_persistent_cache_hit_reads_as_a_load(tmp_path):
+    """With a compile cache of its own, the second build of the same
+    program in this process is a hit: nothing compiled, the whole of
+    ``backend_compile_duration`` is what the load cost."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    led = programs.ledger()
+    x = _x()
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    prev = {k: getattr(jax.config, k) for k in keys}
+    try:
+        for k, v in zip(keys, (True, str(tmp_path / "cache"), 0.0, 0)):
+            jax.config.update(k, v)
+        cc.reset_cache()
+        t0 = time.time()
+        for i in range(2):
+            win = led.compile_window(("cached", i), family="probe")
+            _fresh_jit(6.0, "cached_twice")(x)
+            win.close()
+    finally:
+        for k, v in prev.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    miss, hit = (led.entry(("cached", i)).row() for i in range(2))
+    assert miss["cache_hit"] is False and miss["backend_compile_s"] > 0
+    assert miss["cache_load_s"] == 0
+    assert hit["cache_hit"] is True and hit["cache_load_s"] > 0
+    assert hit["backend_compile_s"] == 0
+    assert hit["trace_s"] > 0 and hit["lower_s"] > 0    # a hit saves neither
+    got = led.builds(since=t0)
+    assert got["executables"] == 2 and got["cache_hits"] == 1
+    assert got["programs"][repr(("cached", 1))]["hits"] == 1
+
+
+def test_builds_until_leaves_out_what_ended_later():
+    led = programs.ledger()
+    x = _x()
+    t0 = time.time()
+    _fresh_jit(7.0, "before_the_mark")(x)
+    mark = time.time()
+    _fresh_jit(8.0, "after_the_mark")(x)
+    early = led.builds(since=t0, until=mark)
+    assert "before_the_mark" in early["programs"]
+    assert "after_the_mark" not in early["programs"]
+    assert early["executables"] == 1
+    late = led.builds(since=mark)
+    assert "after_the_mark" in late["programs"]
+    assert "before_the_mark" not in late["programs"]
+    both = led.builds(since=t0)
+    for kind, s in both["seconds"].items():
+        assert s == pytest.approx(
+            early["seconds"][kind] + late["seconds"][kind])
+
+
+def test_callee_builds_merge_into_their_caller():
+    """JAX says when a build starts too, so what runs inside what is known:
+    a trace or a lowering inside another build never reaches the list (the
+    looped decoder's step program holds tens of thousands of jitted
+    callees' traces), its seconds kept by kind in the build around it; an
+    executable built inside a trace is an entry of its own and its seconds
+    come off."""
+    rec = programs.BuildRecord(limit=8)
+    rec.enter("trace")                      # the caller's trace starts
+    for _ in range(5_000):
+        rec.enter("trace")
+        rec.built("trace", 1e-6, "callee")
+    for kind in ("trace", "lower", "compile"):      # an eager constant
+        rec.enter(kind)
+        rec.built(kind, 0.002, "eager_constant")
+    rec.built("trace", 0.1, "caller")
+    rec.enter("lower")
+    rec.enter("trace")                      # a lowering rule, traced
+    rec.built("trace", 0.003, "a_lowering_rule")
+    rec.built("lower", 0.05, "caller")
+    got = rec.builds()
+    assert got["events"] == 3 and got["folded"] == 0
+    assert set(got["programs"]) == {"caller", "eager_constant"}
+    caller = got["programs"]["caller"]
+    # the caller's 100 ms less the constant's 6, which holds 4 of
+    # tracing and lowering merged back in, and the rule's 3
+    assert caller["trace_s"] == pytest.approx(0.1 - 0.006 + 0.002 + 0.003)
+    assert caller["lower_s"] == pytest.approx(0.002 + 0.05 - 0.003)
+    assert caller["n"] == 0 and got["executables"] == 1
+    assert got["programs"]["eager_constant"] == {
+        "n": 1, "hits": 0, "trace_s": 0.0, "lower_s": 0.0,
+        "compile_s": 0.002, "cache_load_s": 0.0}
+    assert sum(got["seconds"].values()) == pytest.approx(0.15)
+    # a window's split counts every second once, merged or not, and a
+    # cache hit announced inside the compile makes it a load
+    led = programs.ProgramLedger(record=rec)
+    win = led.compile_window(("merged",), family="probe")
+    rec.enter("trace")
+    rec.enter("trace")
+    rec.built("trace", 0.005, "callee")
+    rec.built("trace", 0.02, "caller")
+    rec.enter("compile")
+    rec.cache_hit()
+    rec.built("compile", 0.01, "caller")
+    win.close()
+    ent = led.entry(("merged",))
+    assert ent.trace_s == pytest.approx(0.02) and ent.cache_hit is True
+    assert ent.cache_load_s == 0.01 and ent.backend_compile_s == 0.0
+    # a phase takes what ran inside it off its self time and keeps it
+    with rec.phase("probe.holds_a_build"):
+        time.sleep(0.02)
+        rec.enter("trace")
+        rec.built("trace", 0.015, "inside")
+    ph = rec.builds()["phases"]["probe.holds_a_build"]
+    assert ph["self_s"] == pytest.approx(ph["seconds"] - 0.015)
+    assert "inside" in rec.builds()["programs"]
+    assert rec._stack() == []
+
+
+def test_record_is_bounded_and_its_totals_hold():
+    rec = programs.BuildRecord(limit=8)
+    rec.add_phase("startup.import", None, time.time() - 0.5, 0.25)
+    for i in range(30):
+        rec.built(("trace", "lower", "compile")[i % 3], 0.0005, f"fn{i // 3}")
+    assert len(rec._events) == 8
+    got = rec.builds()
+    assert got["events"] == 8 and got["folded"] == 23
+    # a phase is folded by its name, never lost
+    assert got["phases"]["startup.import"] == {
+        "n": 1, "seconds": 0.25, "self_s": 0.25, "parent": None}
+    assert got["seconds"] == pytest.approx(
+        {"trace": 0.005, "lower": 0.005, "compile": 0.005, "cache_load": 0.0})
+    assert got["executables"] == 10
+    assert got["programs"]["(folded)"]["n"] + sum(
+        r["n"] for k, r in got["programs"].items() if k != "(folded)") == 10
+    # an interval that cuts through what was folded cannot split it
+    cut = rec.builds(since=rec._events[0].end)
+    assert "(folded)" not in cut["programs"] and cut["events"] == 8
+    assert not cut["phases"]
+
+
+def test_compile_seconds_is_the_build_not_the_wall():
+    """A window that also waited on a result (since PR 33 the engine's
+    closes after a read-back): the counter gets what building cost."""
+    led = programs.ledger()
+    reg = prof_metrics.get_registry()
+    x = _x()
+    labels = dict(family="probe_waits", replica="w")
+    win = led.compile_window(("win_waits",), family="probe_waits",
+                             replica="w")
+    _fresh_jit(9.0, "then_it_waits")(x).block_until_ready()
+    time.sleep(0.3)                     # the read-back's stand-in
+    win.close()
+    r = led.entry(("win_waits",)).row()
+    built = r["trace_s"] + r["lower_s"] + r["backend_compile_s"] \
+        + r["cache_load_s"]
+    assert 0 < built <= r["compile_s"] - 0.3
+    assert win.wall_s == pytest.approx(r["compile_s"], abs=1e-5)
+    assert reg.get("programs.compile_seconds").labels(
+        **labels).value == pytest.approx(built, abs=1e-5)
+
+
+def test_phase_nests_with_self_times_and_is_a_span():
+    from paddle_tpu.observability import tracing
+
+    led = programs.ledger()
+    x = _x()
+    tr = tracing.Tracer().start()
+    t0 = time.time()
+    try:
+        with programs.phase("probe.outer"):
+            time.sleep(0.05)
+            with programs.phase("probe.outer.inner"):
+                time.sleep(0.05)
+                _fresh_jit(10.0, "inside_a_phase")(x)
+    finally:
+        tr.stop()
+    got = led.builds(since=t0)
+    outer, inner = (got["phases"][n]
+                    for n in ("probe.outer", "probe.outer.inner"))
+    assert inner["parent"] == "probe.outer" and outer["parent"] is None
+    assert outer["n"] == inner["n"] == 1
+    assert outer["seconds"] >= inner["seconds"] >= 0.05
+    assert outer["self_s"] == pytest.approx(
+        outer["seconds"] - inner["seconds"], abs=1e-6)
+    built = sum(got["seconds"].values())
+    assert built > 0
+    assert inner["self_s"] == pytest.approx(inner["seconds"] - built,
+                                            abs=1e-6)
+    # phases and builds add up to the outer wall: nothing counted twice
+    assert outer["self_s"] + inner["self_s"] + built == pytest.approx(
+        outer["seconds"], abs=1e-6)
+    reg = prof_metrics.get_registry()
+    assert reg.get("startup.phase_seconds").labels(
+        phase="probe.outer").value == pytest.approx(outer["seconds"])
+    # the same region is a span of the armed tracer, parent and child
+    (so,), (si,) = tr.find("probe.outer"), tr.find("probe.outer.inner")
+    assert si.parent_id == so.span_id and si.trace_id == so.trace_id
+    # and a decorator, for what wraps a whole function
+    @programs.phase("probe.decorated")
+    def body(a, b=2):
+        return a + b
+    assert body(1, b=3) == 4
+    assert led.builds(since=t0)["phases"]["probe.decorated"]["n"] == 1
+
+
+def test_engine_phases_and_start_up_on_statusz(model):
+    """Construction and start leave their phases behind, the pools' and
+    the weights' as children; /statusz prints the record under start_up."""
+    from paddle_tpu.serving import ServingEngine
+
+    led = programs.ledger()
+    t0 = time.time()
+    eng = ServingEngine(model, num_slots=2, page_size=PS,
+                        max_model_len=MAXLEN)
+    eng.start()
+    eng.stop()
+    ph = led.builds(since=t0)["phases"]
+    init = ph["serving.engine_init"]
+    for child in ("serving.engine_init.pools", "serving.engine_init.weights"):
+        assert ph[child]["parent"] == "serving.engine_init"
+        assert 0 <= ph[child]["seconds"] <= init["seconds"]
+    assert init["self_s"] <= init["seconds"] - sum(
+        ph[c]["seconds"] for c in ph if c.startswith("serving.engine_init."))\
+        + 1e-6
+    assert ph["serving.engine_start"]["seconds"] > 0
+    sec = led.statusz()["start_up"]
+    assert set(sec["seconds"]) == set(programs.BuildRecord.KINDS)
+    assert "serving.engine_init" in sec["phases"]
+    assert len(sec["programs"]) <= 32 and sec["executables"] >= 1
+    json.dumps(sec)
 
 
 # ================================================== manifest: warm restarts
@@ -513,6 +821,11 @@ def test_train_step_mints_ledger_rows():
            and r["kind"] == "train_step"]
     assert new, led.rows()
     assert new[0]["compile_s"] > 0
+    # the variant's first call ran under a compile window and a phase
+    assert new[0]["trace_s"] > 0 and new[0]["backend_compile_s"] > 0
+    assert new[0]["trace_s"] + new[0]["lower_s"] \
+        + new[0]["backend_compile_s"] <= new[0]["compile_s"]
+    assert "train_step.first_call" in led.builds()["phases"]
 
 
 @pytest.mark.slow

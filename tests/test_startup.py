@@ -26,6 +26,9 @@ def test_import_initialises_no_backend():
         "import benchmarks.raw_resnet50, benchmarks.raw_bert\n"
         "import jax\n"
         "from jax._src import xla_bridge\n"
+        "from paddle_tpu.observability import programs\n"
+        "print(programs.ledger().builds()['phases']['startup.import'])\n"
+        "print(len(jax._src.monitoring.get_event_duration_listeners()))\n"
         "print(list(xla_bridge._backends))\n"
         "print(jax.config.jax_compilation_cache_dir)\n")
     env = {k: v for k, v in os.environ.items()
@@ -34,8 +37,13 @@ def test_import_initialises_no_backend():
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, cwd=REPO, timeout=120)
     assert r.returncode == 0, r.stderr
-    backends, cache_dir = r.stdout.strip().splitlines()[-2:]
+    phase, listeners, backends, cache_dir = r.stdout.strip().splitlines()[-4:]
     assert backends == "[]"
+    # the import is the record's first phase, and the one build listener is
+    # registered by it: the process's first program will be counted
+    phase = eval(phase)     # a dict of numbers this test's child printed
+    assert phase["n"] == 1 and phase["seconds"] == phase["self_s"] > 0
+    assert listeners == "1"
     assert cache_dir == os.path.join(REPO, ".jax_cache")
 
 
