@@ -115,7 +115,7 @@ class PipelineLayer(Layer):
 
     def forward(self, x):
         if self._recompute_interval:
-            from ..utils import recompute as _rc
+            from ..utils.recompute import recompute as _recompute
 
             i, fns = 0, self.run_function
             while i < len(fns):
@@ -124,7 +124,7 @@ class PipelineLayer(Layer):
                     for layer, ffn in _fns:
                         h = ffn(layer, h) if ffn is not None else layer(h)
                     return h
-                x = _rc.recompute(run_span, x)
+                x = _recompute(run_span, x)
                 i = j
             return x
         for layer, ffn in self.run_function:

@@ -7,14 +7,34 @@ the traced segment's activations are dropped and recomputed in the backward
 pass, with RNG replay free because keys are values.  The wrapper keeps the
 reference call shape ``recompute(fn, *args)`` and works both eagerly (tape
 node wrapping the remat'd function) and under to_static/TrainStep traces.
+
+Kept by default: a flash attention kernel's output and log-sum-exp
+(``ops.flash_attention.RESIDUAL_NAMES``), because a region that drops them
+runs the forward kernel a second time for two small tensors; a region that
+holds no such kernel keeps nothing but its arguments.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 
+from ....ops.flash_attention import RESIDUAL_NAMES
+from ....profiler import metrics as _metrics
 from ....tensor.dispatch import apply as _apply
 from ....tensor.tensor import Tensor
+
+# the default policy: what only a flash forward kernel can make again
+_KEEP_FLASH_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    *RESIDUAL_NAMES)
+
+_m_regions = _metrics.counter(
+    "recompute.regions_traced",
+    "regions traced under fleet.utils.recompute, by what they keep (policy = "
+    "flash_residuals: the default; caller: an explicit checkpoint_policy; "
+    "none: checkpoint_policy=None spelled out); counted when a region is "
+    "traced, not when it runs")
 
 
 def recompute(function, *args, **kwargs):
@@ -22,20 +42,26 @@ def recompute(function, *args, **kwargs):
 
     preserve_rng_state / use_reentrant kwargs are accepted for parity; RNG
     correctness is structural (keys thread through the trace).
+
+    Without ``checkpoint_policy=`` the region keeps the flash kernels'
+    named residuals and recomputes the rest: 68 MB a layer at B=2, S=4,096
+    against a 4.6 ms kernel run again.  A ``checkpoint_policy=`` given is
+    ``jax.checkpoint``'s ``policy`` as it stands (``None``: keep nothing).
     """
     kwargs.pop("preserve_rng_state", None)
     kwargs.pop("use_reentrant", None)
-    policy = kwargs.pop("checkpoint_policy", None)
-
-    import functools
+    if "checkpoint_policy" in kwargs:
+        policy = kwargs.pop("checkpoint_policy")
+        kept = "none" if policy is None else "caller"
+    else:
+        policy, kept = _KEEP_FLASH_RESIDUALS, "flash_residuals"
 
     tensor_idx = [i for i, a in enumerate(args) if isinstance(a, Tensor)]
     consts = {i: a for i, a in enumerate(args) if i not in set(tensor_idx)}
-    ckpt = jax.checkpoint if policy is None else functools.partial(jax.checkpoint,
-                                                                   policy=policy)
 
-    @ckpt
+    @functools.partial(jax.checkpoint, policy=policy)
     def inner(*tvals):
+        _m_regions.inc(policy=kept)
         call = []
         it = iter(tvals)
         for i in range(len(args)):

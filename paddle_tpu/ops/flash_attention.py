@@ -24,6 +24,13 @@ Heads of two widths: q and k share one head size ``d``, v and the output
 have their own ``dv`` (latent attention: 192 and 128).  Each is padded to
 its own lane multiple, v never to q's size; at ``dv == d`` every call is
 the one it was.
+
+Both forward rules NAME the two residuals only the kernel can make, the
+output and the log-sum-exp (``RESIDUAL_NAMES``).  Outside a
+``jax.checkpoint`` a name is the identity and lowers to nothing; inside
+one, a policy that saves these names (``fleet.utils.recompute``'s default)
+keeps both and the forward kernel drops out of the recomputed pass: they
+are the cheapest things in a layer to hold and the dearest to make again.
 """
 
 from __future__ import annotations
@@ -33,8 +40,21 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..profiler import metrics as _metrics
+
+# the names a forward rule gives (o, lse): what a checkpoint policy saves to
+# keep the forward kernel out of a recomputed region
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
+
+_m_forward_rules = _metrics.counter(
+    "flash.forward_rules_traced",
+    "forward rules of the flash custom VJPs traced (a kernel call that is "
+    "differentiated, its two residuals named); counted when a program is "
+    "traced, not when it runs")
 
 # swept on v5e at S=4096: (512, 1024) beats XLA's fused attention 1.7x;
 # blocks shrink adaptively for shorter sequences
@@ -413,9 +433,23 @@ def _flash(q, k, v, scale, causal, block_q, block_k, causal_offset):
     return _flash_fwd(q, k, v, scale, causal, block_q, block_k, causal_offset)
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, causal_offset):
+def _fwd_named(q, k, v, scale, causal, block_q, block_k, causal_offset):
+    """``(o, lse)`` of the forward kernel under ``RESIDUAL_NAMES``, for both
+    forward rules, which hand them on as outputs AND as residuals: what
+    follows the kernel in a recomputed region then reads the saved ones.
+    The log-sum-exp is named as ``[BH, S]``: the kernel's ``[BH, S, 1]``
+    is held 128 lanes wide on the chip (134 MB where 1 MB is data at
+    BH=64, S=4,096), and outside a checkpoint the two reshapes cancel."""
+    _m_forward_rules.inc()
     o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
                         causal_offset, with_lse=True)
+    rows = checkpoint_name(lse.reshape(lse.shape[:2]), RESIDUAL_NAMES[1])
+    return checkpoint_name(o, RESIDUAL_NAMES[0]), rows.reshape(lse.shape)
+
+
+def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, causal_offset):
+    o, lse = _fwd_named(q, k, v, scale, causal, block_q, block_k,
+                        causal_offset)
     return o, (q, k, v, o, lse)
 
 
@@ -435,8 +469,8 @@ def _flash_lse(q, k, v, scale, causal, block_q, block_k, causal_offset):
 
 
 def _flash_lse_vjp_fwd(q, k, v, scale, causal, block_q, block_k, causal_offset):
-    o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
-                        causal_offset, with_lse=True)
+    o, lse = _fwd_named(q, k, v, scale, causal, block_q, block_k,
+                        causal_offset)
     return (o, lse), (q, k, v, o, lse)
 
 

@@ -21,7 +21,8 @@ engine).
   ``bias_update_speed`` (not a published key; DeepSeek-V3 trained at 0.001,
   0 leaves the bias alone) balances the experts' load while training.
 - ``recompute=True`` checkpoints each decoder layer
-  (``fleet.utils.recompute``).
+  (``fleet.utils.recompute``, which keeps the flash kernel's output and
+  log-sum-exp and recomputes the rest; the model names no policy).
 """
 
 from __future__ import annotations

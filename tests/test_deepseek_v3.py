@@ -598,3 +598,86 @@ def test_the_flash_kernels_lie_under_mla_attention():
     for s in scopes[1:]:
         assert s.startswith("transpose(jvp(forward_loss))")
         assert "mla_attention" in s and "flash_fwd" not in s
+
+
+# ------------------------------------------- what a recomputed layer keeps
+def _toy_gradients(recompute, policy=None):
+    """``(flash_fwd kernels in jax.grad's jaxpr, gradients by name)`` of a
+    toy model built for this call: JAX caches a custom VJP's traced rules
+    by function, so every comparison makes its functions afresh.  The model
+    names no policy (``deepseek_v3.py`` calls ``_recompute(layer, ...)``),
+    so one is handed in through the name it calls."""
+    import functools
+
+    from paddle_tpu.distributed.fleet.utils.recompute import recompute
+    from paddle_tpu.framework.state import no_grad_ctx
+
+    spans = importlib.import_module("tests.test_program_spans")
+    ds = importlib.import_module("paddle_tpu.text.models.deepseek_v3")
+    paddle.seed(0)
+    model = DeepseekV3ForCausalLM(recompute=recompute, **TOY)
+    model.train()
+    ids = paddle.to_tensor(np.random.RandomState(0).randint(0, 97, (1, 256)))
+    params = {k: p._value for k, p in model.named_parameters()}
+    buffers = {k: b._value for k, b in model.named_buffers()}
+
+    def loss(p):
+        with no_grad_ctx(), model.bind(p, buffers):
+            return model(ids, labels=ids)._value
+
+    through = recompute if policy is None else functools.partial(
+        recompute, checkpoint_policy=policy)
+    with mock.patch.object(ds, "_recompute", through):
+        scopes = spans._pallas_scopes(jax.grad(loss), params)
+        grads = jax.jit(jax.grad(loss))(params)
+    return sum("flash_fwd" in s for s in scopes), grads
+
+
+@pytest.mark.parametrize("policy,forwards_a_layer", [
+    (None, 1), (jax.checkpoint_policies.nothing_saveable, 2)],
+    ids=["default", "nothing_saveable"])
+def test_a_recomputed_layer_runs_the_flash_forward(interpreted, policy,
+                                                   forwards_a_layer):
+    """Once under ``recompute()``'s default policy (the kernel's output and
+    log-sum-exp are kept, so the recomputed pass drops the call), twice
+    where a caller's policy keeps nothing; the gradients are those of the
+    model without recomputation, leaf by leaf."""
+    layers = TOY["num_hidden_layers"]
+    forwards, got = _toy_gradients(True, policy)
+    assert forwards == forwards_a_layer * layers
+    plain_forwards, want = _toy_gradients(False)
+    assert plain_forwards == layers
+    assert sorted(got) == sorted(want)
+    for name in want:
+        _close(got[name], want[name], 1e-5)
+
+
+def test_a_recomputed_layer_saves_the_two_names_and_its_arguments(
+        interpreted, capsys):
+    """``print_saved_residuals`` of one recomputed decoder layer: what is
+    not an argument (or a weight, a constant of this closure) is the
+    kernel's output, lane-padded, and its log-sum-exp as ``[BH, S]``."""
+    from paddle_tpu.distributed.fleet.utils.recompute import recompute
+    from paddle_tpu.text.models.deepseek_v3 import DeepseekV3DecoderLayer
+
+    paddle.seed(0)
+    cfg = DeepseekV3Config(**TOY)
+    layer = DeepseekV3DecoderLayer(cfg, 1)
+    assert layer.sparse
+    cos, sin = (t._value for t in _rope(cfg, 256))
+    before = prof_metrics.counter("flash.forward_rules_traced").total()
+
+    def out_sum(x, cos, sin):
+        y, _ = recompute(layer, *map(paddle.to_tensor, (x, cos, sin)))
+        return jnp.sum(y._value)
+
+    kept = importlib.import_module("tests.test_distributed")._kept(
+        capsys, out_sum, jnp.ones((1, 256, 32), jnp.float32), cos, sin)
+    heads, lanes = cfg.num_attention_heads, 128
+    assert len(kept) == 2, kept
+    lse, = [line for line in kept if f"named '{fa.RESIDUAL_NAMES[1]}'" in line]
+    assert lse.startswith(f"f32[{heads},256] ")
+    out, = [line for line in kept if line is not lse]
+    assert out.startswith(f"f32[{heads},256,{lanes}] ")
+    assert prof_metrics.counter(
+        "flash.forward_rules_traced").total() == before + 1

@@ -191,6 +191,124 @@ def test_recompute_matches_plain():
     assert x.grad is not None
 
 
+def _regions_traced():
+    from paddle_tpu.profiler import metrics
+
+    counter = metrics.counter("recompute.regions_traced")
+    return {k: counter.get(policy=k) or 0
+            for k in ("flash_residuals", "caller", "none")}
+
+
+def _kept(capsys, fn, *args):
+    """``print_saved_residuals``' lines for what ``fn`` keeps beside its
+    arguments and the weights its closure holds."""
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(fn, *args)
+    return [line for line in capsys.readouterr().out.splitlines()
+            if " from the argument " not in line
+            and not line.endswith(" from a constant")]
+
+
+def _mlp_and_input():
+    paddle.seed(9)
+    m = nn.Sequential(nn.Linear(8, 32), nn.GELU(), nn.Linear(32, 8))
+    return m, jnp.asarray(np.random.RandomState(0).randn(4, 8), jnp.float32)
+
+
+def test_recompute_without_a_flash_kernel_keeps_only_its_arguments(capsys):
+    import paddle_tpu.distributed.fleet as fleet
+
+    m, x = _mlp_and_input()
+    assert _kept(capsys, lambda x: jnp.sum(
+        fleet.recompute(m, paddle.to_tensor(x))._value), x) == []
+
+
+@pytest.mark.parametrize("given,label,kept", [
+    ({}, "flash_residuals", 1),
+    ({"checkpoint_policy": jax.checkpoint_policies.nothing_saveable},
+     "caller", 0),
+    ({"checkpoint_policy": jax.checkpoint_policies.everything_saveable},
+     "caller", None),
+    ({"checkpoint_policy": None}, "none", 0)],
+    ids=["default", "nothing_saveable", "everything_saveable", "None"])
+def test_recompute_keeps_the_flash_names_unless_the_caller_says(
+        capsys, given, label, kept):
+    """A value under one of ``RESIDUAL_NAMES`` is kept by the default policy
+    and by no other: an explicit ``checkpoint_policy=`` wins, ``None``
+    spelled out being ``jax.checkpoint``'s own (nothing kept); the counter
+    says which by label."""
+    import paddle_tpu.distributed.fleet as fleet
+    from paddle_tpu.ops.flash_attention import RESIDUAL_NAMES
+    from paddle_tpu.tensor.dispatch import apply
+
+    m, x = _mlp_and_input()
+
+    def region(h):
+        return apply(lambda v: jnp.sin(jax.ad_checkpoint.checkpoint_name(
+            jnp.cos(v), RESIDUAL_NAMES[0])), m(h), op_name="named")
+
+    before = _regions_traced()
+    lines = _kept(capsys, lambda x: jnp.sum(fleet.recompute(
+        region, paddle.to_tensor(x), **given)._value), x)
+    after = _regions_traced()
+    assert {k: after[k] - before[k] for k in after} == {
+        k: int(k == label) for k in after}
+    if kept is None:
+        assert len(lines) > 1
+    else:
+        assert len(lines) == kept, lines
+        assert all(line.startswith("f32[4,8] ") for line in lines)
+
+
+@pytest.mark.parametrize("path", ["eager", "train_step", "sequential",
+                                  "pipeline_layer"])
+def test_recompute_counts_a_region_on_every_path(path):
+    """``recompute.regions_traced{policy=flash_residuals}``: one a call on
+    the tape, one a region of a ``TrainStep``'s trace, one a span of
+    ``recompute_sequential`` and of ``PipelineLayer(recompute_interval=)``."""
+    import paddle_tpu.distributed.fleet as fleet
+    from paddle_tpu.distributed.fleet.meta_parallel import PipelineLayer
+    from paddle_tpu.distributed.fleet.utils.recompute import \
+        recompute_sequential
+
+    m, x = _mlp_and_input()
+    xt = paddle.to_tensor(x, stop_gradient=False)
+    before = _regions_traced()
+    if path == "eager":
+        fleet.recompute(m, xt).sum().backward()
+        assert xt.grad is not None
+        regions = 1
+    elif path == "sequential":
+        recompute_sequential({"segments": 3}, m, xt).sum().backward()
+        assert xt.grad is not None
+        regions = 3
+    elif path == "pipeline_layer":
+        piped = PipelineLayer(list(m), num_stages=1, recompute_interval=2)
+        np.testing.assert_allclose(piped(xt).numpy(), m(xt).numpy(),
+                                   rtol=1e-5)
+        regions = 2
+    else:
+        class Twice(nn.Layer):
+            def __init__(self):
+                super().__init__()
+                self.m = m
+
+            def forward(self, h):
+                return fleet.recompute(self.m, fleet.recompute(self.m, h))
+
+        net = Twice()
+        step = paddle.jit.TrainStep(
+            net, opt.SGD(learning_rate=0.1, parameters=net.parameters()),
+            loss_fn=lambda out, y: ((out - y) ** 2).mean())
+        first = float(step(xt, xt))
+        assert float(step(xt, xt)) < first
+        regions = 2
+    after = _regions_traced()
+    assert after["flash_residuals"] - before["flash_residuals"] == regions
+    assert after["caller"] == before["caller"]
+    assert after["none"] == before["none"]
+
+
 def test_spmd_pipeline_parity():
     from paddle_tpu.distributed.fleet.meta_parallel import spmd_pipeline
     from jax.sharding import Mesh

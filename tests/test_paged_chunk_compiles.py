@@ -206,6 +206,41 @@ def test_flash_kernels_compile_at_latent_attentions_head_sizes(one_chip):
     assert backward.as_text().count("tpu_custom_call") == 2
 
 
+@pytest.mark.parametrize("region,kernels", [
+    (None, 3), ({}, 3),
+    ({"checkpoint_policy": jax.checkpoint_policies.nothing_saveable}, 4)],
+    ids=["no_region", "default_policy", "nothing_saveable"])
+def test_a_recomputed_region_compiles_to_one_flash_forward(
+        monkeypatch, one_chip, region, kernels):
+    """The loss and its gradient through latent attention's kernels at the
+    cell's sizes, as the TPU's compiler leaves them: forward, dk/dv and dq;
+    under ``recompute()`` still three (the named output and log-sum-exp
+    are kept and the second forward is dead code), four where a caller's
+    policy keeps nothing."""
+    import paddle_tpu as paddle
+    from paddle_tpu import ops
+    from paddle_tpu.distributed.fleet.utils.recompute import recompute
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def attend(q, k, v):
+        return ops.flash_attention(q, k, v, causal=True)
+
+    def loss(*qkv):
+        qkv = [paddle.to_tensor(a) for a in qkv]
+        out = attend(*qkv) if region is None \
+            else recompute(attend, *qkv, **region)
+        return jnp.sum(out._value.astype(jnp.float32))
+
+    def sds(width):
+        return jax.ShapeDtypeStruct((2, 4096, 32, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        sds(192), sds(192), sds(128)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == kernels
+
+
 def test_grouped_products_compile_at_published_widths(monkeypatch, one_chip):
     """The seam over 16 held experts of 2,048 x 768 and a buffer as long as
     all 8,192 x 6 assignments (3,072 rows a group by shape: the shapes keep
