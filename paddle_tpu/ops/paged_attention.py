@@ -40,15 +40,60 @@ gather reference (a scatter, for the writer) with identical semantics.
 from __future__ import annotations
 
 import functools
+import inspect
 import math
 
 import jax
 import jax.numpy as jnp
 
+from ..profiler import metrics as _metrics
 from ..tensor.dispatch import apply as _apply
 
 NEG_INF = -1e30
 _LANES = 128
+
+_m_calls = _metrics.counter(
+    "paged.kernel_calls_traced",
+    "paged attention kernels applied by the programs traced, by kernel "
+    "(kernel = decode | chunk): one a layer body; counted when a program "
+    "is traced, not when it runs")
+_m_bodies = _metrics.counter(
+    "paged.kernel_bodies_traced",
+    "paged attention kernel bodies traced, by kernel (kernel = decode | "
+    "chunk): one a signature (operand shapes and types, static "
+    "parameters); every other call takes the body traced then")
+
+
+def _traced_once(kernel):
+    """Decorator: the application of a Pallas kernel to its prepared
+    operands, traced ONCE a signature -- the operands' shapes and types,
+    the keyword-only parameters (static) and the x64 state at the call.
+    Pallas caches no kernel body, so a program of 48 layer bodies traced
+    the same kernel 48 times.  ``jax.jit`` keeps the traced application,
+    and ``inline=True`` puts its equations into the calling program as
+    they are: under the caller's name stack, with no call of their own, so
+    the program is the one an uncached call traces and the kernels keep
+    their instruction names (JAX's lowering cache then lowers the one
+    kernel once a program, too).  The layer is an operand: a traced layer
+    stays one, and the constant of an int is made by the caller, as
+    before.  The undecorated function is the wrapper's ``__wrapped__``."""
+    def wrap(fn):
+        static = tuple(n for n, p in inspect.signature(fn).parameters.items()
+                       if p.kind is p.KEYWORD_ONLY)
+
+        @functools.wraps(fn)
+        def body(*args, **kw):
+            _m_bodies.inc(kernel=kernel)
+            return fn(*args, **kw)
+
+        cached = jax.jit(body, static_argnames=static, inline=True)
+
+        @functools.wraps(fn)
+        def call(*args, **kw):
+            _m_calls.inc(kernel=kernel)
+            return cached(*args, **kw)
+        return call
+    return wrap
 
 
 # ------------------------------------------------------------------ decode
@@ -418,14 +463,48 @@ def _paged_decode_pallas(q, pools, scales, page_table, seq_lens, scale,
     pools whose pages it cannot fetch (:func:`_decode_blocking`) are swept
     a page a grid step, the page a block whose leading dimension of one,
     the layer, the kernel does not see."""
+    B, H, D = q.shape
+    HKV = pools[0].shape[3]
+    blocking = _decode_blocking(q, pools[0], page_table.shape[1])
+    if blocking is None:
+        operand = q
+        paged = (*pools, *(a.astype(jnp.float32) for a in scales))
+    else:
+        # a few KB around the kernel: query head kv * g + r to row [r, kv]
+        operand = jnp.swapaxes(q.reshape(B, HKV, H // HKV, D), 1, 2)
+        paged = pools
+    # x64 OFF around the call: the framework enables jax_enable_x64 globally
+    # (paddle int64 tensor parity), and under it the literal 0s of the
+    # BlockSpec index maps trace as i64 constants, which Mosaic fails to
+    # legalize (checked against libtpu 0.0.34: "failed to legalize operation
+    # 'func.func'" on the index-map transform).  Every dtype in the kernel is
+    # pinned, so x32 promotion rules change nothing numerically.
+    with jax.enable_x64(False):
+        out = _paged_decode_call(
+            page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+            _layer_scalar(layer), operand, *paged, scale=scale,
+            interpret=interpret, name=name, blocking=blocking)
+    if blocking is not None:
+        out = jnp.swapaxes(out, 1, 2).reshape(B, H, D)
+    return out.astype(q.dtype)
+
+
+@_traced_once("decode")
+def _paged_decode_call(page_table, seq_lens, layer, operand, *paged, scale,
+                       interpret, name, blocking):
+    """The decode kernel applied to what :func:`_paged_decode_pallas`
+    prepares: the int32 table, lengths and ``[1]`` layer, the query rows
+    (``[B, H, D]``, or ``[B, g, HKV, D]`` for the kernel of ``blocking``),
+    the pools (and float32 scale pools) -> float32 rows in the query's
+    shape."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, D = q.shape
-    page_size, HKV = pools[0].shape[2:4]
+    page_size, HKV = paged[0].shape[2:4]
     NP = page_table.shape[1]
-    blocking = _decode_blocking(q, pools[0], NP)
     if blocking is None:
+        B, H, D = operand.shape
+
         def page_spec(pool):
             # the index map clamps the sweep: steps past the row's last
             # valid page re-present it, and a revisited block is not
@@ -435,8 +514,7 @@ def _paged_decode_pallas(q, pools, scales, page_table, seq_lens, scale,
                     i, _last_page(ln[b], page_size))]) + (0,) * (pool.ndim - 2)
             return pl.BlockSpec((None, 1) + pool.shape[2:], idx)
 
-        paged = (*pools, *(a.astype(jnp.float32) for a in scales))
-        operand, grid = q, (B, NP)
+        grid = (B, NP)
         q_spec = pl.BlockSpec((1, H, D), lambda b, i, pt, ln, ly: (b, 0, 0))
         in_specs = [q_spec] + [page_spec(a) for a in paged]
         scratch = [pltpu.VMEM((H, 1), jnp.float32),
@@ -444,49 +522,36 @@ def _paged_decode_pallas(q, pools, scales, page_table, seq_lens, scale,
                    pltpu.VMEM((H, D), jnp.float32)]
         kernel = functools.partial(
             _paged_page_kernel, page_size=page_size, scale=scale,
-            num_kv_heads=HKV, quantized=bool(scales))
+            num_kv_heads=HKV, quantized=len(paged) > 2)
         # batch rows are independent; the page sweep carries the
         # online-softmax state and stays sequential
         semantics, vmem_limit = ("parallel", "arbitrary"), None
     else:
         pages, vmem_limit = blocking
-        g = H // HKV
-        # a few KB around the kernel: query head kv * g + r to row [r, kv]
-        operand = jnp.swapaxes(q.reshape(B, HKV, g, D), 1, 2)
-        paged, grid = pools, (B,)
+        B, g, _, D = operand.shape
+        grid = (B,)
         q_spec = pl.BlockSpec((1, g, HKV, D),
                               lambda b, pt, ln, ly: (b, 0, 0, 0))
         in_specs = [q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
         scratch = [pltpu.VMEM((2, pages) + a.shape[2:], a.dtype)
-                   for a in pools] \
+                   for a in paged] \
             + [pltpu.SemaphoreType.DMA((2, 2)), pltpu.SMEM((1,), jnp.int32)]
         kernel = functools.partial(
             _paged_decode_kernel, page_size=page_size, scale=scale,
             pages=pages, table_pages=NP)
         # sequential: a slot's last block fetches the next slot's first
         semantics = ("arbitrary",)
-    # x64 OFF around the call: the framework enables jax_enable_x64 globally
-    # (paddle int64 tensor parity), and under it the literal 0s of the
-    # BlockSpec index maps trace as i64 constants, which Mosaic fails to
-    # legalize (checked against libtpu 0.0.34: "failed to legalize operation
-    # 'func.func'" on the index-map transform).  Every dtype in the kernel is
-    # pinned, so x32 promotion rules change nothing numerically.
-    with jax.enable_x64(False):
-        out = pl.pallas_call(
-            kernel,
-            name=name,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=3, grid=grid, in_specs=in_specs,
-                out_specs=q_spec, scratch_shapes=scratch),
-            out_shape=jax.ShapeDtypeStruct(operand.shape, jnp.float32),
-            interpret=interpret,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=semantics, vmem_limit_bytes=vmem_limit),
-        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-          _layer_scalar(layer), operand, *paged)
-    if blocking is not None:
-        out = jnp.swapaxes(out, 1, 2).reshape(B, H, D)
-    return out.astype(q.dtype)
+    return pl.pallas_call(
+        kernel,
+        name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=grid, in_specs=in_specs,
+            out_specs=q_spec, scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct(operand.shape, jnp.float32),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=semantics, vmem_limit_bytes=vmem_limit),
+    )(page_table, seq_lens, layer, operand, *paged)
 
 
 def _paged_flash_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
@@ -820,25 +885,43 @@ def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
     one that the kernel does not see, at the block index the third
     prefetched scalar gives (an int or a traced int32 scalar), so a layer
     is read where it lies in the pool."""
+    C = q.shape[1]
+    blocking = _chunk_blocking(q, pools[0], table.shape[1])
+    qt = jnp.transpose(jnp.pad(q, ((0, 0), (0, blocking[0] - C), (0, 0),
+                                   (0, 0))), (0, 2, 3, 1))  # [B, H, D, Cp]
+    paged = (*pools, *(a.astype(jnp.float32) for a in scales))
+    # x64 OFF for the same Mosaic i64-index reason as _paged_decode_pallas
+    with jax.enable_x64(False):
+        out = _paged_chunk_call(
+            table.astype(jnp.int32), lens.astype(jnp.int32),
+            _layer_scalar(layer), qt, *paged, scale=scale,
+            interpret=interpret, name=name, chunk=C, blocking=blocking)
+    return jnp.transpose(out, (0, 3, 1, 2))[:, :C].astype(q.dtype)
+
+
+@_traced_once("chunk")
+def _paged_chunk_call(table, lens, layer, qt, *paged, scale, interpret, name,
+                      chunk, blocking):
+    """The chunk kernel applied to what :func:`_paged_chunk_pallas`
+    prepares: the int32 table, lengths and ``[1]`` layer, the queries
+    ``[B, H, D, Cp]`` of a ``chunk`` padded to ``Cp``, the pools (and
+    float32 scale pools) -> float32 ``[B, H, D, Cp]``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, C, H, D = q.shape
-    page_size, HKV = pools[0].shape[2:4]
+    B, H, D, Cp = qt.shape
+    page_size, HKV = paged[0].shape[2:4]
     NP = table.shape[1]
-    Cp, tile, pages, vmem_limit = _chunk_blocking(q, pools[0], NP)
-    qt = jnp.transpose(jnp.pad(q, ((0, 0), (0, Cp - C), (0, 0), (0, 0))),
-                       (0, 2, 3, 1))                       # [B, H, D, Cp]
+    _, tile, pages, vmem_limit = blocking
 
     def page_map(r, rank):
         def idx(b, j, i, pt, ln, ly):
-            last = _chunk_last_key(ln[b], j, tile, C, NP * page_size)
+            last = _chunk_last_key(ln[b], j, tile, chunk, NP * page_size)
             return (ly[0],
                     pt[b, jnp.minimum(i * pages + r, last // page_size)]) \
                 + (0,) * (rank - 2)
         return idx
 
-    paged = (*pools, *(a.astype(jnp.float32) for a in scales))
     q_spec = pl.BlockSpec((1, H, D, tile),
                           lambda b, j, i, pt, ln, ly: (b, 0, 0, j))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -856,26 +939,21 @@ def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
             pltpu.VMEM((H, D, tile), jnp.float32),
         ],
     )
-    # x64 OFF for the same Mosaic i64-index reason as _paged_decode_pallas
-    with jax.enable_x64(False):
-        out = pl.pallas_call(
-            functools.partial(
-                _paged_chunk_kernel, page_size=page_size, scale=scale,
-                num_kv_heads=HKV, pages=pages, chunk=C, table_pages=NP,
-                quantized=bool(scales)),
-            name=name,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(qt.shape, jnp.float32),
-            interpret=interpret,
-            # slots and query tiles are independent; the page sweep carries
-            # the online-softmax state and stays sequential
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
-                vmem_limit_bytes=vmem_limit),
-        )(table.astype(jnp.int32), lens.astype(jnp.int32),
-          _layer_scalar(layer), qt,
-          *(a for a in paged for _ in range(pages)))
-    return jnp.transpose(out, (0, 3, 1, 2))[:, :C].astype(q.dtype)
+    return pl.pallas_call(
+        functools.partial(
+            _paged_chunk_kernel, page_size=page_size, scale=scale,
+            num_kv_heads=HKV, pages=pages, chunk=chunk, table_pages=NP,
+            quantized=len(paged) > 2),
+        name=name,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, jnp.float32),
+        interpret=interpret,
+        # slots and query tiles are independent; the page sweep carries
+        # the online-softmax state and stays sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
+    )(table, lens, layer, qt, *(a for a in paged for _ in range(pages)))
 
 
 def _paged_chunk_flash_pallas(q, k_pages, v_pages, table, lens, scale,
