@@ -13,7 +13,10 @@ MXU wants.
 
 ``DroplessMoELayer`` is the other formulation (DeepSeek-V3, arXiv:2412.19437
 section 2.1.2): sigmoid scores, top-k with a selection bias, no capacity and
-no dropped token.  Tokens are sorted into expert order and the experts are
+no dropped token; or softmax scores over every expert, the top k of them
+renormalised (SmallThinker), with ReLU in the gated products' place of SiLU
+where the model asks for it, and the routing computed from another input
+than the one the experts transform where the model routes early.  Tokens are sorted into expert order and the experts are
 grouped products (``ops.grouped_matmul``: XLA's ``ragged_dot`` or the repo's
 kernel, by the shapes) over the rows each one really got; no ``[G, E, C]``
 tensor exists.  The layer is told which experts it holds
@@ -229,8 +232,28 @@ def sigmoid_topk(x, router_w, bias, top_k, scale=1.0, normalize=True,
     return idx, picked * jnp.float32(scale)
 
 
+def softmax_topk(x, router_w, top_k, normalize=True):
+    """The softmax router: ``(expert ids [T, k], weights [T, k] f32)``.
+    Scores are ``softmax(x W_r)`` over every expert the router scores, in
+    float32 whatever ``x`` is; the k experts are the top k of them, their
+    weights the scores there, renormalised over the k (``normalize``)."""
+    scores = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    picked, idx = jax.lax.top_k(scores, top_k)
+    if normalize:
+        picked = picked / picked.sum(-1, keepdims=True)
+    return idx, picked
+
+
+#: the activation of the gated products ``act(x W_gate) * (x W_up)``:
+#: SwiGLU's, or ReGLU's
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
-                   expert_offset=0, scale=1.0, normalize=True, eps=1e-20):
+                   expert_offset=0, scale=1.0, normalize=True, eps=1e-20,
+                   act="silu", routing=None):
     """The held experts' part of a dropless MoE over tokens ``x`` [T, H]:
     ``(y [T, H], load [router's experts] int32)``, the load being the tokens
     each expert the router scores was chosen by, held here or not.
@@ -240,12 +263,20 @@ def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
     the router scores.  An assignment to an expert held elsewhere adds
     nothing here.  Every buffer is as long as all ``T * top_k``
     assignments, so no load drops a token; the grouped products touch only
-    the rows that exist."""
+    the rows that exist.
+
+    ``routing``, where given, is ``(expert ids [T, k], weights [T, k])``
+    computed before (from another input than ``x``, by either router): the
+    router is then not run here.  ``act`` names the gate's activation
+    (``ACTIVATIONS``)."""
     T, H = x.shape
     held = w_gate.shape[0]
     with jax.named_scope("moe_route"):
-        idx, weights = sigmoid_topk(x, router_w, bias, top_k, scale,
-                                    normalize, eps)
+        if routing is None:
+            idx, weights = sigmoid_topk(x, router_w, bias, top_k, scale,
+                                        normalize, eps)
+        else:
+            idx, weights = routing
         local = idx - expert_offset
         here = (local >= 0) & (local < held)
         # assignments held elsewhere sort behind the last held expert
@@ -266,7 +297,7 @@ def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
             return jnp.where(exists, rows_, jnp.zeros((), rows_.dtype))
 
         xs = real(xs)
-        h = real(jax.nn.silu(grouped_matmul(xs, w_gate, counts))
+        h = real(ACTIVATIONS[act](grouped_matmul(xs, w_gate, counts))
                  * grouped_matmul(xs, w_up, counts))
         ys = real(grouped_matmul(h, w_down, counts))
     with jax.named_scope("moe_route"):
@@ -301,7 +332,12 @@ class DroplessMoELayer(Layer):
     ``tokens_per_expert`` counts the assignments each held expert has got
     since the layer was built.
     ``norm_topk_eps`` is what the normalisation adds to the picked scores'
-    sum (the model's to state).  ``param_init(name, shape)``, where given,
+    sum (the model's to state).  ``router="softmax"`` scores the experts
+    by a softmax over all of them instead (:func:`softmax_topk`: no
+    selection bias, no scaling), and ``activation="relu"`` makes the
+    experts ReGLU; :meth:`route` runs the router alone, for a model that
+    routes from another input than the one the experts take
+    (``forward(x, routing=...)``).  ``param_init(name, shape)``, where given,
     makes each weight in place of the normal initializer: a model too large
     to be drawn in float32 and cast hands its leaves over in their own type.
     """
@@ -310,9 +346,16 @@ class DroplessMoELayer(Layer):
                  experts_held=None, expert_offset=0, num_shared_experts=0,
                  routed_scaling_factor=1.0, norm_topk_prob=True,
                  initializer_range=0.02, bias_update_speed=0.0,
-                 norm_topk_eps=1e-20, param_init=None):
+                 norm_topk_eps=1e-20, param_init=None, router="sigmoid",
+                 activation="silu"):
         super().__init__()
         from ....nn import initializer as I
+
+        if router not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown router {router!r}")
+        if activation not in ACTIVATIONS:
+            raise ValueError(f"unknown expert activation {activation!r}")
+        self.router, self.activation = router, activation
 
         held = num_experts if experts_held is None else int(experts_held)
         if not 0 <= expert_offset <= num_experts - held:
@@ -351,25 +394,51 @@ class DroplessMoELayer(Layer):
                              jnp.zeros((held,), jnp.int32))
         self._published = None
 
-    def routed(self, x):
+    def route(self, x):
+        """``(expert ids [T, k] int32, weights [T, k] float32)`` of tokens
+        ``x`` ([..., d_model]) by this layer's router, under ``moe_route``:
+        what ``forward(..., routing=)`` takes."""
+        top_k, norm = self.top_k, self.norm_topk_prob
+        scale, eps = self.routed_scaling_factor, self.norm_topk_eps
+        softmax = self.router == "softmax"
+
+        @jax.named_scope("moe_route")
+        def fn(xv, rw, bias):
+            flat = xv.reshape(-1, xv.shape[-1])
+            if softmax:
+                idx, w = softmax_topk(flat, rw, top_k, norm)
+            else:
+                idx, w = sigmoid_topk(flat, rw, bias, top_k, scale, norm,
+                                      eps)
+            return idx.astype(jnp.int32), w
+
+        return _apply(fn, x, self.gate_weight, self.e_score_correction_bias,
+                      n_outs=2)
+
+    def routed(self, x, routing=None):
         """``(y, this call's load over all the router's experts)`` of the
         held experts alone; no buffer is touched (``count()`` does that), so
-        this may run under ``recompute``."""
+        this may run under ``recompute``.  ``routing``: what :meth:`route`
+        gave for these tokens (needed for the softmax router)."""
         top_k, offset = self.top_k, self.expert_offset
         scale, norm = self.routed_scaling_factor, self.norm_topk_prob
-        eps = self.norm_topk_eps
+        eps, act = self.norm_topk_eps, self.activation
+        if routing is None and self.router != "sigmoid":
+            routing = self.route(x)
 
-        def fn(xv, rw, bias, wg, wu, wd):
+        def fn(xv, rw, bias, wg, wu, wd, *given):
             y, load = routed_experts(
                 xv.reshape(-1, xv.shape[-1]), rw, bias, wg.astype(xv.dtype),
                 wu.astype(xv.dtype), wd.astype(xv.dtype), top_k=top_k,
-                expert_offset=offset, scale=scale, normalize=norm, eps=eps)
+                expert_offset=offset, scale=scale, normalize=norm, eps=eps,
+                act=act, routing=tuple(given) or None)
             return y.reshape(xv.shape), load
 
         # no op_name: autocast must not round the router's bias, and the
         # products take the activations' type by themselves
         return _apply(fn, x, self.gate_weight, self.e_score_correction_bias,
-                      self.w_gate, self.w_up, self.w_down, n_outs=2)
+                      self.w_gate, self.w_up, self.w_down,
+                      *(routing or ()), n_outs=2)
 
     def shared(self, x):
         @jax.named_scope("moe_shared")
@@ -394,11 +463,12 @@ class DroplessMoELayer(Layer):
                 self.e_score_correction_bias._value \
                 + jnp.float32(self.bias_update_speed) * jnp.sign(short)
 
-    def forward(self, x, return_load=False):
+    def forward(self, x, return_load=False, routing=None):
         """x: [B, S, d_model] (or [T, d_model]).  With ``return_load`` the
         call's load is handed back and no buffer touched: for a caller under
-        ``recompute``, who takes it in outside (``count()``)."""
-        y, load = self.routed(x)
+        ``recompute``, who takes it in outside (``count()``).  ``routing``:
+        :meth:`route` of another input, where the model routes from one."""
+        y, load = self.routed(x, routing)
         if self.num_shared_experts:
             y = y + self.shared(x)
         if return_load:

@@ -7,6 +7,7 @@ label from a fixed taxonomy:
 
 - ``kv.pages`` — paged KV payload pools (the engine's donated pool tuple);
 - ``kv.scales`` — the quantized engine's parallel f32 scale pools;
+- ``kv.window_pages`` — the pools of a decoder's sliding-window layers;
 - ``model.params`` — model parameters + buffers (minus int8 weights);
 - ``model.weights_int8`` — converted ``Int8Linear`` weight/scale buffers;
 - ``lora.r<r>`` — the LoRAStore's A/B pools for rank bucket ``r``;
@@ -257,7 +258,8 @@ class MemoryLedger:
         denominator perf's chunk-the-prefill hint compares peak temp
         bytes against."""
         return sum(b for reg, b, _ in self._rows()
-                   if reg.owner in ("kv.pages", "kv.scales"))
+                   if reg.owner in ("kv.pages", "kv.scales",
+                                    "kv.window_pages"))
 
     def replica_rollup(self, replicas):
         """Per-replica owner totals for the cluster's ``stats()`` — a

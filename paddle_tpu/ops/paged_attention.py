@@ -252,7 +252,7 @@ def _decode_blocking(q, k_pages, NP):
 
 def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
                          o_ref, k_buf, v_buf, sem, swept, *, page_size,
-                         scale, pages, table_pages):
+                         scale, pages, table_pages, window=None):
     """Grid (slot b): a slot's whole sweep is one grid step.  ``k_hbm`` /
     ``v_hbm`` are the pools as they lie in HBM, ``[L, P, ps, HKV, D]``, of
     which layer ``layer_ref[0]`` (the third prefetched scalar) is read;
@@ -280,7 +280,13 @@ def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
     probabilities, statistics and the accumulator are f32, and so are both
     products' operands.  The online-softmax update runs a page at a time,
     so that a page's tensors stay near the register file; the carried
-    state is a few tiles."""
+    state is a few tiles.
+
+    With a ``window`` (static) a slot's sweep STARTS at the page of its
+    oldest visible key, ``length - window``, and keys older than that are
+    masked: a window layer reads the pages of at most ``window`` keys a
+    slot, whatever the context.  Without one the kernel is the one it
+    always was."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -289,23 +295,29 @@ def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
     layer = layer_ref[0]
 
     def sweep(slot):
-        """``(length, pages)`` of a slot's sweep: a length that overruns
-        the table sees the table's keys (callers mask with seq_lens), and
-        an empty slot sweeps one page with every key masked, so that each
-        grid step fetches and waits alike."""
+        """``(length, pages, first page)`` of a slot's sweep: a length
+        that overruns the table sees the table's keys (callers mask with
+        seq_lens), and an empty slot sweeps one page with every key masked,
+        so that each grid step fetches and waits alike.  The first page is
+        None without a window (page 0)."""
         seq_len = jnp.minimum(lens_ref[slot], table_pages * page_size)
-        return seq_len, jnp.maximum(
-            (seq_len + page_size - 1) // page_size, 1)
+        live = jnp.maximum((seq_len + page_size - 1) // page_size, 1)
+        if window is None:
+            return seq_len, live, None
+        first = jnp.minimum(jnp.maximum(seq_len - window, 0) // page_size,
+                            live - 1)
+        return seq_len, live - first, first
 
-    def fetch(slot, blk, buf, live, start):
+    def fetch(slot, blk, buf, live, first_page, start):
         """Start (or wait for) the DMAs of block ``blk`` of ``slot`` into
         buffer ``buf``: one a pool for each of the block's pages below
-        ``live``, the slot's page count."""
+        ``live``, the slot's page count, counted from ``first_page``."""
         first = blk * pages
+        base = first if first_page is None else first_page + first
 
         def page(r, carry):
             # to wait for a copy only its shape counts
-            at = pt_ref[slot, first + r] if start else 0
+            at = pt_ref[slot, base + r] if start else 0
             for a, (hbm, buffers) in enumerate(((k_hbm, k_buf),
                                                 (v_hbm, v_buf))):
                 copy = pltpu.make_async_copy(
@@ -322,9 +334,9 @@ def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
         # it has to be finite
         k_buf[...] = jnp.zeros_like(k_buf)
         v_buf[...] = jnp.zeros_like(v_buf)
-        fetch(0, 0, 0, sweep(0)[1], True)
+        fetch(0, 0, 0, *sweep(0)[1:], True)
 
-    seq_len, live = sweep(b)
+    seq_len, live, first_page = sweep(b)
     blocks = (live + pages - 1) // pages
     base = swept[0]
     q = q_ref[0].astype(jnp.float32) * jnp.float32(scale)  # [g, HKV, D]
@@ -334,20 +346,24 @@ def _paged_decode_kernel(pt_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
 
         @pl.when(blk + 1 < blocks)
         def _next_block():
-            fetch(b, blk + 1, 1 - buf, live, True)
+            fetch(b, blk + 1, 1 - buf, live, first_page, True)
 
         @pl.when((blk + 1 == blocks) & (b + 1 < pl.num_programs(0)))
         def _next_slot():
-            fetch(b + 1, 0, 1 - buf, sweep(b + 1)[1], True)
+            fetch(b + 1, 0, 1 - buf, *sweep(b + 1)[1:], True)
 
-        fetch(b, blk, buf, live, False)
+        fetch(b, blk, buf, live, first_page, False)
         m, l, acc = (list(x) for x in state)
         for r in range(pages):
             k = k_buf[buf, r].astype(jnp.float32)          # [ps, HKV, D]
             v = v_buf[buf, r].astype(jnp.float32)
-            pos = (blk * pages + r) * page_size + jax.lax.broadcasted_iota(
+            at = blk * pages + r if first_page is None \
+                else first_page + blk * pages + r
+            pos = at * page_size + jax.lax.broadcasted_iota(
                 jnp.int32, (page_size, 1, 1), 0)
             valid = pos < seq_len                          # [ps, 1, 1]
+            if window is not None:
+                valid = valid & (pos >= seq_len - window)
             for j in range(g):
                 s = jnp.sum(k * q[j][None], axis=2, keepdims=True)
                 s = jnp.where(valid, s, jnp.float32(NEG_INF))  # [ps, HKV, 1]
@@ -380,7 +396,7 @@ def _last_page(seq_len, page_size):
 
 
 def _paged_page_kernel(pt_ref, lens_ref, layer_ref, q_ref, *refs, page_size,
-                       scale, num_kv_heads, quantized):
+                       scale, num_kv_heads, quantized, window=None):
     """Grid (slot b, table entry i): the sweep of ONE page a grid step, for
     the pools of which the decode kernel's DMAs take no page
     (:func:`_decode_blocking`); the layer is the index maps' business.
@@ -392,7 +408,9 @@ def _paged_page_kernel(pt_ref, lens_ref, layer_ref, q_ref, *refs, page_size,
     keepdims reductions, f32 constants, plain-contracting dot_generals
     only.  KV heads run as a STATIC unrolled loop over the page's f32
     ``[page, D]`` tiles, each streamed ONCE and serving all g grouped query
-    heads."""
+    heads.  With a ``window`` only the pages that hold one of the row's
+    last ``window`` keys are attended (the index maps present the first
+    of them until the sweep reaches it) and older keys are masked."""
     from jax.experimental import pallas as pl
 
     k_ref, v_ref = refs[:2]
@@ -420,11 +438,17 @@ def _paged_page_kernel(pt_ref, lens_ref, layer_ref, q_ref, *refs, page_size,
         x = ref[0, :, j, :].astype(jnp.float32)            # [page, D]
         return x * scale_ref[0, :, j:j + 1] if quantized else x
 
-    @pl.when(i * page_size < seq_len)
+    live = i * page_size < seq_len
+    if window is not None:
+        live = live & ((i + 1) * page_size > seq_len - window)
+
+    @pl.when(live)
     def _compute():
         pos = i * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
         valid = pos < seq_len                              # [1, page]
+        if window is not None:
+            valid = valid & (pos >= seq_len - window)
         for j in range(num_kv_heads):
             r = slice(j * g, (j + 1) * g)
             q = q_ref[0, r, :].astype(jnp.float32)         # [g, D]
@@ -454,7 +478,7 @@ def _paged_page_kernel(pt_ref, lens_ref, layer_ref, q_ref, *refs, page_size,
 
 
 def _paged_decode_pallas(q, pools, scales, page_table, seq_lens, scale,
-                         interpret, layer, name=None):
+                         interpret, layer, name=None, window=None):
     """q [B, H, D] against layer ``layer`` of the stacked ``pools`` (K, V:
     [L, P, ps, HKV, D]) and, for int8 pools, ``scales`` (K, V:
     [L, P, ps, HKV]) -> [B, H, D].  The layer (an int or a traced int32
@@ -462,7 +486,8 @@ def _paged_decode_pallas(q, pools, scales, page_table, seq_lens, scale,
     lies in the pool: the decode kernel fetches its pages from HBM itself;
     pools whose pages it cannot fetch (:func:`_decode_blocking`) are swept
     a page a grid step, the page a block whose leading dimension of one,
-    the layer, the kernel does not see."""
+    the layer, the kernel does not see.  ``window`` (static): each row
+    attends its last ``window`` keys only, and sweeps only their pages."""
     B, H, D = q.shape
     HKV = pools[0].shape[3]
     blocking = _decode_blocking(q, pools[0], page_table.shape[1])
@@ -483,7 +508,7 @@ def _paged_decode_pallas(q, pools, scales, page_table, seq_lens, scale,
         out = _paged_decode_call(
             page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
             _layer_scalar(layer), operand, *paged, scale=scale,
-            interpret=interpret, name=name, blocking=blocking)
+            interpret=interpret, name=name, blocking=blocking, window=window)
     if blocking is not None:
         out = jnp.swapaxes(out, 1, 2).reshape(B, H, D)
     return out.astype(q.dtype)
@@ -491,7 +516,7 @@ def _paged_decode_pallas(q, pools, scales, page_table, seq_lens, scale,
 
 @_traced_once("decode")
 def _paged_decode_call(page_table, seq_lens, layer, operand, *paged, scale,
-                       interpret, name, blocking):
+                       interpret, name, blocking, window):
     """The decode kernel applied to what :func:`_paged_decode_pallas`
     prepares: the int32 table, lengths and ``[1]`` layer, the query rows
     (``[B, H, D]``, or ``[B, g, HKV, D]`` for the kernel of ``blocking``),
@@ -508,10 +533,16 @@ def _paged_decode_call(page_table, seq_lens, layer, operand, *paged, scale,
         def page_spec(pool):
             # the index map clamps the sweep: steps past the row's last
             # valid page re-present it, and a revisited block is not
-            # fetched again
+            # fetched again; with a window, so do the steps before the
+            # row's first visible page
             def idx(b, i, pt, ln, ly):
-                return (ly[0], pt[b, jnp.minimum(
-                    i, _last_page(ln[b], page_size))]) + (0,) * (pool.ndim - 2)
+                last = _last_page(ln[b], page_size)
+                if window is None:
+                    at = jnp.minimum(i, last)
+                else:
+                    first = jnp.maximum(ln[b] - window, 0) // page_size
+                    at = jnp.clip(i, jnp.minimum(first, last), last)
+                return (ly[0], pt[b, at]) + (0,) * (pool.ndim - 2)
             return pl.BlockSpec((None, 1) + pool.shape[2:], idx)
 
         grid = (B, NP)
@@ -522,7 +553,7 @@ def _paged_decode_call(page_table, seq_lens, layer, operand, *paged, scale,
                    pltpu.VMEM((H, D), jnp.float32)]
         kernel = functools.partial(
             _paged_page_kernel, page_size=page_size, scale=scale,
-            num_kv_heads=HKV, quantized=len(paged) > 2)
+            num_kv_heads=HKV, quantized=len(paged) > 2, window=window)
         # batch rows are independent; the page sweep carries the
         # online-softmax state and stays sequential
         semantics, vmem_limit = ("parallel", "arbitrary"), None
@@ -538,7 +569,7 @@ def _paged_decode_call(page_table, seq_lens, layer, operand, *paged, scale,
             + [pltpu.SemaphoreType.DMA((2, 2)), pltpu.SMEM((1,), jnp.int32)]
         kernel = functools.partial(
             _paged_decode_kernel, page_size=page_size, scale=scale,
-            pages=pages, table_pages=NP)
+            pages=pages, table_pages=NP, window=window)
         # sequential: a slot's last block fetches the next slot's first
         semantics = ("arbitrary",)
     return pl.pallas_call(
@@ -555,7 +586,7 @@ def _paged_decode_call(page_table, seq_lens, layer, operand, *paged, scale,
 
 
 def _paged_flash_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
-                        interpret, layer):
+                        interpret, layer, window=None):
     # This call alone carries no ``name="paged_decode"``: a name is the
     # innermost scope of the kernel's name stack and so becomes its HLO
     # instruction name, and the benchmark's ``paged_decode_roofline`` finds
@@ -564,7 +595,8 @@ def _paged_flash_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
     # not come through here: it has a kernel of its own
     # (``_paged_chunk_pallas``) under the scope ``chunk_attention``.
     return _paged_decode_pallas(q, (k_pages, v_pages), (), page_table,
-                                seq_lens, scale, interpret, layer)
+                                seq_lens, scale, interpret, layer,
+                                window=window)
 
 
 def _paged_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
@@ -574,9 +606,17 @@ def _paged_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
                                 layer, name="paged_decode_q")
 
 
-def _gathered_attend(q, k, v, seq_lens, scale):
+def _window_mask(pos, lens, window):
+    """Keys at ``pos`` a query that sees ``lens`` keys reaches: ``pos <
+    lens``, and with a window ``pos >= lens - window`` too."""
+    seen = pos < lens
+    return seen if window is None else seen & (pos >= lens - window)
+
+
+def _gathered_attend(q, k, v, seq_lens, scale, window=None):
     """The dense-reference math shared by the bf16 and int8 fallbacks:
-    q [B, H, D] against gathered k/v [B, T, HKV, D] masked by seq_lens.
+    q [B, H, D] against gathered k/v [B, T, HKV, D] masked by seq_lens
+    (and by ``window``: the last ``window`` keys of a row).
 
     GQA runs as a grouped einsum over [HKV, g] (query head k*g+j attends
     kv head k, the jnp.repeat convention) — the K/V operands stay at their
@@ -589,13 +629,14 @@ def _gathered_attend(q, k, v, seq_lens, scale):
     qg = q.reshape(B, HKV, g, D).astype(jnp.float32)
     s = jnp.einsum("bkgd,btkd->bkgt", qg, k.astype(jnp.float32)) * scale
     pos = jnp.arange(T)[None, None, None, :]
-    s = jnp.where(pos < seq_lens[:, None, None, None], s, NEG_INF)
+    s = jnp.where(_window_mask(pos, seq_lens[:, None, None, None], window),
+                  s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgt,btkd->bkgd", p, v.astype(jnp.float32))
     return out.reshape(B, H, D).astype(q.dtype)
 
 
-def _gathered_chunk_attend(q, k, v, lens2, scale):
+def _gathered_chunk_attend(q, k, v, lens2, scale, window=None):
     """Chunked twin of :func:`_gathered_attend`: q [B, C, H, D] against
     gathered k/v [B, T, HKV, D], position (b, t) masked to its OWN valid
     length ``lens2[b, t]``.  The point is the gather amortization: the
@@ -609,29 +650,32 @@ def _gathered_chunk_attend(q, k, v, lens2, scale):
     qg = q.reshape(B, C, HKV, g, D).astype(jnp.float32)
     s = jnp.einsum("bckgd,btkd->bckgt", qg, k.astype(jnp.float32)) * scale
     pos = jnp.arange(T)[None, None, None, None, :]
-    s = jnp.where(pos < lens2[:, :, None, None, None], s, NEG_INF)
+    s = jnp.where(_window_mask(pos, lens2[:, :, None, None, None], window),
+                  s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bckgt,btkd->bckgd", p, v.astype(jnp.float32))
     return out.reshape(B, C, H, D).astype(q.dtype)
 
 
 def paged_attention_ref(q, k_pages, v_pages, page_table, seq_lens,
-                        scale=None, layer=None):
+                        scale=None, layer=None, window=None):
     """Dense-gather reference with identical semantics (oracle + fallback).
 
     GQA: q may carry g*HKV heads against HKV-head pools (q head h attends
-    kv head h//g, matching jnp.repeat(kv, g, axis=heads))."""
+    kv head h//g, matching jnp.repeat(kv, g, axis=heads)).  ``window``: a
+    row sees its last ``window`` keys only."""
     B, H, D = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     k = _gather_pages(k_pages, page_table, layer)[..., :D]
     v = _gather_pages(v_pages, page_table, layer)[..., :D]
     HKV = k.shape[3]
     return _gathered_attend(q, k.reshape(B, -1, HKV, D),
-                            v.reshape(B, -1, HKV, D), seq_lens, scale)
+                            v.reshape(B, -1, HKV, D), seq_lens, scale,
+                            window)
 
 
 def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
-                    interpret=None, layer=None):
+                    interpret=None, layer=None, window=None):
     """Decode attention over a paged KV cache (see module docstring).
     With ``layer`` the pools are the serving engine's stacked
     ``[L, P, ps, HKV, D]`` and that layer of them is attended, in place.
@@ -642,7 +686,8 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
     valid pages (pad rows with any in-range id — padded pages are masked
     by ``seq_lens``).  GQA: q with g*HKV heads against HKV-head pools is
     grouped inside the kernel — each page streams once for all g query
-    heads.
+    heads.  ``window`` (static): each row attends its last ``window`` keys
+    only (a sliding-window layer), and the kernel sweeps only their pages.
     """
     B, H, D = q.shape
     if H % k_pages.shape[-2]:
@@ -652,17 +697,25 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
     if interpret is None:
         if jax.default_backend() != "tpu":
             return paged_attention_ref(q, k_pages, v_pages, page_table,
-                                       seq_lens, scale, layer)
+                                       seq_lens, scale, layer, window)
         interpret = False
     pools, layer = _as_stack((k_pages, v_pages), layer)
     q = _to_lanes(q, k_pages.shape[-1])     # rows as wide as the pool's
     if _MP_SCOPE[0] is not None:
+        _unsharded_window(window)
         out = _flash_sharded(_paged_flash_pallas, q, pools, (), page_table,
                              seq_lens, scale, interpret, layer)
     else:
         out = _paged_flash_pallas(q, *pools, page_table, seq_lens, scale,
-                                  interpret, layer)
+                                  interpret, layer, window)
     return out[..., :D]
+
+
+def _unsharded_window(window):
+    if window is not None:
+        raise NotImplementedError(
+            "a windowed paged attention under an mp shard scope: the "
+            "head-sharded kernels take no window")
 
 
 # ------------------------------------------------------------------ writes
@@ -782,9 +835,16 @@ def _chunk_last_key(seq_len, j, tile, chunk, capacity):
                     0, capacity - 1)
 
 
+def _chunk_first_key(seq_len, j, tile, last, window):
+    """Oldest key query tile ``j`` of a slot sees under a ``window``: that
+    of its first position (or, for a tile of padding alone, of its last
+    key).  Never past ``last``, so that every tile finalizes."""
+    return jnp.maximum(jnp.minimum(seq_len + j * tile, last) - window + 1, 0)
+
+
 def _paged_chunk_kernel(pt_ref, lens_ref, layer_ref, q_ref, *refs, page_size,
                         scale, num_kv_heads, pages, chunk, table_pages,
-                        quantized):
+                        quantized, window=None):
     """Grid (slot b, query tile j, page step i); the layer is the index
     maps' business.  ``refs``: ``pages`` K
     page refs, as many V, (int8 pools: as many K-scale and V-scale refs),
@@ -793,7 +853,14 @@ def _paged_chunk_kernel(pt_ref, lens_ref, layer_ref, q_ref, *refs, page_size,
     widened to f32 in VMEM (dequant fused there); both products take f32
     operands on the MXU, which rounds them to bf16 (exact for the stored
     K, V and a bf16 q; the probabilities lose their low bits); 2-D tiles
-    inside the head loop, keepdims reductions, f32 constants."""
+    inside the head loop, keepdims reductions, f32 constants.
+
+    With a ``window`` (static) position t sees keys ``lens[b]+t-window+1 ..
+    lens[b]+t`` only, and grid step ``i`` of a tile is the page step ``i``
+    after that of the tile's oldest visible key (``_chunk_first_key``): the
+    sweep starts there instead of at key 0, and the pages of that step that
+    lie behind the key re-present the key's page, so a window layer reads
+    at most ``window + tile`` keys a tile whatever the context."""
     from jax.experimental import pallas as pl
 
     k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
@@ -818,6 +885,8 @@ def _paged_chunk_kernel(pt_ref, lens_ref, layer_ref, q_ref, *refs, page_size,
 
     seq_len = lens_ref[b]
     last = _chunk_last_key(seq_len, j, tile, chunk, capacity)
+    step = i if window is None else \
+        i + _chunk_first_key(seq_len, j, tile, last, window) // T
 
     def stage(pool_refs, scale_refs, scr):
         """The step's pages into ``scr`` [HKV, T, D] f32, head-major: the
@@ -830,16 +899,17 @@ def _paged_chunk_kernel(pt_ref, lens_ref, layer_ref, q_ref, *refs, page_size,
             scr[:, r * page_size:(r + 1) * page_size, :] = \
                 jnp.swapaxes(x, 0, 1)
 
-    @pl.when(i * T <= last)
+    @pl.when(step * T <= last)
     def _compute():
         stage(k_refs, ks_refs, k_scr)
         stage(v_refs, vs_refs, v_scr)
-        key = i * T + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
-        lim = jnp.minimum(
-            seq_len + j * tile
-            + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1),
-            capacity - 1)
+        key = step * T + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
+        at = seq_len + j * tile \
+            + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+        lim = jnp.minimum(at, capacity - 1)
         valid = key <= lim                                 # [T, tile]
+        if window is not None:
+            valid = valid & (key > at - window)
 
         # a loop over kv heads, not an unrolled one: the kernel is traced
         # and lowered once per layer of every program that holds it, and
@@ -868,7 +938,7 @@ def _paged_chunk_kernel(pt_ref, lens_ref, layer_ref, q_ref, *refs, page_size,
                 acc_scr[h] = acc_scr[h] * alpha + pv
                 m_scr[h] = m_new
 
-    @pl.when(i == last // T)
+    @pl.when(step == last // T)
     def _fin():
         # output stays f32; the wrapper downcasts outside the kernel
         @pl.loop(0, H)
@@ -878,7 +948,7 @@ def _paged_chunk_kernel(pt_ref, lens_ref, layer_ref, q_ref, *refs, page_size,
 
 
 def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
-                        layer, name=None):
+                        layer, name=None, window=None):
     """q [B, C, H, D] against layer ``layer`` of the stacked ``pools`` (K,
     V: [L, P, ps, HKV, D]) and, for int8 pools, ``scales`` (K, V:
     [L, P, ps, HKV]) -> [B, C, H, D].  The layer is a block dimension of
@@ -895,13 +965,14 @@ def _paged_chunk_pallas(q, pools, scales, table, lens, scale, interpret,
         out = _paged_chunk_call(
             table.astype(jnp.int32), lens.astype(jnp.int32),
             _layer_scalar(layer), qt, *paged, scale=scale,
-            interpret=interpret, name=name, chunk=C, blocking=blocking)
+            interpret=interpret, name=name, chunk=C, blocking=blocking,
+            window=window)
     return jnp.transpose(out, (0, 3, 1, 2))[:, :C].astype(q.dtype)
 
 
 @_traced_once("chunk")
 def _paged_chunk_call(table, lens, layer, qt, *paged, scale, interpret, name,
-                      chunk, blocking):
+                      chunk, blocking, window):
     """The chunk kernel applied to what :func:`_paged_chunk_pallas`
     prepares: the int32 table, lengths and ``[1]`` layer, the queries
     ``[B, H, D, Cp]`` of a ``chunk`` padded to ``Cp``, the pools (and
@@ -917,9 +988,13 @@ def _paged_chunk_call(table, lens, layer, qt, *paged, scale, interpret, name,
     def page_map(r, rank):
         def idx(b, j, i, pt, ln, ly):
             last = _chunk_last_key(ln[b], j, tile, chunk, NP * page_size)
-            return (ly[0],
-                    pt[b, jnp.minimum(i * pages + r, last // page_size)]) \
-                + (0,) * (rank - 2)
+            if window is None:
+                at = jnp.minimum(i * pages + r, last // page_size)
+            else:
+                first = _chunk_first_key(ln[b], j, tile, last, window)
+                at = jnp.clip((i + first // (pages * page_size)) * pages + r,
+                              first // page_size, last // page_size)
+            return (ly[0], pt[b, at]) + (0,) * (rank - 2)
         return idx
 
     q_spec = pl.BlockSpec((1, H, D, tile),
@@ -943,7 +1018,7 @@ def _paged_chunk_call(table, lens, layer, qt, *paged, scale, interpret, name,
         functools.partial(
             _paged_chunk_kernel, page_size=page_size, scale=scale,
             num_kv_heads=HKV, pages=pages, chunk=chunk, table_pages=NP,
-            quantized=len(paged) > 2),
+            quantized=len(paged) > 2, window=window),
         name=name,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, jnp.float32),
@@ -957,9 +1032,9 @@ def _paged_chunk_call(table, lens, layer, qt, *paged, scale, interpret, name,
 
 
 def _paged_chunk_flash_pallas(q, k_pages, v_pages, table, lens, scale,
-                              interpret, layer):
+                              interpret, layer, window=None):
     return _paged_chunk_pallas(q, (k_pages, v_pages), (), table, lens, scale,
-                               interpret, layer)
+                               interpret, layer, window=window)
 
 
 def _paged_chunk_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
@@ -969,7 +1044,8 @@ def _paged_chunk_q_flash_pallas(q, k_pages, v_pages, k_scales, v_scales,
                                name="paged_chunk_q")
 
 
-def _chunk_attend(pallas_fn, q, pools, scales, table, lens, layer):
+def _chunk_attend(pallas_fn, q, pools, scales, table, lens, layer,
+                  window=None):
     """The TPU side of both chunk entries: the chunk kernel under the
     scope the benchmark reads it by, head-sharded under an mp scope."""
     D = q.shape[-1]
@@ -980,11 +1056,15 @@ def _chunk_attend(pallas_fn, q, pools, scales, table, lens, layer):
     q = _to_lanes(q, pools[0].shape[-1])    # rows as wide as the pool's
     with jax.named_scope("chunk_attention"):
         if _MP_SCOPE[0] is not None:
+            _unsharded_window(window)
             out = _flash_sharded(pallas_fn, q, pools, scales, table, lens,
                                  scale, False, layer)
-        else:
+        elif window is None:
             out = pallas_fn(q, *pools, *scales, table, lens, scale, False,
                             layer)
+        else:
+            out = pallas_fn(q, *pools, *scales, table, lens, scale, False,
+                            layer, window)
     return out[..., :D]
 
 
@@ -996,7 +1076,8 @@ def _chunk_lens(lens, C, capacity):
     return jnp.minimum(lens2, jnp.int32(capacity))
 
 
-def paged_chunk_attend(q, k_pages, v_pages, table, lens, layer=None):
+def paged_chunk_attend(q, k_pages, v_pages, table, lens, layer=None,
+                       window=None):
     """Attend C query positions per slot against the global paged pools
     (with ``layer``: that layer of the stacked pools, in place):
     position t of slot b sees tokens ``0 .. lens[b]+t`` (its own K/V
@@ -1006,19 +1087,23 @@ def paged_chunk_attend(q, k_pages, v_pages, table, lens, layer=None):
     On TPU one chunk kernel (:func:`_paged_chunk_pallas`): a slot's C
     positions are one query block, and each of its K/V pages is walked once
     for all of them.  Elsewhere the dense reference, which gathers the
-    slot's pages once for all C positions.
+    slot's pages once for all C positions.  ``window`` (static): position
+    t sees its last ``window`` keys only, and the kernel sweeps only their
+    pages.
 
     q: [B, C, H, D] -> [B, C, H, D]."""
     B, C, H, D = q.shape
     if jax.default_backend() == "tpu":
         return _chunk_attend(_paged_chunk_flash_pallas, q,
-                             (k_pages, v_pages), (), table, lens, layer)
+                             (k_pages, v_pages), (), table, lens, layer,
+                             window)
     NP = table.shape[1]
     ps, HKV = k_pages.shape[-3:-1]
     lens2 = _chunk_lens(lens, C, NP * ps)
     k, v = (_gather_pages(p, table, layer)[..., :D].reshape(
         B, NP * ps, HKV, D) for p in (k_pages, v_pages))
-    return _gathered_chunk_attend(q, k, v, lens2, 1.0 / math.sqrt(D))
+    return _gathered_chunk_attend(q, k, v, lens2, 1.0 / math.sqrt(D),
+                                  window)
 
 
 # --------------------------------------------------- int8 quantized pools
@@ -1339,7 +1424,7 @@ def paged_chunk_attend_quant(q, k_pages, v_pages, k_scales, v_scales,
 # ``chunk_attention``, the writer by its kernel name.
 
 
-def paged_cache_attend(q, k, v, cache, prefill_attend):
+def paged_cache_attend(q, k, v, cache, prefill_attend, window=None):
     """One decoder layer's attention through the paged KV cache: this
     layer's K/V chunk goes into the pools, then ``q`` attends the pages.
 
@@ -1361,6 +1446,12 @@ def paged_cache_attend(q, k, v, cache, prefill_attend):
     - ``"served_chunk"``: C tokens a slot at the slot's own position (a
       prefill chunk, a speculative verify) through the chunk kernel.
 
+    ``window`` (a sliding-window layer): position t attends its last
+    ``window`` keys only, its own included, and the kernels sweep only the
+    pages that hold them; ``prefill_attend`` applies the window itself.
+    Such a layer's table may map the pages behind the window to pages that
+    hold later tokens: they are never read.
+
     Returns ``(attn [B, C, H, D], pools)``."""
     tag, layer, pools, table, lens = cache
     if tag not in ("served", "served_chunk"):
@@ -1380,10 +1471,13 @@ def paged_cache_attend(q, k, v, cache, prefill_attend):
             pl, kk, vv, tb, ln, layer),
         k, v, table, lens, *pools, n_outs=None, op_name="paged_write")
     quantized = len(pools) == 4
+    # the entries take no window keyword unless the layer has one
+    win = {} if window is None else {"window": int(window)}
     if tag == "served_chunk":
         attend = paged_chunk_attend_quant if quantized else paged_chunk_attend
         attn = _apply(
-            lambda qq, tb, ln, *pl: attend(qq, *pl, tb, ln, layer=layer),
+            lambda qq, tb, ln, *pl: attend(qq, *pl, tb, ln, layer=layer,
+                                           **win),
             q, table, lens, *pools, op_name="paged_attention")
     else:
         # the decode entries take ONE query row a slot and the length that
@@ -1392,6 +1486,6 @@ def paged_cache_attend(q, k, v, cache, prefill_attend):
         attn = _apply(
             lambda qq, tb, ln, *pl: attend(
                 qq[:, 0], *pl, tb, ln.astype(jnp.int32) + 1,
-                layer=layer)[:, None],
+                layer=layer, **win)[:, None],
             q, table, lens, *pools, op_name="paged_attention")
     return attn, pools

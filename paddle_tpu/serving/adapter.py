@@ -63,7 +63,11 @@ the decode rows are the slots and the one-request programs (``prefill``,
 ``prefill_chunk``) take the slot's index as one more int32 operand behind
 ``lens``, which the engine appends (``ServingEngine._prefill_extra``) for an
 adapter that says ``slot_state``; without one the tuple is ``(kp, vp)`` and
-the programs take what :class:`GPTAdapter`'s take.
+the programs take what :class:`GPTAdapter`'s take.  A decoder that names
+``window_layers`` (sliding-window layers beside full ones) gets A SECOND
+PAGE POOL for them, ``(kp, vp, kw, vw)``, and a table twice as wide: the
+full layers' row, then the window layers' row, whose pages a sequence
+reuses behind its window (``block_manager.WindowPages``).
 """
 
 from __future__ import annotations
@@ -136,12 +140,14 @@ class PagedAdapter:
                 "dtype": str(self.dtype)}
 
     # ----------------------------------------------------------- pool hooks
-    def _page_pool(self, num_pages):
+    def _page_pool(self, num_pages, layers=None):
         """One zeroed payload pool [L, P, ps, h, d]: every page-holding
-        layer's pages in one array, ``d`` the head size in whole lanes."""
+        layer's pages in one array (``layers`` of them: by default all),
+        ``d`` the head size in whole lanes."""
         from ..ops.paged_attention import pool_lane_dim
 
-        return jnp.zeros((self.num_layers, int(num_pages), self.page_size,
+        return jnp.zeros((self.num_layers if layers is None else layers,
+                          int(num_pages), self.page_size,
                           self.num_kv_heads, pool_lane_dim(self.head_dim)),
                          self.dtype)
 
@@ -405,10 +411,16 @@ class StatedCacheAdapter(PagedAdapter):
       (``(layers, rows, width)`` of the state ONE sequence carries) and
       ``"loop_steps"`` (how often the stack runs: a fact for the
       signature, the rows are counted already);
+    - optionally ``"window_layers"`` and ``"window"``: that many layers
+      see only their last ``window`` keys and keep their pages in a pool
+      of their own (``attention_layers`` then counts the full layers
+      alone);
     - ``model.model`` is the decoder, called as ``decoder(ids, position_ids,
       cache=(tag, (kp, vp), table, lens))`` and returning ``(hidden, (kp,
       vp))``; with a state also ``conv_state=[L_state, B, R, W],
-      valid=[B]``, returning the state after the call third;
+      valid=[B]``, returning the state after the call third; with window
+      layers the pools are ``(kp, vp, kw, vw)`` and the table ``[B, 2 *
+      NP]``, the full layers' row and then the window layers';
     - ``model.head_weight()`` is the head as ``[V, H]``.
 
     The pool tuple is ``(kp, vp)`` or ``(kp, vp, state)``: ``kp`` / ``vp``
@@ -448,6 +460,30 @@ class StatedCacheAdapter(PagedAdapter):
         #: how often the decoder runs its layers (1: once)
         self.loop_steps = int(need.get("loop_steps", 1))
         self.num_slots = int(num_slots)
+        #: sliding-window layers, their window, and the pages of their
+        #: pool (``plan_window``)
+        self.window_layers = int(need.get("window_layers", 0))
+        self.window = int(need["window"]) if self.window_layers else None
+        self.window_pages = None
+        if self.window_layers:
+            if self.slot_state:
+                raise NotImplementedError(
+                    "window layers beside a per-slot state")
+            self.n_pools = 4
+
+    def plan_window(self, num_slots, table_width, widest_write):
+        """``(pages of the window pool, pages a sequence holds of it)``,
+        or None without window layers.  A sequence holds enough pages for
+        its window and the widest dispatch that writes it, ``widest_write``
+        tokens (a chunk: its keys are written before they are attended),
+        and one for a window that starts inside a page; never more than
+        the table's width."""
+        if not self.window_layers:
+            return None
+        per_seq = min(-(-(self.window + int(widest_write))
+                        // self.page_size) + 1, int(table_width))
+        self.window_pages = int(num_slots) * per_seq
+        return self.window_pages, per_seq
 
     def signature(self):
         sig = dict(super().signature(), cache_layers=int(self.num_layers),
@@ -455,6 +491,9 @@ class StatedCacheAdapter(PagedAdapter):
         if self.slot_state:
             sig.update(state_shape=list(self.state_shape),
                        num_slots=int(self.num_slots))
+        if self.window_layers:
+            sig.update(window_layers=self.window_layers, window=self.window,
+                       window_pages=self.window_pages)
         return sig
 
     # ----------------------------------------------------------- pool hooks
@@ -462,6 +501,9 @@ class StatedCacheAdapter(PagedAdapter):
         """``(kp, vp)`` over the cache rows, and with a state ``(kp, vp,
         state)``: the zeroed per-slot state with its scratch row."""
         kp = self._page_pool(num_pages)
+        if self.window_layers:
+            kw = self._page_pool(self.window_pages + 1, self.window_layers)
+            return kp, jnp.zeros_like(kp), kw, jnp.zeros_like(kw)
         if not self.slot_state:
             return kp, jnp.zeros_like(kp)
         layers, rows, width = self.state_shape
@@ -470,6 +512,8 @@ class StatedCacheAdapter(PagedAdapter):
         return kp, jnp.zeros_like(kp), state
 
     def pool_owners(self):
+        if self.window_layers:
+            return (("kv.pages", (0, 1)), ("kv.window_pages", (2, 3)))
         if not self.slot_state:
             return super().pool_owners()
         return (("kv.pages", (0, 1)), ("state.slots", (2,)))
@@ -496,7 +540,7 @@ class StatedCacheAdapter(PagedAdapter):
                 + f"; got {len(args)} trailing args")
         table, lens = args[n], args[n + 1]
         if not self.slot_state:
-            return tuple(args[:2]), None, table, lens, None
+            return tuple(args[:n]), None, table, lens, None
         if len(args) == n + 3:
             slot = args[n + 2].astype(jnp.int32)
         else:

@@ -36,6 +36,14 @@ host DRAM first, and a later allocate whose match ends where a spilled
 prefix begins resurrects them into fresh device slots — still cached,
 one PCIe copy instead of a forward pass.  In legacy mode memory sharing
 is real but prefill compute still runs per sequence.
+
+Window pages (``window=(pages, per_seq)``): a decoder whose sliding-window
+layers see only the last ``window`` keys keeps those layers' K/V in a
+SECOND pool of its own (:class:`WindowPages`).  A sequence holds at most
+``per_seq`` pages of it, whatever its length, and reuses them round-robin:
+logical page ``j`` lies in its ``j % per_seq``-th page, which by then holds
+only keys behind every window that can still look.  Admission counts both
+pools; :meth:`BlockManager.table_row` lays both tables out side by side.
 """
 
 from __future__ import annotations
@@ -53,12 +61,16 @@ class PageAllocation:
     tokens they cover need no prefill compute; it is always 0 in legacy
     (exact-key) mode, where sharing saves memory but not compute."""
 
-    __slots__ = ("pages", "shared_keys", "cached_pages")
+    __slots__ = ("pages", "shared_keys", "cached_pages", "window_pages")
 
-    def __init__(self, pages, shared_keys=(), cached_pages=0):
+    def __init__(self, pages, shared_keys=(), cached_pages=0,
+                 window_pages=()):
         self.pages = list(pages)
         self.shared_keys = tuple(shared_keys)
         self.cached_pages = int(cached_pages)
+        #: the sequence's pages of the window pool (:class:`WindowPages`),
+        #: in the order its logical pages cycle through them
+        self.window_pages = list(window_pages)
 
     @property
     def num_shared(self):
@@ -68,10 +80,91 @@ class PageAllocation:
         return len(self.pages)
 
 
+class WindowPages:
+    """The pool of a decoder's sliding-window layers: ``num_pages`` pages
+    (its scratch page is row ``num_pages``), of which one sequence holds
+    ``min(pages its tokens fill, per_seq)``.  Logical page ``j`` of a
+    sequence that holds ``n`` lies in its ``j % n``-th page: with ``n ==
+    per_seq`` the page it reuses holds keys that no window can reach any
+    more, the writer's own dispatch included, which is what ``per_seq``
+    is sized for (``ceil((window + widest write) / page_size) + 1``)."""
+
+    def __init__(self, num_pages, per_seq, page_size, replica="0"):
+        if per_seq < 1 or num_pages < per_seq:
+            raise ValueError(f"window pool of {num_pages} pages for "
+                             f"{per_seq} pages a sequence")
+        self.num_pages, self.per_seq = int(num_pages), int(per_seq)
+        self.page_size = int(page_size)
+        self._free = collections.deque(range(self.num_pages))
+        self._most = 0
+        from ..profiler import metrics as _metrics
+
+        self._m_recycled = _metrics.bind(_metrics.counter(
+            "serving.swa_pages_recycled",
+            "window-layer pages a sequence wrote a second time, over keys "
+            "behind its window (logical pages past the pages it holds), "
+            "counted when it retires"), replica=replica)
+        self._m_in_use = _metrics.bind(_metrics.gauge(
+            "serving.swa_pages_in_use",
+            "pages of the window layers' pool held by live sequences"),
+            replica=replica)
+        self._m_most = _metrics.bind(_metrics.gauge(
+            "serving.swa_pages_per_slot_max",
+            "the most window-layer pages one sequence has held, over the "
+            "engine's life (at most per_seq)"), replica=replica)
+
+    def pages_for(self, num_tokens):
+        return min(-(-int(num_tokens) // self.page_size), self.per_seq)
+
+    @property
+    def free_pages(self):
+        return len(self._free)
+
+    @property
+    def used_pages(self):
+        return self.num_pages - len(self._free)
+
+    def take(self, num_tokens):
+        """The pages of a sequence of ``num_tokens`` (caller checked)."""
+        pages = [self._free.popleft() for _ in range(self.pages_for(
+            num_tokens))]
+        self._most = max(self._most, len(pages))
+        self._m_most.set(self._most)
+        self._m_in_use.set(self.used_pages)
+        return pages
+
+    def give_back(self, pages, reached=None):
+        """Return a retired sequence's pages; ``reached``: the tokens it
+        got to, for the count of pages written twice."""
+        if reached is not None and pages:
+            self._m_recycled.inc(max(-(-int(reached) // self.page_size)
+                                     - len(pages), 0))
+        self._free.extend(pages)
+        self._m_in_use.set(self.used_pages)
+
+    def row(self, pages, num_tokens, width):
+        """The window table's row ``[width]``: logical page ``j`` of the
+        ``num_tokens`` the sequence may reach at its ``j % len(pages)``-th
+        page, every other entry at the scratch page."""
+        import numpy as np
+
+        row = np.full((width,), self.num_pages, np.int32)
+        n = min(-(-int(num_tokens) // self.page_size), width)
+        if pages and n:
+            row[:n] = np.asarray(pages, np.int32)[np.arange(n) % len(pages)]
+        return row
+
+    def stats(self):
+        return {"num_pages": self.num_pages, "per_seq": self.per_seq,
+                "used_pages": self.used_pages, "free_pages": self.free_pages,
+                "most_per_seq": self._most}
+
+
 class BlockManager:
     def __init__(self, num_pages, page_size, prefix_sharing=False,
                  replica="0", bytes_per_page=None, pool_dtype=None,
-                 shards=1, radix=False, spill=None, state_bytes_per_seq=0):
+                 shards=1, radix=False, spill=None, state_bytes_per_seq=0,
+                 window=None):
         if num_pages < 1:
             raise ValueError(f"num_pages must be >= 1, got {num_pages}")
         if page_size < 1:
@@ -97,6 +190,12 @@ class BlockManager:
         # recurrent state a slot (hybrid decoders), whatever its length
         self.state_bytes_per_seq = int(state_bytes_per_seq)
         self.shards = int(shards)
+        #: the window layers' pool, from ``window=(pages, per_seq)``
+        self.window = None if window is None else WindowPages(
+            *window, page_size=self.page_size, replica=self.replica)
+        if self.window is not None and self.prefix_sharing:
+            raise ValueError("window pages are private to a sequence: "
+                             "prefix sharing has no window-pool rule")
         self._free = collections.deque(range(self.num_pages))
         self._active = {}                       # prefix key -> [page, refs]
         self._idle = collections.OrderedDict()  # prefix key -> page (refs 0)
@@ -190,6 +289,8 @@ class BlockManager:
             st["kv_bytes_per_token"] = self.bytes_per_page / self.page_size
         if self.state_bytes_per_seq:
             st["state_bytes_per_seq"] = self.state_bytes_per_seq
+        if self.window is not None:
+            st["window_pages"] = self.window.stats()
         if self.prefix_sharing:
             # hit TOKENS, not just hit counts: saved_tokens is hit pages x
             # page_size, so a 100-page shared-run hit reads as 100x the
@@ -316,6 +417,10 @@ class BlockManager:
             return self._plan(prompt_ids, num_tokens) is not None
 
     def _plan(self, prompt_ids, num_tokens):
+        if self.window is not None \
+                and self.window.pages_for(num_tokens) \
+                > self.window.free_pages:
+            return None
         need = self.pages_for(num_tokens)
         n_sharable = 0
         if self.prefix_sharing:
@@ -363,7 +468,10 @@ class BlockManager:
                 return None
             if self.radix:
                 return self._allocate_radix(prompt_ids, plan)
-            return self._allocate_legacy(prompt_ids, plan)
+            alloc = self._allocate_legacy(prompt_ids, plan)
+            if self.window is not None:
+                alloc.window_pages = self.window.take(num_tokens)
+            return alloc
 
     def _allocate_radix(self, prompt_ids, plan):
         need, n_sharable, blocks = plan
@@ -447,11 +555,39 @@ class BlockManager:
         # legacy exact-key sharing saves memory, never compute
         return PageAllocation(pages, keys, cached_pages=0)
 
-    def free(self, alloc: PageAllocation):
+    def table_row(self, alloc, num_tokens, width):
+        """The page-table row of a sequence: its pages, and where a window
+        pool is kept, ``[width]`` entries of its full pages (scratch after
+        them) then ``[width]`` of :meth:`WindowPages.row`."""
+        import numpy as np
+
+        row = np.asarray(alloc.pages, np.int32)
+        if self.window is None:
+            return row
+        full = np.full((width,), self.num_pages, np.int32)
+        full[:len(row)] = row
+        return np.concatenate([full, self.window.row(
+            alloc.window_pages, num_tokens, width)])
+
+    def scratch_row(self, width):
+        """A page-table row that holds nothing: every entry at a scratch
+        page (each pool's own where there are two)."""
+        import numpy as np
+
+        row = np.full((width,), self.num_pages, np.int32)
+        if self.window is None:
+            return row
+        return np.concatenate([row, self.window.row((), 0, width)])
+
+    def free(self, alloc: PageAllocation, reached=None):
         """Release a retired sequence's pages: private pages return to the
         free list; shared prefix pages decref and park in the idle cache
-        when the last holder leaves."""
+        when the last holder leaves.  ``reached``: the tokens the sequence
+        got to (the window pool counts the pages it wrote twice)."""
         with self._mut:
+            if self.window is not None:
+                self.window.give_back(alloc.window_pages, reached)
+                alloc.window_pages = []
             if self.radix:
                 if alloc.shared_keys:
                     full = alloc.shared_keys[-1]

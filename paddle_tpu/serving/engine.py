@@ -546,6 +546,11 @@ class ServingEngine:
                 prefix_sharing=prefix_sharing, prefix_cache=prefix_cache,
                 kv_spill=kv_spill, speculative_k=speculative_k,
                 kv_dtype=kv_dtype, mesh=mesh)
+        if getattr(self._adapter, "window_layers", 0):
+            self._refuse_with_window_pages(
+                prefix_sharing=prefix_sharing, prefix_cache=prefix_cache,
+                kv_spill=kv_spill, speculative_k=speculative_k,
+                kv_dtype=kv_dtype, mesh=mesh)
         self._refuse_what_the_adapter_lacks(
             self._adapter, speculative_k=speculative_k, kv_dtype=kv_dtype,
             mesh=mesh)
@@ -565,6 +570,15 @@ class ServingEngine:
         if num_pages is None:
             num_pages = self.num_slots * self.table_width  # full residency
         self._num_pages = int(num_pages)
+        # sliding-window layers keep their pages in a pool of their own, a
+        # sequence at most what its window and the widest write need
+        # (adapter.plan_window): a chunk, or a whole prompt where prompts
+        # are not chunked
+        plan = getattr(self._adapter, "plan_window", None)
+        self._window_plan = plan(
+            self.num_slots, self.table_width,
+            self._prefill_bucket(self._chunk_tokens) if self._chunk_tokens
+            else self.table_width * self.page_size) if plan else None
         # hierarchical KV cache (README "Hierarchical KV cache"):
         # prefix_cache="radix" swaps the BlockManager's exact-key prefix
         # matching for the page-granular radix index (serving/
@@ -614,6 +628,8 @@ class ServingEngine:
         # padded table tails point at it (every table entry must be a valid
         # pool row; junk written there is never attended)
         self._scratch = int(num_pages)
+        # a table row that holds nothing (each pool's scratch page)
+        self._scratch_row = self._bm.scratch_row(self.table_width)
         # dp-replica placement (device=): commit this replica's params/
         # buffers and page pools to its device — uncommitted per-step host
         # arrays (table/lens/ids) follow the committed operands, so every
@@ -726,8 +742,7 @@ class ServingEngine:
         self._h_last = np.zeros((self.num_slots, 1), np.int64)
         self._h_lens = np.zeros((self.num_slots,), np.int32)
         self._h_temps = np.zeros((self.num_slots,), np.float32)
-        self._h_table = np.full((self.num_slots, self.table_width),
-                                self._scratch, np.int32)
+        self._h_table = np.tile(self._scratch_row, (self.num_slots, 1))
         if self._spec_k:
             self._h_ids = np.zeros((self.num_slots, self._spec_k + 1),
                                    np.int64)
@@ -994,6 +1009,35 @@ class ServingEngine:
                 f"paged KV; not supported with it: {why}")
 
     @staticmethod
+    def _refuse_with_window_pages(prefix_sharing, prefix_cache, kv_spill,
+                                  speculative_k, kv_dtype, mesh):
+        """A decoder with sliding-window layers keeps their pages in a pool
+        of its own where a sequence reuses the pages behind its window
+        (block_manager.WindowPages): every mechanism that assumes a layer
+        holds every page of a sequence is refused here by name."""
+        why = None
+        if prefix_sharing or prefix_cache is not None:
+            why = ("prefix_sharing / prefix_cache: a shared page run would "
+                   "need the window layers' pages too, and those a "
+                   "sequence overwrites behind its window")
+        elif kv_spill:
+            why = ("kv_spill: the host spill tier snapshots pages of one "
+                   "pool, by content address; window pages have none")
+        elif speculative_k:
+            why = ("speculative_k: a verify chunk writes drafts into "
+                   "window pages that a rejected draft would have to give "
+                   "back")
+        elif kv_dtype == "int8":
+            why = ("kv_dtype='int8': the windowed kernels read pages in the "
+                   "model's type, and the window pool has no scale pools")
+        elif mesh is not None:
+            why = ("mesh=: the head-sharded kernels take no window")
+        if why is not None:
+            raise ValueError(
+                f"this model keeps its sliding-window layers' pages in a "
+                f"pool of their own; not supported with it: {why}")
+
+    @staticmethod
     def _refuse_what_the_adapter_lacks(adapter, speculative_k, kv_dtype,
                                        mesh):
         """The served paths that exist for :class:`GPTAdapter` only
@@ -1132,7 +1176,8 @@ class ServingEngine:
                             pool_dtype=self._pool_dtype,
                             shards=self._mp,
                             radix=self._radix, spill=self._spill,
-                            state_bytes_per_seq=self._state_bytes_per_slot)
+                            state_bytes_per_seq=self._state_bytes_per_slot,
+                            window=self._window_plan)
 
     # ------------------------------------------------- hierarchical KV cache
     def _spill_snapshot(self, page):
@@ -1342,7 +1387,7 @@ class ServingEngine:
             return
         guard = self._numeric_guard
         ids = np.zeros((1, s_pad), np.int64)
-        table = np.full((1, self.table_width), self._scratch, np.int32)
+        table = self._scratch_row[None].copy()
         lens = np.asarray([s_pad], np.int32)   # junk K/V lands in scratch
         temps = np.zeros((1,), np.float32)
         rkey = self._base_key
@@ -1373,7 +1418,7 @@ class ServingEngine:
         guard = self._numeric_guard
         ids = np.zeros((1, c_pad), np.int64)
         nvalid = np.asarray([c_pad], np.int32)
-        table = np.full((1, self.table_width), self._scratch, np.int32)
+        table = self._scratch_row[None].copy()
         lens = np.zeros((1,), np.int32)
         temps = np.zeros((1,), np.float32)
         rkey = self._base_key
@@ -2548,7 +2593,8 @@ class ServingEngine:
         cached = min(alloc.cached_pages * self.page_size, S0 - 1) \
             if alloc.cached_pages else 0
         width = self._prefill_bucket(S0 - cached)
-        table_row = np.asarray(alloc.pages, np.int32)
+        table_row = self._bm.table_row(alloc, S0 + req.max_new_tokens,
+                                       self.table_width)
         if cached > 0:
             fam = self._prefill_cached_family(width, alloc.cached_pages)
             cm = _tracing.span(
@@ -2591,7 +2637,7 @@ class ServingEngine:
         write."""
         ids = np.zeros((1, width), np.int64)
         ids[0, :nval] = req.prompt[start:start + nval]
-        table = np.full((1, self.table_width), self._scratch, np.int32)
+        table = self._scratch_row[None].copy()
         table[0, :len(table_row)] = table_row
         temps = np.asarray([req.sampling.temperature], np.float32)
         if chunk:
@@ -2617,7 +2663,7 @@ class ServingEngine:
         step's ``last`` on the device."""
         slot.prefilled = None
         slot.unread = 1
-        self._h_table[i, :] = self._scratch
+        self._h_table[i, :] = self._scratch_row
         self._h_table[i, :len(slot.table_row)] = slot.table_row
         self._h_lens[i] = slot.length
         self._h_temps[i] = slot.temp
@@ -2800,7 +2846,7 @@ class ServingEngine:
         if drop:
             slot.gen += 1
         if self._slots[i] is slot:
-            self._bm.free(slot.alloc)
+            self._bm.free(slot.alloc, reached=slot.length)
             self._release_tenant(slot.req)
             self._slots[i] = None
             self._clear_slot_row(i, slot)
@@ -2813,7 +2859,8 @@ class ServingEngine:
         decode batch never waits out a monolithic long-prompt dispatch.
         The lane's persistent host row stays inert (scratch table, length
         0) until the final chunk seeds decode."""
-        table_row = np.asarray(alloc.pages, np.int32)
+        table_row = self._bm.table_row(
+            alloc, len(req.prompt) + req.max_new_tokens, self.table_width)
         if req.handle.admitted_at is None:   # TTFT decomposition: queue_s
             req.handle.admitted_at = time.time()
         slot = _Slot(req, alloc, table_row)
@@ -3290,7 +3337,7 @@ class ServingEngine:
         """Reset slot ``i``'s persistent host-buffer row (and drafter
         state) after retirement — the row points at scratch again so the
         next dispatch treats the lane as inactive."""
-        self._h_table[i, :] = self._scratch
+        self._h_table[i, :] = self._scratch_row
         self._h_lens[i] = 0
         self._h_temps[i] = 0.0
         self._h_last[i, 0] = 0
@@ -3304,7 +3351,7 @@ class ServingEngine:
 
     def _reset_host_buffers(self):
         """Full reset (engine restart / stop): every lane inactive."""
-        self._h_table[:] = self._scratch
+        self._h_table[:] = self._scratch_row
         self._h_lens[:] = 0
         self._h_temps[:] = 0.0
         self._h_last[:] = 0
@@ -3551,7 +3598,7 @@ class ServingEngine:
                           "trace_id": s.handle.trace_id,
                           "status": s.handle.status, "length": s.length,
                           "produced": s.produced, "max_new": s.max_new,
-                          "pages": len(s.table_row),
+                          "pages": len(s.alloc.pages),
                           "prefilled": s.prefilled})
         st["slots"] = slots
         return st

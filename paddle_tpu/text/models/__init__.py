@@ -18,3 +18,6 @@ from .lfm2 import (  # noqa: F401
 from .ouro import (  # noqa: F401
     OuroConfig, OuroForCausalLM, OuroModel,
 )
+from .smallthinker import (  # noqa: F401
+    SmallThinkerConfig, SmallThinkerForCausalLM, SmallThinkerModel,
+)

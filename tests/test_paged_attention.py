@@ -255,3 +255,72 @@ def test_cache_seam_refuses_a_tag_it_does_not_know():
              Tensor(jnp.zeros((1,), jnp.int32)))
     with pytest.raises(ValueError, match="unknown paged cache tag 'paged'"):
         paged_cache_attend(x, x, x, cache, None)
+
+
+# ------------------------------------------------------ sliding windows
+def _window_oracle(q, k_pages, v_pages, table, lens, window):
+    """Independent numpy oracle of a window: row b attends its last
+    ``window`` keys, ``lens[b] - window .. lens[b] - 1``."""
+    B, H, D = q.shape
+    HKV = k_pages.shape[2]
+    g = H // HKV
+    out = np.zeros((B, H, D), "float32")
+    for b in range(B):
+        ks = np.concatenate([np.asarray(k_pages[p], "float32")
+                             for p in np.asarray(table[b])], 0)
+        vs = np.concatenate([np.asarray(v_pages[p], "float32")
+                             for p in np.asarray(table[b])], 0)
+        L = int(lens[b])
+        lo = max(L - window, 0)
+        for h in range(H):
+            s = ks[lo:L, h // g] @ np.asarray(q[b, h], "float32") \
+                / math.sqrt(D)
+            p_ = np.exp(s - s.max())
+            out[b, h] = (p_ / p_.sum()) @ vs[lo:L, h // g]
+    return out
+
+
+def test_windowed_ref_matches_oracle():
+    q, kp, vp, table, lens = _setup()
+    got = np.asarray(paged_attention_ref(q, kp, vp, table, lens, window=11))
+    np.testing.assert_allclose(
+        got, _window_oracle(q, kp, vp, table, lens, 11), rtol=2e-5,
+        atol=2e-5)
+
+
+@pytest.mark.parametrize("kind,HKV,D,dtype", [
+    ("one page a step", 4, 16, "float32"),
+    ("blocks of pages by DMA", 2, 128, "bfloat16")])
+@pytest.mark.parametrize("window", [1, 8, 13, 64])
+def test_windowed_kernels_match_ref(kind, HKV, D, dtype, window):
+    """Both decode kernels with a window: rows shorter than the window,
+    rows whose window starts inside a page and on a page edge; the pages
+    before each row's window hold infinities, which only a sweep that
+    starts at the window's first page leaves unread."""
+    rs = np.random.RandomState(window)
+    B, ps, NP, H = 4, 8, 6, 2 * HKV
+    P = B * NP
+    q = jnp.asarray(rs.randn(B, H, D), dtype)
+    kp = jnp.asarray(rs.randn(P, ps, HKV, D), dtype)
+    vp = jnp.asarray(rs.randn(P, ps, HKV, D), dtype)
+    table = jnp.asarray(rs.permutation(P).reshape(B, NP).astype("int32"))
+    lens = np.array([5, 29, 40, 48], "int32")
+    want = _window_oracle(q, kp, vp, table, lens, window)
+    for b, n in enumerate(lens):
+        for i in range(max(int(n) - window, 0) // ps):
+            page = int(table[b, i])
+            kp, vp = (p.at[page].set(jnp.inf) for p in (kp, vp))
+    stacked = tuple(jnp.stack([jnp.full_like(p, 7.0), p]) for p in (kp, vp))
+    got = _paged_flash_pallas(q, *stacked, table, jnp.asarray(lens),
+                              1.0 / math.sqrt(D), True, 1, window)
+    np.testing.assert_allclose(np.asarray(got, "float32"), want,
+                               rtol=2e-2 if dtype == "bfloat16" else 2e-4,
+                               atol=2e-2 if dtype == "bfloat16" else 2e-4)
+
+
+def test_no_window_is_the_unbounded_sweep():
+    """A window at least as long as every row reads what no window reads."""
+    q, kp, vp, table, lens = _setup(seed=4)
+    a = np.asarray(paged_attention_ref(q, kp, vp, table, lens))
+    b = np.asarray(paged_attention_ref(q, kp, vp, table, lens, window=10**6))
+    np.testing.assert_array_equal(a, b)

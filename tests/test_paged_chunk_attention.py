@@ -200,3 +200,55 @@ def test_public_entries_reach_the_kernel(as_tpu, kind, sharded):
     np.testing.assert_allclose(np.asarray(got),
                                _reference(q, pools, scales, table, lens),
                                rtol=5e-5, atol=5e-5)
+
+
+# ------------------------------------------------------ sliding windows
+def _window_reference(q, pools, table, lens, window):
+    """Independent numpy oracle: position t of slot b attends keys
+    ``lens[b] + t - window + 1 .. lens[b] + t``."""
+    B, C, H, _ = q.shape
+    HKV = pools[0].shape[2]
+    g = H // HKV
+    k, v = (np.asarray(p, np.float32)[np.asarray(table)].reshape(
+        B, CAP, HKV, D) for p in pools)
+    out = np.zeros((B, C, H, D), np.float32)
+    qn = np.asarray(q, np.float32)
+    for b in range(B):
+        for t in range(C):
+            at = min(lens[b] + t, CAP - 1)
+            lo = max(lens[b] + t - window + 1, 0)
+            for h in range(H):
+                s = k[b, lo:at + 1, h // g] @ qn[b, t, h] / math.sqrt(D)
+                p_ = np.exp(s - s.max())
+                out[b, t, h] = (p_ / p_.sum()) @ v[b, lo:at + 1, h // g]
+    return out
+
+
+@pytest.mark.parametrize("window", [1, 12, 64, 200])
+@pytest.mark.parametrize("C", [3, 130])
+def test_windowed_chunk_kernel_matches_reference(window, C):
+    """Slots whose window starts before the chunk, inside its first page,
+    and (window 12 at base 5: key 0 still visible to the first positions)
+    a chunk that straddles the window's first page; the pages wholly
+    behind every position's window hold infinities."""
+    q, pools, scales, table = _make(4, C, 4, 2, "bf16", seed=window + C)
+    lens = [5, 3 * PS, 150, 250 - C]
+    want = _window_reference(q, pools, table, lens, window)
+    dense = _reference_windowed(q, pools, table, lens, window)
+    np.testing.assert_allclose(dense, want, rtol=5e-5, atol=5e-5)
+    poisoned = list(pools)
+    for b, n in enumerate(lens):
+        for i in range(max(n - window + 1, 0) // PS):
+            poisoned = [p.at[int(table[b, i])].set(jnp.inf)
+                        for p in poisoned]
+    got = np.asarray(pa._paged_chunk_flash_pallas(
+        q, *(p[None] for p in poisoned), table, jnp.asarray(lens, jnp.int32),
+        1.0 / math.sqrt(D), True, 0, window))
+    np.testing.assert_allclose(got, want, rtol=5e-5, atol=5e-5)
+
+
+def _reference_windowed(q, pools, table, lens, window):
+    """The public dense path with the window (what the CPU serves)."""
+    return np.asarray(pa.paged_chunk_attend(
+        q, *pools, table, jnp.asarray(lens, jnp.int32), window=window),
+        np.float32)

@@ -458,3 +458,71 @@ def test_looped_programs_hold_48_layer_bodies_and_fit_the_chip(
     assert mem.temp_size_in_bytes < 1.5e9
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes) < 15.0e9
+
+
+@pytest.mark.parametrize("closure", ["step", "prefill_chunk"])
+def test_window_programs_fit_the_chip_at_the_cells_sizes(monkeypatch,
+                                                         one_chip, closure):
+    """``StatedCacheAdapter``'s decode and chunk programs for the eight-layer
+    cut of SmallThinker-21BA3B as
+    ``smallthinker_21b_a3b_l8.batch_closed_16k`` serves it (32 slots,
+    tables 1,024 pages wide, bfloat16): 7.93 GB of weights never built here,
+    the full layers' pool of 32,769 pages and the window layers' of 8,737
+    (273 a slot), touched by the kernels alone: a writer and an attention
+    kernel a layer, and the 8 x 3 grouped products through the repo's own
+    kernel, none left to ``ragged_dot`` (``costs/smallthinker
+    .grouped_kernels``)."""
+    import json
+    import os
+
+    import chip_smoke
+    from paddle_tpu.serving import StatedCacheAdapter
+    from paddle_tpu.text.models.smallthinker import SmallThinkerForCausalLM
+
+    spec = importlib.import_module("chipbench.spec")
+    ref = spec.load_module("reference", "smallthinker")
+    family = spec.load_module("models", "smallthinker")
+    with open(os.path.join(spec.HERE, "configs",
+                           "smallthinker_21b_a3b_l8.json")) as f:
+        cfg = json.load(f)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    # a scalar stands in for every leaf; its shape comes from the reference
+    model = SmallThinkerForCausalLM(
+        family.program_config(cfg, "bfloat16"),
+        param_init=lambda name, shape: jnp.zeros((), jnp.bfloat16)).eval()
+    shapes = ref.param_shapes(cfg)
+    for name, leaf in model.named_parameters():
+        leaf._value = jax.ShapeDtypeStruct(shapes[name], leaf._value.dtype)
+    slots = 32
+    adapter = StatedCacheAdapter(model, 16, slots)
+    assert adapter.plan_window(slots, 1024, 256) == (slots * 273, 273)
+    params, bufs = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), adapter.params_and_buffers())
+    assert sum(math.prod(v.shape) for v in params.values()) == 3_966_937_600
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pools = tuple(sds(p.shape, p.dtype) for p in jax.eval_shape(
+        lambda: adapter.init_pools(slots * 1024 + 1)))
+    assert [p.shape for p in pools] == [(2, 32769, 16, 4, 128)] * 2 \
+        + [(6, 8737, 16, 4, 128)] * 2
+    B, width = (slots, 1) if closure == "step" else (1, 256)
+    lead = (sds((B, width), jnp.int64),) + (
+        (sds((B,), jnp.int32),) if closure == "prefill_chunk" else ())
+    first = 2 + len(lead)
+    compiled = jax.jit(
+        getattr(adapter, closure),
+        donate_argnums=tuple(range(first, first + 4))).lower(
+        params, bufs, *lead, *pools, sds((B, 2048), jnp.int32),
+        sds((B,), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert chip_smoke.pool_sized_instructions(
+        text, [p.shape for p in pools]) == []
+    assert text.count("tpu_custom_call") == 2 * 8 + 24
+    assert "ragged-dot" not in text
+    assert len(re.findall(r'custom-call\([^\n]*op_name="[^"]*swa_attention/'
+                          r'[^"]*paged_write', text)) == 6
+    assert mem.temp_size_in_bytes < 100e6
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) < 12.5e9

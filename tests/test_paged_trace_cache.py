@@ -232,3 +232,58 @@ def test_served_programs_trace_as_before_and_a_body_once(monkeypatch, model,
     assert not any(e[0] in ("jit", "pjit") and e[4] in CACHED
                    for e in cached)
     assert not any("jit(" in e[3] for e in cached)
+
+
+def _smallthinker_adapter():
+    """Two NoPE full layers and six window layers at toy widths, heads of
+    the published 128 lanes, served on pages of 16 over 4 slots: the window
+    layers' pages in a pool of their own."""
+    from paddle_tpu.serving import StatedCacheAdapter
+    from paddle_tpu.text.models.smallthinker import SmallThinkerForCausalLM
+
+    layout = [0, 1, 1, 1, 0, 1, 1, 1]
+    model = SmallThinkerForCausalLM(dict(
+        vocab_size=128, hidden_size=256, num_hidden_layers=8,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+        moe_ffn_hidden_size=64, moe_num_primary_experts=8,
+        moe_num_active_primary_experts=2, rope_layout=layout,
+        sliding_window_layout=layout, sliding_window_size=64,
+        max_position_embeddings=256, dtype="bfloat16")).eval()
+    adapter = StatedCacheAdapter(model, 16, 4)
+    adapter.plan_window(4, 16, 32)
+    return adapter
+
+
+@pytest.mark.parametrize("closure", ["step", "prefill_chunk"])
+def test_the_window_signature_is_traced_once_a_program(monkeypatch,
+                                                       closure):
+    """Six window layers and two full layers: eight kernel applications, a
+    body for each signature (the window pool's, with its window; the full
+    pool's, without) and none again on a second build; the program is the
+    undecorated application's, equation for equation."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    adapter = _smallthinker_adapter()
+    kernel = "decode" if closure == "step" else "chunk"
+
+    def flat():
+        params, bufs = adapter.params_and_buffers()
+        pools = jax.eval_shape(lambda: adapter.init_pools(65))
+        B, width = (4, 1) if closure == "step" else (1, 32)
+        lead = (jax.ShapeDtypeStruct((B, width), jnp.int64),) + (
+            (jax.ShapeDtypeStruct((B,), jnp.int32),)
+            if closure == "prefill_chunk" else ())
+        return _flat(jax.make_jaxpr(getattr(adapter, closure))(
+            params, bufs, *lead, *pools,
+            jax.ShapeDtypeStruct((B, 32), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32)).jaxpr)
+
+    before = _counts(kernel)
+    cached = flat()
+    calls, bodies = _moved(kernel, before)
+    assert calls == 8 and bodies <= 2
+    before = _counts(kernel)
+    flat()
+    assert _moved(kernel, before) == (8, 0)
+    _uncached(monkeypatch)
+    assert flat() == cached
+    assert sum(e[0] == "pallas_call" for e in cached) == 2 * 8
